@@ -15,7 +15,7 @@ Two checks:
      ``trino_tpu/ops/pallas_kernels.py`` must appear as a key in its
      ``KERNEL_REGISTRY`` — the registry is what the kernel profile and
      the bench artifacts use to attribute dispatches, so an unregistered
-     kernel is invisible to regression triage (how the BENCH_r05 crash
+     kernel is invisible to regression triage (how the round-5 bench crash
      stayed unattributed for two rounds).
 
 Run standalone (``python scripts/check_donation.py``, exit 1 on

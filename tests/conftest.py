@@ -8,22 +8,24 @@ without TPU hardware.
 import os
 import sys
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-if os.environ.get("TRINO_TPU_TEST_TPU") != "1":
-    # Share compiled XLA executables across every process the suite
-    # spawns: the distributed/lifecycle/recovery/multihost tests each
-    # stand up fresh worker processes that would otherwise recompile
-    # identical fragment programs from scratch.  The cache is keyed by
-    # HLO + compile options + jax version, so reuse is always sound;
-    # min-compile-time 0 catches the many sub-second fragment programs
-    # that dominate on the CPU tier-1 path.  Env (not jax.config) so
-    # subprocess workers inherit it.
-    os.environ.setdefault(
-        "JAX_COMPILATION_CACHE_DIR", "/tmp/trino_tpu_xla_cache"
-    )
-    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.0")
-    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "0")
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, _REPO)
+
+# Share compiled XLA executables across every process the suite spawns:
+# the distributed/lifecycle/recovery/multihost tests each stand up fresh
+# worker processes that would otherwise recompile identical fragment
+# programs from scratch.  The cache is keyed by HLO + compile options +
+# jax version, so reuse is always sound; min-compile-time 0 catches the
+# many sub-second fragment programs that dominate on the CPU tier-1 path.
+# Env (not jax.config) so subprocess workers inherit it; a directory the
+# caller already chose stays, otherwise it is the checkout's own
+# .jax_cache (the same default trino_tpu.cache.compile_cache uses).
+os.environ.setdefault(
+    "JAX_COMPILATION_CACHE_DIR", os.path.join(_REPO, ".jax_cache")
+)
+os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.0")
+os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "0")
 
 import trino_tpu
 
@@ -35,11 +37,4 @@ def pytest_configure(config):
     )
 
 
-if os.environ.get("TRINO_TPU_TEST_TPU") == "1":
-    # hardware-validation mode: run single-device suites on the real
-    # TPU backend (mesh/distributed suites need 8 devices — skip them)
-    import jax
-
-    jax.config.update("jax_enable_x64", True)
-else:
-    trino_tpu.force_cpu(8)
+trino_tpu.force_cpu(8)

@@ -1,6 +1,6 @@
 """Unified cache subsystem tests (cache/): plan signatures, the fragment
 result cache (LRU + spill + chaos heal + DML invalidation), the compiled-
-fragment cache (cross-session reuse, persistent tier, poisoned-entry
+fragment cache (cross-session reuse, persistent tier, runtime-error
 retry), and the observability surfaces (system.runtime.caches, /v1/cache).
 
 Reference parity: Presto's fragment result cache tests (canonical plan
@@ -353,66 +353,77 @@ def test_compile_cache_lru_bounded():
     assert cc.get("a") is None  # oldest gone
 
 
-def test_poisoned_entry_recompiled_exactly_once():
+def test_faulting_cached_executable_surfaces_without_a_recompile():
+    """A runtime error from a cached executable is a real error on a
+    directly attached chip: it surfaces as it is — no eviction, no
+    recompile, no second dispatch."""
     # result cache off so the second execute actually runs the fragment
     s = tpch_session(SF, result_cache=False)
     cc = CompileCache()
     s.caches.compile_cache = s._jit_cache = cc
     q = "select count(*) as c from nation"
-    first = s.execute(q).to_pylist()
+    s.execute(q)
     assert len(cc) == 1
     key = next(iter(cc._entries))
     entry = cc._entries[key]
-    real_fn, calls = entry["fn"], {"n": 0}
+    calls, puts = {"n": 0}, cc.puts
 
     def faulting(resident_prep, tile_prep):
         calls["n"] += 1
         raise jax.errors.JaxRuntimeError(
-            "INVALID_ARGUMENT: executable reuse fault (injected)"
+            "INVALID_ARGUMENT: executable fault (injected)"
         )
 
     entry["fn"] = faulting
-    # the faulted execution evicts the poisoned entry and recompiles
-    # exactly once — and succeeds
-    assert s.execute(q).to_pylist() == first
+    with pytest.raises(jax.errors.JaxRuntimeError, match="injected"):
+        s.execute(q)
     assert calls["n"] == 1
-    assert cc.poison_evictions == 1
-    assert len(cc) == 1  # the recompiled (healthy) entry is back
+    assert cc.puts == puts and cc._entries[key] is entry  # nothing evicted
 
 
-def test_poison_retry_is_exactly_once_then_raises():
+@pytest.mark.parametrize("message", [
+    "INVALID_ARGUMENT: injected",
+    "RESOURCE_EXHAUSTED: out of memory",
+    "INTERNAL: compile failed (injected)",
+])
+def test_runtime_errors_are_not_retried(message):
     s = tpch_session(SF)
     ex = s._executor()
     calls = {"n": 0}
 
-    def always_faulting(plan, scans, counts):
+    def failing(plan, scans, counts):
         calls["n"] += 1
-        ex._last_jit_key = ("poisoned-key",)
-        raise jax.errors.JaxRuntimeError("INVALID_ARGUMENT: injected")
+        raise jax.errors.JaxRuntimeError(message)
 
-    ex._run_jitted = always_faulting
-    plan = s.plan("select count(*) as c from nation")
-    with pytest.raises(jax.errors.JaxRuntimeError):
-        ex.execute(plan)
-    # one original attempt + exactly one recompile, then surface (the old
-    # path burned three blind retries "regardless of cache state")
-    assert calls["n"] == 2
-
-
-def test_non_invalid_argument_not_retried():
-    s = tpch_session(SF)
-    ex = s._executor()
-    calls = {"n": 0}
-
-    def oom(plan, scans, counts):
-        calls["n"] += 1
-        ex._last_jit_key = ("k",)
-        raise jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: out of memory")
-
-    ex._run_jitted = oom
+    ex._run_jitted = failing
     with pytest.raises(jax.errors.JaxRuntimeError):
         ex.execute(s.plan("select count(*) as c from nation"))
     assert calls["n"] == 1  # real errors surface with their real message
+
+
+def test_compile_time_oom_falls_to_streaming_tiles():
+    """The one runtime error with a remedy: XLA's compile-time "Ran out
+    of memory" proves the monolithic program cannot fit, so the plan is
+    offered to the streaming tiled executor (once)."""
+    s = tpch_session(SF)
+    ex = s._executor()
+    tried = {"n": 0}
+
+    def oom(plan, scans, counts):
+        raise jax.errors.JaxRuntimeError(
+            "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out "
+            "of memory in memory space hbm."
+        )
+
+    def streaming(plan):
+        tried["n"] += 1
+        return None  # untileable: the ORIGINAL error must surface
+
+    ex._run_jitted = oom
+    ex._try_forced_streaming = streaming
+    with pytest.raises(jax.errors.JaxRuntimeError, match="Ran out of memory"):
+        ex.execute(s.plan("select count(*) as c from nation"))
+    assert tried["n"] == 1
 
 
 def test_compile_cache_persistent_second_process(tmp_path):
@@ -428,7 +439,11 @@ def test_compile_cache_persistent_second_process(tmp_path):
         "s.execute('select count(*) as c from nation')\n"
         "print(json.dumps(shared_compile_cache().stats()))\n"
     )
+    # the executables go where the environment puts jax's cache; the
+    # session-named directory holds only the fragment index
+    xla_dir = tmp_path / "xla"
     env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(xla_dir),
                PYTHONPATH=os.path.dirname(os.path.dirname(
                    os.path.abspath(__file__))))
     stats = []
@@ -442,8 +457,8 @@ def test_compile_cache_persistent_second_process(tmp_path):
     assert stats[0]["persistent_hits"] == 0  # first process: cold disk
     assert stats[1]["persistent_hits"] >= 1  # second: compiled-by-peer
     assert (tmp_path / "index.json").exists()
-    # jax wrote executables into the shared dir
-    assert any(n.endswith("-cache") for n in os.listdir(tmp_path))
+    assert any(n.endswith("-cache") for n in os.listdir(xla_dir))
+    assert not any(n.endswith("-cache") for n in os.listdir(tmp_path))
 
 
 # --- observability -------------------------------------------------------

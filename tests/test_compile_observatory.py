@@ -65,12 +65,12 @@ def test_cause_taxonomy_precedence():
     assert obs.classify("f1", "s2", query_id="qA") == co.FIRST_COMPILE
     # new shape from a different query once the family is warm: retrace
     assert obs.classify("f1", "s2", query_id="qB") == co.SHAPE_MISS
-    # precedence: poisoned recovery > ladder rung > persistent load >
-    # the warm/cold distinction
+    # precedence: ladder rung > persistent load > the warm/cold
+    # distinction
     assert obs.classify("f1", "s2", ladder_attempt=2,
                         query_id="qB") == co.LADDER_RUNG
     assert obs.classify("f1", "s2", ladder_attempt=2,
-                        poisoned=True) == co.POISONED_RECOVERY
+                        persistent=True) == co.LADDER_RUNG
     assert obs.classify("f1", "s1", persistent=True) == co.PERSISTENT_LOAD
     assert obs.counts_by_cause()[co.FIRST_COMPILE] == 1
 

@@ -1,4 +1,4 @@
-"""Fused scan->filter->aggregate megakernels + the BENCH_r05 regression.
+"""Fused scan->filter->aggregate megakernels + the round-5 bench run regression.
 
 Three suites in one file because they are one feature:
 
@@ -11,7 +11,7 @@ Three suites in one file because they are one feature:
    pins the reduction dtype), so wide sums travel as 16-bit planes that
    recombine on the host via int64 shifts — unit tests drive
    ``fused_agg_sums`` directly at the wraparound boundaries.
-3. BENCH_r05 crash regression: the on-device TPC-H generator used to
+3. Round-5 bench crash regression: the on-device TPC-H generator used to
    dispatch OUTSIDE supervision, so the r05 worker crash left no
    breadcrumb.  The generator now dispatches with synthetic output-lane
    shapes; a seeded device_loss at exactly that kernel must be
@@ -251,7 +251,7 @@ def test_fused_agg_sums_predicate_masks_rows():
     assert int(np.asarray(sums)[0, 0]) == int(vals[vals < 100].sum())
 
 
-# --- BENCH_r05: the devgen crash site, now supervised ---------------------
+# --- the round-5 bench run: the devgen crash site, now supervised ---------------------
 
 
 def test_devgen_dispatch_is_supervised_with_replayable_shapes():

@@ -349,27 +349,6 @@ def test_sentinel_recovers_configs_from_truncated_tail():
     assert cfgs["b"]["rows_per_sec"] == 7.0
 
 
-@pytest.mark.skipif(
-    not glob.glob(os.path.join(REPO, "BENCH_r0*.json")),
-    reason="no BENCH trajectory in this checkout",
-)
-def test_sentinel_real_trajectory_flags_r03_and_r05():
-    """Acceptance: on the repo's real BENCH_r01..r05 artifacts the
-    sentinel flags r05 as crash-introduced and r03 as a regression."""
-    paths = sorted(glob.glob(os.path.join(REPO, "BENCH_r0*.json")))
-    rounds = sorted(
-        (bench_sentinel.load_round(p) for p in paths),
-        key=lambda r: r["round"],
-    )
-    verdicts = {v["round"]: v["verdict"]
-                for v in bench_sentinel.judge(rounds)}
-    assert verdicts[3] == "regression"
-    assert verdicts[5] == "crash-introduced"
-    # and nothing else in the trajectory is misflagged as a crash
-    assert [n for n, v in verdicts.items()
-            if v == "crash-introduced"] == [5]
-
-
 def test_sentinel_markdown_names_flagged_rounds(tmp_path):
     _write_rounds(tmp_path, [
         (1, _wrap(1, 0, {"configs": {"q6": {"rows_per_sec": 10.0}}})),

@@ -11,14 +11,12 @@ import jax
 import jax.numpy as jnp
 
 from trino_tpu.ops.pallas_kernels import (  # noqa: E402
-    HAVE_PALLAS,
     MAX_GROUPS,
     grouped_count,
     grouped_sum_i64,
     seg_count_maybe,
 )
 
-pytestmark = pytest.mark.skipif(not HAVE_PALLAS, reason="pallas unavailable")
 
 
 @pytest.mark.parametrize("n,groups", [(1000, 4), (300_000, 12), (5000, 32)])

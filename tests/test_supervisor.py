@@ -94,8 +94,8 @@ def test_device_loss_names_culprit_kernel_and_quarantines():
 
 
 def test_device_fault_error_is_not_a_jax_runtime_error():
-    """exec/local.py's JaxRuntimeError handlers (poisoned-executable
-    eviction, compile-OOM streaming) must never swallow a device fault."""
+    """exec/local.py's JaxRuntimeError handler (compile-OOM streaming)
+    must never swallow a device fault."""
     import jax
 
     e = DeviceFaultError("device_loss", Breadcrumb("k"))
@@ -428,3 +428,60 @@ def test_no_naked_device_dispatch_in_exec_or_server():
         "unsupervised device dispatch found:\n"
         + "\n".join(f"{r}:{n}: {c}" for r, n, c in violations)
     )
+
+
+# --- a compile is not a wedge ---------------------------------------------
+
+
+@pytest.mark.parametrize("mode", ["local", "mesh", "devgen"])
+def test_compile_longer_than_watchdog_is_not_a_wedge(mode, monkeypatch):
+    """Trace + XLA compile run outside the supervised dispatch: a program
+    whose compile outlasts device_watchdog_timeout_s still answers on the
+    device — no device_wedge, no quarantine, fallback counter untouched."""
+    from trino_tpu.connectors import tpch_device
+    from trino_tpu.exec.local import LocalExecutor
+    from trino_tpu.session import tpch_session
+    from trino_tpu.utils.metrics import REGISTRY
+
+    timeout = 2.0
+    slow = {"n": 0}
+
+    def slowly(orig):
+        def wrapper(*a, **kw):
+            out = orig(*a, **kw)
+            time.sleep(timeout * 1.5)  # the compile outlasts the watchdog
+            slow["n"] += 1
+            return out
+        return wrapper
+
+    if mode == "devgen":
+        # the generator's compile step, which the executor runs before
+        # (not inside) the supervised generator dispatch
+        tpch_device.clear_jit_cache()
+        monkeypatch.setattr(
+            tpch_device, "compile_lanes", slowly(tpch_device.compile_lanes)
+        )
+    else:
+        monkeypatch.setattr(
+            LocalExecutor, "_compile_fragment",
+            staticmethod(slowly(LocalExecutor._compile_fragment)),
+        )
+    extra = {"distributed": True, "num_devices": 2} if mode == "mesh" else {}
+    s = tpch_session(
+        SF, result_cache=False, device_cpu_fallback=False,
+        device_watchdog_timeout_s=timeout, **extra,
+    )
+    fallbacks = REGISTRY.counter("trino_tpu_device_fallback_total").total()
+    # compile_cache=False: an executable another test already compiled
+    # must not stand in for the slow compile
+    s.properties.set("compile_cache", False)
+    got = s.execute(Q6).to_pylist()
+    assert slow["n"] >= 1, "the slowed compile never ran"
+    assert got == tpch_session(SF, result_cache=False).execute(Q6).to_pylist()
+    assert s.device_supervisor.device_state() == ACTIVE
+    assert s.device_supervisor.fallback_attempted == 0
+    assert REGISTRY.counter(
+        "trino_tpu_device_fallback_total").total() == fallbacks
+    if mode == "devgen":
+        tpch_device.clear_jit_cache()
+

@@ -4,11 +4,8 @@ The device path (connectors/tpch_device.py) must produce EXACTLY the
 arrays the numpy path (connectors/tpch.generate) produces — splitmix64 is
 pure integer math, so any divergence is a bug, not noise.
 
-Under ``TRINO_TPU_TEST_TPU=1`` this whole file runs against the real TPU
-backend (tests/conftest.py), so the generator kernels and the end-to-end
-session test below validate actual HBM materialization, not the CPU
-emulation — the r5 bench wedge (generator programs faulting the backend)
-is exactly what that mode exists to catch.
+This file proves parity on the CPU backend; ``chip_smoke.py`` holds the
+same generator to the host generator on real HBM at SF10.
 """
 import numpy as np
 import pytest
@@ -94,8 +91,7 @@ def test_session_device_generation_end_to_end():
     host numpy generator, for scans with numeric, date, and dictionary
     columns.  This is the query-level complement of the per-array parity
     tests above — it exercises the _LazyDeviceLane plumbing, padded-cap
-    generation, and dictionary merge inside exec/local.py, on whatever
-    backend the suite runs (real TPU under TRINO_TPU_TEST_TPU=1)."""
+    generation, and dictionary merge inside exec/local.py."""
     queries = [
         # numeric + date filter over lineitem (the q6 shape)
         "select sum(l_extendedprice * l_discount) from lineitem "

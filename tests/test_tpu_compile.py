@@ -1,0 +1,160 @@
+"""Compile the main path's device programs for a TPU v5e that is described,
+not attached: the chip's own compiler (Mosaic for the pallas kernels, XLA
+for the rest) accepts or refuses them here, at the real row counts, at no
+chip time.  Nothing runs, so nothing here is a device number.
+
+All cases live in this one file and compile in the test's own process:
+one process at a time may load the TPU library, and the topology is
+described inside a fixture so every xdist worker collects the same tests.
+"""
+import contextlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from tpch_sql import QUERIES
+from trino_tpu.connectors import tpch_device
+from trino_tpu.exec.local import LocalExecutor
+from trino_tpu.exec.shapes import resolve_ladder
+from trino_tpu.ops import aggregation as agg_ops
+from trino_tpu.ops import pallas_kernels as pk
+from trino_tpu.session import tpch_session
+
+ROWS = {"sf1": 6_001_215, "sf10": 59_986_052}  # lineitem, by the generator
+HBM_BYTES = 16 * 1024**3
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_persistent_cache():
+    """A compile for a described chip is written to jax's persistent cache
+    but cannot be read back without the chip; keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+
+
+def _q6_emit(t):
+    """Q6-shaped closure: date/discount/quantity predicate, one product
+    split into 16-bit planes, global aggregate (no group id)."""
+    pred = (
+        (t["d"] >= 8766) & (t["d"] < 9131)
+        & (t["disc"] >= 5) & (t["disc"] <= 7) & (t["q"] < 2400)
+    )
+    v = t["p"] * t["disc"]
+    return pred, None, [1, v & 0xFFFF, v >> 16]
+
+
+def _kernel_program(kernel, n, sds):
+    if kernel == "_count_kernel":
+        return (
+            jax.jit(lambda f, g: pk.grouped_count(f, g, 9)),
+            (sds((n,), jnp.bool_), sds((n,), jnp.int32)),
+        )
+    if kernel == "_plane_kernel":
+        return (
+            jax.jit(lambda v, g: pk.grouped_sum_i64(v, g, 9)),
+            (sds((n,), jnp.int64), sds((n,), jnp.int32)),
+        )
+    cols = {k: sds((n,), jnp.int32) for k in ("d", "disc", "q", "p")}
+    return (
+        jax.jit(lambda c, live: pk.fused_agg_sums(c, live, _q6_emit, 3, 1)),
+        (cols, sds((n,), jnp.bool_)),
+    )
+
+
+@pytest.mark.parametrize("size", sorted(ROWS))
+@pytest.mark.parametrize("kernel", sorted(pk.KERNEL_REGISTRY))
+def test_pallas_kernel_compiles_for_v5e(kernel, size, one_chip,
+                                        no_persistent_cache):
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fn, args = _kernel_program(kernel, ROWS[size], sds)
+    compiled = fn.lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes) < HBM_BYTES
+
+
+def test_lineitem_generator_q6_columns_sf1_fits_hbm(one_chip,
+                                                    no_persistent_cache):
+    q = resolve_ladder({}).quantize
+    cols = ("l_quantity", "l_extendedprice", "l_discount", "l_shipdate")
+    fn = tpch_device._gen_lineitem(cols, q(1_500_000), q(ROWS["sf1"]), 1.0)
+    scalar = jax.ShapeDtypeStruct((), jnp.int64, sharding=one_chip)
+    mem = fn.lower(scalar, scalar).compile().memory_analysis()
+    total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+             + mem.output_size_in_bytes)
+    assert 0 < total < HBM_BYTES
+
+
+@contextlib.contextmanager
+def _fragments_compiled_for(one_chip):
+    """Spy on the executor's ahead-of-time compile: each fragment program
+    the engine builds is ALSO traced as the chip would trace it (pallas
+    live, TPU reduction choice) and compiled for the described device.
+    The query itself carries on, on the CPU."""
+    texts = []
+    orig = LocalExecutor._compile_fragment
+    enabled, use_masked = pk.enabled, agg_ops._use_masked
+
+    def spy(fn, *args):
+        shapes = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip), args)
+        pk.enabled = lambda: True
+        agg_ops._use_masked = lambda cap: cap <= agg_ops._SMALL_SEG_CAP
+        try:
+            # a fresh wrapper: jit caches traces by the wrapped function
+            on_chip = jax.jit(lambda *a: fn.__wrapped__(*a))
+            texts.append(on_chip.lower(*shapes).compile().as_text())
+        finally:
+            pk.enabled, agg_ops._use_masked = enabled, use_masked
+        return orig(fn, *args)
+
+    LocalExecutor._compile_fragment = staticmethod(spy)
+    try:
+        yield texts
+    finally:
+        LocalExecutor._compile_fragment = staticmethod(orig)
+
+
+@pytest.mark.parametrize("query", [6, 1])
+def test_fused_scan_aggregate_fragment_compiles_for_v5e(
+        query, one_chip, no_persistent_cache):
+    """The whole jitted fragment of Q6 / Q1 — megakernel with the plan's
+    real `emit` closure plus the finalize tail — as the engine builds it.
+    (A python-int term or clip bound entering the kernel as an int64
+    scalar made Mosaic's lowering recurse; only this compile shows it.)"""
+    s = tpch_session(0.01, result_cache=False, device_cpu_fallback=False)
+    with _fragments_compiled_for(one_chip) as texts:
+        rows = s.execute(QUERIES[query][0]).to_pylist()
+    assert rows
+    assert texts and all("tpu_custom_call" in t for t in texts)

@@ -54,9 +54,12 @@ def _local_session(args):
     if _SESSION is None:
         import trino_tpu
 
-        if not args.tpu:
-            trino_tpu.force_cpu()
+        # runs on whatever device jax provides (JAX_PLATFORMS=cpu for a
+        # CPU run); compiled programs persist across invocations
         trino_tpu.enable_x64()
+        from .cache.compile_cache import place_jax_cache
+
+        place_jax_cache()
         from .session import Session, tpch_session, tpcds_session
 
         if args.catalog == "tpch":
@@ -74,7 +77,6 @@ def main(argv=None) -> int:
     p.add_argument("--server", help="coordinator URI (default: in-process)")
     p.add_argument("--catalog", default="tpch")
     p.add_argument("--sf", type=float, default=0.01, help="tpch scale factor")
-    p.add_argument("--tpu", action="store_true", help="use the TPU backend")
     p.add_argument("--execute", "-e", help="run one statement and exit")
     args = p.parse_args(argv)
 

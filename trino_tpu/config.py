@@ -469,9 +469,10 @@ SESSION_PROPERTIES: Dict[str, PropertyMetadata] = {
         ),
         PropertyMetadata(
             "compile_cache_dir",
-            "persistent compile-cache directory shared across processes "
-            "(jax persistent compilation cache + fragment index); "
-            "empty = in-memory only",
+            "turns on the persistent compile tier and names the directory "
+            "of its fragment index, shared across processes (the XLA "
+            "executables live in JAX_COMPILATION_CACHE_DIR, else "
+            "<checkout>/.jax_cache); empty = in-memory only",
             str, "",
         ),
         PropertyMetadata(

@@ -1,8 +1,9 @@
 """ctypes bindings + build for the native (C++) TPC-H generator.
 
-The shared library is built on first use with g++ -O3 (cached under
-native/build/).  Falls back silently to the numpy path when a toolchain
-is unavailable; results are bit-identical either way (tested).
+The shared library is built on first use with g++ -O3 into native/build/
+(ignored by git, never committed).  Without a toolchain the numpy path
+is taken; results are bit-identical either way (tested), and
+`available()` says which one runs.
 """
 from __future__ import annotations
 
@@ -27,15 +28,21 @@ def _load() -> Optional[ctypes.CDLL]:
         return _LIB
     _TRIED = True
     try:
+        # native/build/ is never committed: the binary is built here, on
+        # first use, from the source beside it — and again whenever the
+        # source is newer.  The build lands under a private name first so
+        # concurrent first users never load a half-written library.
         if not os.path.exists(_SO) or (
             os.path.getmtime(_SO) < os.path.getmtime(_SRC)
         ):
             os.makedirs(os.path.dirname(_SO), exist_ok=True)
+            tmp = "%s.%d.tmp" % (_SO, os.getpid())
             subprocess.run(
-                ["g++", "-O3", "-shared", "-fPIC", "-o", _SO, _SRC],
+                ["g++", "-O3", "-shared", "-fPIC", "-o", tmp, _SRC],
                 check=True,
                 capture_output=True,
             )
+            os.replace(tmp, _SO)
         lib = ctypes.CDLL(_SO)
         lib.gen_lineitem.restype = ctypes.c_int64
         _LIB = lib

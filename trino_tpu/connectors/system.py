@@ -561,7 +561,11 @@ class _SystemSource:
                 "total_bytes": [e["totalBytes"] for e in entries],
                 "device_wall_s": [e["deviceWallS"] for e in entries],
                 "gbps": [e["gbps"] for e in entries],
-                "roofline_pct": [e["rooflinePct"] for e in entries],
+                # NaN off-TPU: the CPU backend has no HBM peak
+                "roofline_pct": [
+                    float("nan") if e["rooflinePct"] is None
+                    else e["rooflinePct"] for e in entries
+                ],
             }
         if table == "flight_recorder":
             import json as _json

@@ -1,8 +1,7 @@
 """HBM bandwidth ledger: per-kernel bytes-touched over timed device wall.
 
-ROADMAP open item 1 is a bandwidth gap — best Q6 runs at ~2 GB/s
-effective against ~1.2 TB/s of HBM — and closing it needs a per-operator
-accounting of where the bytes go.  Each supervised dispatch that runs
+Scan-aggregate queries are HBM-bandwidth bound, and closing the gap to
+the chip's peak needs a per-operator accounting of where the bytes go.  Each supervised dispatch that runs
 under the ``bandwidth_ledger`` session property is bracketed with
 ``block_until_ready`` in the executor, and the ledger folds
 
@@ -18,25 +17,52 @@ endpoint, ``system.runtime.kernel_bandwidth``, and the
 """
 from __future__ import annotations
 
-import os
 import threading
 from typing import Dict, List, Optional
 
 from ..utils.metrics import BYTES_BUCKETS, REGISTRY
 
-# one TPU v4 chip moves ~1228 GB/s from HBM2e; override for other parts
-# (or to calibrate CPU-backend tests) via TRINO_TPU_ROOFLINE_GBPS
-DEFAULT_ROOFLINE_GBPS = 1228.8
+# Published per-chip peaks, keyed by jax's `device_kind`.  A roofline is
+# the attached chip's: a kind that is not listed is an error, never a
+# default borrowed from another part.
+DEVICE_PEAKS: Dict[str, Dict] = {
+    "TPU v5 lite": {
+        "hbm_gbps": 819.0,
+        "hbm_bytes": 16 * 10**9,
+        "source": "Google Cloud documentation, \"TPU v5e\": 16 GB of HBM "
+                  "at 819 GB/s per chip",
+    },
+}
 
 
-def roofline_bytes_per_s() -> float:
+def device_peaks(device_kind: Optional[str] = None) -> Dict:
+    """Published peaks of `device_kind` (default: the first jax device)."""
+    if device_kind is None:
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
     try:
-        gbps = float(
-            os.environ.get("TRINO_TPU_ROOFLINE_GBPS", DEFAULT_ROOFLINE_GBPS)
-        )
-    except ValueError:
-        gbps = DEFAULT_ROOFLINE_GBPS
-    return gbps * 1e9
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}: add it "
+            "to trino_tpu.obs.bandwidth.DEVICE_PEAKS with its source"
+        ) from None
+
+
+def roofline_bytes_per_s() -> Optional[float]:
+    """HBM peak of the attached chip in bytes/s; None on the CPU backend,
+    which has no HBM (a CPU run never reports a share of a chip's peak)."""
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return None
+    return device_peaks(dev.device_kind)["hbm_gbps"] * 1e9
+
+
+def _roofline_pct(gbps: float, peak: Optional[float]) -> Optional[float]:
+    return None if not peak else 100.0 * gbps * 1e9 / peak
 
 
 class BandwidthLedger:
@@ -105,9 +131,7 @@ class BandwidthLedger:
         gbps = (e["totalBytes"] / wall / 1e9) if wall > 0 else 0.0
         out = dict(e)
         out["gbps"] = gbps
-        out["rooflinePct"] = (
-            100.0 * gbps * 1e9 / self.roofline_bytes_per_s
-        )
+        out["rooflinePct"] = _roofline_pct(gbps, self.roofline_bytes_per_s)
         return out
 
     def entries(self) -> List[Dict]:
@@ -133,6 +157,9 @@ class BandwidthLedger:
             "deviceWallS": wall,
             "exchangeBytes": self.exchange_bytes,
             "effectiveGbps": gbps,
-            "rooflinePct": 100.0 * gbps * 1e9 / self.roofline_bytes_per_s,
-            "rooflineGbps": self.roofline_bytes_per_s / 1e9,
+            "rooflinePct": _roofline_pct(gbps, self.roofline_bytes_per_s),
+            "rooflineGbps": (
+                self.roofline_bytes_per_s / 1e9
+                if self.roofline_bytes_per_s else None
+            ),
         }

@@ -17,7 +17,6 @@ the mesh executor — reports here with a structured *cause*:
 - ``ladder_rung``     — a capacity-overflow retry re-traced (attempt > 0)
 - ``shape_miss``      — the family was warm but this shape signature
   was not: the retrace the zero-retrace gate hunts
-- ``poisoned_recovery`` — recompile after ``evict_poisoned``
 - ``persistent_load`` — re-trace whose XLA compile was served by the
   on-disk persistent tier (cheap, but still a trace)
 
@@ -77,7 +76,6 @@ CENSUS_FIELDS = (
 )
 
 # -- the cause taxonomy (classification precedence is top to bottom) ----
-POISONED_RECOVERY = "poisoned_recovery"
 LADDER_RUNG = "ladder_rung"
 PERSISTENT_LOAD = "persistent_load"
 SHAPE_MISS = "shape_miss"
@@ -86,7 +84,6 @@ CAUSES = (
     FIRST_COMPILE,
     LADDER_RUNG,
     SHAPE_MISS,
-    POISONED_RECOVERY,
     PERSISTENT_LOAD,
 )
 
@@ -302,19 +299,16 @@ class CompileObservatory:
         family: str,
         shape_sig: str,
         ladder_attempt: int = 0,
-        poisoned: bool = False,
         persistent: bool = False,
         query_id: str = "",
     ) -> str:
         """Structured cause for one compile, in precedence order:
-        poisoned recovery beats ladder rung beats persistent load beats
+        ladder rung beats persistent load beats
         the warm/cold family distinction (shape_miss vs first_compile).
         A family is only warm against queries that arrive after its
         cold window: the query that introduced it — and siblings that
         started alongside it — present their per-partition shapes
         moments later, and those are first compiles, not retraces."""
-        if poisoned:
-            return POISONED_RECOVERY
         if int(ladder_attempt or 0) > 0:
             return LADDER_RUNG
         if persistent:
@@ -371,7 +365,6 @@ class CompileObservatory:
         task_id: str = "",
         node_id: str = "",
         ladder_attempt: int = 0,
-        poisoned: bool = False,
         persistent: bool = False,
         scan_rows: Optional[List[int]] = None,
         shape_sig: Optional[str] = None,
@@ -388,7 +381,7 @@ class CompileObservatory:
         if cause is None:
             cause = self.classify(
                 family, sig, ladder_attempt=ladder_attempt,
-                poisoned=poisoned, persistent=persistent,
+                persistent=persistent,
                 query_id=str(query_id or ""),
             )
         self._register(family, sig, query_id=str(query_id or ""))

@@ -2,7 +2,7 @@
 
 Every ``DeviceSupervisor.dispatch`` writes a *dispatch* record BEFORE the
 thunk runs and a *complete* (or *fault*) record after, so when the TPU
-runtime kills the process mid-kernel (the BENCH_r05 failure mode: nothing
+runtime kills the process mid-kernel (the round-5 bench run failure mode: nothing
 but ``UNAVAILABLE: TPU worker process crashed`` in the log) the last N
 dispatches — kernel digest, input shapes/dtypes, HBM reservation, the
 post-dispatch device-memory watermark, wall time, query/task id — survive

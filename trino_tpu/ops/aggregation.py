@@ -673,7 +673,7 @@ class SortedSegments:
     adjacent, gid non-decreasing).
 
     XLA:TPU scatter runs ~16M updates/s regardless of sortedness hints
-    (MICRO_group.json), so at capacities beyond the masked-matrix range
+    (round-3 micro-benchmark, record deleted in PR 22), so at capacities beyond the masked-matrix range
     every accumulator cost ~0.5s at SF1.  Sorted runs instead admit:
       - ONE extra single-key sort (merge_rank of arange(cap) into the
         sorted gids) shared by all aggregates, giving each group's

@@ -142,7 +142,7 @@ class DirectLookupSource(NamedTuple):
     (operator/join/ArrayPositionLinks / PagesHash fast path); TPU-first
     shape: one scatter to build, ONE random gather per probe row —
     measured 0.09s vs the sort-merge rank's 0.21s at 4M probes
-    (MICRO_probe.json)."""
+    (round-3 micro-benchmark, record deleted in PR 22)."""
 
     table: jnp.ndarray  # [domain] int32: build row + 1, 0 = empty
     lo: int

@@ -45,6 +45,7 @@ import dataclasses
 import math
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from ..expr import ir
@@ -426,8 +427,6 @@ def try_fused(ctx, node: P.Aggregate):
     ex = ctx.ex
     if ex._megakernel_mode() != "on":
         return None
-    if not pk.HAVE_PALLAS:
-        return None
     try:
         return _run(ctx, node)
     except Reject as r:
@@ -498,7 +497,9 @@ def _run(ctx, node: P.Aggregate):
         p = _fold(pred_fns, tiles, True) if pred_fns else None
         gid = None
         for _k, sk, dom in doms:
-            code = jnp.clip(tiles[sk], 0, dom - 1)
+            # int32 bounds: python ints would enter jnp.clip as int64
+            # scalars, and an in-kernel int64 convert recurses in Mosaic
+            code = jnp.clip(tiles[sk], jnp.int32(0), jnp.int32(dom - 1))
             gid = code if gid is None else gid * (dom + 1) + code
         return p, gid, [fn(tiles) for fn, _sh in terms]
 
@@ -515,9 +516,14 @@ def _run(ctx, node: P.Aggregate):
         cols32[nm] = v.astype(jnp.int32)
 
     n_terms = len(terms)
+    # interpret mode is for off-TPU parity tests (megakernels=on); on
+    # the chip pk.enabled() holds, so the kernel is a real Mosaic call
+    interpret = not pk.enabled()
+    assert not (interpret and jax.default_backend() == "tpu"), (
+        "fused megakernel would run in pallas interpret mode on a TPU"
+    )
     sums = pk.fused_agg_sums(
-        cols32, live, emit, n_terms, cap,
-        interpret=not pk.enabled(),
+        cols32, live, emit, n_terms, cap, interpret=interpret,
     )
     # mesh shard bodies: each device fused ITS split shard; the trace
     # context merges the int64 (term, group) partials across the mesh

@@ -331,7 +331,7 @@ def seg_sum_chunks(row_chunks, gid: jnp.ndarray, cap: int):
 
     Small capacities use the masked-matrix reduction per chunk lane
     (XLA:TPU scatter measured ~16M updates/s vs ~100x that for the
-    masked form at cap<=32 — MICRO_group.json); large capacities fall
+    masked form at cap<=32 — round-3 micro-benchmark, record deleted in PR 22); large capacities fall
     back to one stacked (n, k) scatter."""
     from .aggregation import _use_masked
 

@@ -30,7 +30,7 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P_
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P_
 
 from ..catalog import CatalogManager
 from ..exec.local import (
@@ -67,18 +67,8 @@ def _is_hll_lane(spec, name: str) -> bool:
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    """shard_map moved out of jax.experimental and renamed its
-    replication-check kwarg (check_rep -> check_vma) across jax
-    releases; resolve whichever this install provides."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    except TypeError:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def default_mesh(n: Optional[int] = None) -> Mesh:
@@ -89,6 +79,21 @@ def default_mesh(n: Optional[int] = None) -> Mesh:
 
 def _agather(x: jnp.ndarray) -> jnp.ndarray:
     return jax.lax.all_gather(x, AXIS, axis=0, tiled=True)
+
+
+def _pextreme(x: jnp.ndarray, kind: str) -> jnp.ndarray:
+    """Cross-device max/min of a replicated-shape value.  XLA:TPU lowers
+    a 64-bit all-reduce only for sums (the v5e compiler refuses the rest:
+    "Supported lowering only of Sum all reduce"), so 64-bit lanes are
+    gathered and reduced locally; narrower lanes use the collective."""
+    if jnp.dtype(x.dtype).itemsize == 8:
+        gathered = jax.lax.all_gather(x, AXIS)
+        return (jnp.max if kind == "max" else jnp.min)(gathered, axis=0)
+    return (jax.lax.pmax if kind == "max" else jax.lax.pmin)(x, AXIS)
+
+
+def _pmax(x: jnp.ndarray) -> jnp.ndarray:
+    return _pextreme(x, "max")
 
 
 def _shuffle_chunk(cap: int, ndev: int, factor: int, quantize=None) -> int:
@@ -283,6 +288,24 @@ class MeshExecutor(LocalExecutor):
         self.shuffle_hints = self._skew_shuffle_hints(
             plan, scan_args, counts_args, ndev
         )
+        # one explicit sharded upload ([ndev, cap] stacks split on the
+        # mesh axis, one row block per device), shared by every ladder
+        # attempt; where each lane's shards landed goes on the profile
+        sharding = NamedSharding(self.mesh, P_(AXIS))
+        host_args = scan_args
+        scan_args = self._dispatch(
+            # supervised: runs inside this _dispatch thunk
+            lambda: jax.device_put(host_args, sharding),  # dispatch-guard: ok
+            self._dispatch_crumb("mesh:%d/upload" % ndev, "upload", host_args),
+        )
+        self.kernel_profile["scanShards"] = {
+            "%s.%s" % (nid, sym): [
+                (sh.device.id, int(np.prod(sh.data.shape)))
+                for sh in lane.addressable_shards
+            ]
+            for nid, lanes in scan_args.items()
+            for sym, lane in lanes.items()
+        }
         self.group_capacity = int(self.config.get("group_capacity", 4096))
         self.join_factor = 1
         self.force_expansion = set()
@@ -361,14 +384,19 @@ class MeshExecutor(LocalExecutor):
                     padded_rows / actual_rows, 3
                 ) if actual_rows else 1.0,
             ):
-                fn = jax.jit(shard_fn)  # dispatch-guard: ok (lazy wrapper)
+                # trace + compile outside the watchdog (see
+                # LocalExecutor._run_jitted); only execution is supervised
+                fn = self._compile_fragment(
+                    jax.jit(shard_fn),  # dispatch-guard: ok (lazy wrapper)
+                    scan_args, counts_args,
+                )
+                compile_s = time.time() - compile_start
                 led_t0 = time.perf_counter()
                 out = self._dispatch(
                     lambda: fn(scan_args, counts_args), bc
                 )
             self._ledger_bracket(out, digest, "mesh", plan, scan_args,
                                  led_t0)
-            compile_s = time.time() - compile_start
             _compile_obs.record_compile(
                 kernel=digest, family=family, cause=cause,
                 mode="mesh", shapes=shapes, shape_sig=shape_sig,
@@ -815,11 +843,11 @@ class _MeshTraceCtx(_TraceCtx):
 
     def _note_capacity(self, ngroups, cap, kind="group"):
         # replicate the check value so it can cross the out_specs=P() boundary
-        self.capacity_checks.append(jax.lax.pmax(ngroups, AXIS))
+        self.capacity_checks.append(_pmax(ngroups))
         self.capacity_limits.append((cap, kind))
 
     def _note_collision(self, coll):
-        self.collision_checks.append(jax.lax.pmax(coll, AXIS))
+        self.collision_checks.append(_pmax(coll))
 
     def visit(self, node: P.PlanNode) -> Batch:
         # the eager per-node instrumentation concretizes row counts
@@ -1043,7 +1071,11 @@ class _MeshTraceCtx(_TraceCtx):
         so a cross-device wrap (each shard under the threshold, total
         beyond int64) fails loudly."""
         out = {}
-        ops = {"sum": jax.lax.psum, "min": jax.lax.pmin, "max": jax.lax.pmax}
+        ops = {
+            "sum": lambda x: jax.lax.psum(x, AXIS),
+            "min": lambda x: _pextreme(x, "min"),
+            "max": _pmax,
+        }
         for s in specs:
             hll_names = [
                 n for n in s.accumulator_names if _is_hll_lane(s, n)
@@ -1061,7 +1093,7 @@ class _MeshTraceCtx(_TraceCtx):
                 if _is_hll_lane(s, name):
                     continue
                 kind = s.psum_kind(name)
-                out[name] = ops[kind](accs[name], AXIS)
+                out[name] = ops[kind](accs[name])
                 if (
                     kind == "sum"
                     and s.kind in ("sum", "avg")

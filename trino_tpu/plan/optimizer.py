@@ -789,7 +789,7 @@ def _scan_minmax(node: P.PlanNode, symbol: str, metadata: Metadata):
 def _annotate_direct_joins(node: P.PlanNode, metadata: Metadata) -> P.PlanNode:
     """Dense-domain build keys probe through a direct-address table (one
     scatter + one gather) instead of sort-merge ranks — measured 2.3x on
-    the locate step at 4M probes (MICRO_probe.json), and the build sort
+    the locate step at 4M probes (round-3 micro-benchmark, record deleted in PR 22), and the build sort
     disappears.  Requirements (ops/join.DirectLookupSource): build key
     strict-proven unique, narrow integer, bounded domain from column
     stats.  The runtime self-verifies (violation + duplicate counters

@@ -1,7 +1,7 @@
 """Supervised kernel dispatch: crash attribution, quarantine, fallback.
 
 The real-TPU bench regressed to ``JaxRuntimeError: UNAVAILABLE: TPU worker
-process crashed`` on nearly every config (BENCH_r05) with nothing naming
+process crashed`` on nearly every config (the round-5 bench run) with nothing naming
 the culprit kernel, and a single fault killed the whole worker.  Following
 Dean & Barroso's *The Tail at Scale* (CACM 2013) — tolerate component
 failure at the system level instead of assuming it away — the TPU runtime
@@ -55,10 +55,10 @@ STRIKE_WINDOW_S = 600.0
 # within half a minute even after a long quarantine
 MAX_PROBE_BACKOFF_S = 30.0
 
-# error-message signatures of a LOST device (tunnel/worker crash, device
-# dropped off the bus).  Deliberately conservative: INVALID_ARGUMENT
-# (poisoned executable) and compile OOM keep their existing targeted
-# handlers in exec/local.py and must NOT be swallowed here.
+# error-message signatures of a LOST device (runtime/worker crash, device
+# dropped off the bus).  Deliberately conservative: INVALID_ARGUMENT and
+# compile OOM are program errors that exec/local.py surfaces (or, for a
+# compile OOM, streams) and must NOT be swallowed here.
 _DEVICE_LOSS_SIGNATURES = (
     "UNAVAILABLE",
     "worker process crashed",
@@ -124,8 +124,8 @@ class DeviceFaultError(RuntimeError):
     """A device execution was lost or wedged; names the culprit kernel.
 
     NOT a ``jax.errors.JaxRuntimeError`` subclass on purpose: the
-    executor's existing JaxRuntimeError handler (poisoned-executable
-    eviction, compile-OOM streaming fallback) must never intercept a
+    executor's JaxRuntimeError handler (compile-OOM streaming
+    fallback) must never intercept a
     device fault — this error's handler is the degraded CPU fallback."""
 
     def __init__(self, kind: str, breadcrumb: Breadcrumb,

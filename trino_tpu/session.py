@@ -1061,17 +1061,21 @@ class Session:
                 text += "\n  (no compiles this query)"
         bandwidth = prof.get("bandwidth") or []
         if bandwidth:
+            def _pct(v):
+                # no HBM peak off-TPU: never print a share of one
+                return "n/a" if v is None else f"{v:.3f}%"
+
             text += (
                 "\n\nHBM bandwidth ledger "
                 f"(roofline {summary.get('effectiveGbps', 0.0):.2f} GB/s "
-                f"effective, {summary.get('rooflinePct', 0.0):.3f}% of "
+                f"effective, {_pct(summary.get('rooflinePct'))} of "
                 "peak):"
             )
             for e in bandwidth:
                 text += (
                     f"\n  kernel {e['kernel']} [{e['mode']}]: "
                     f"{e['gbps']:.2f} GB/s "
-                    f"({e['rooflinePct']:.3f}% roofline), "
+                    f"({_pct(e['rooflinePct'])} roofline), "
                     f"in {e['inputBytes']}B, out {e['outputBytes']}B, "
                     f"inter {e['intermediateBytes']}B over "
                     f"{e['deviceWallS'] * 1000:.2f}ms device wall"
