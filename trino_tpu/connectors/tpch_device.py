@@ -292,6 +292,14 @@ def clear_jit_cache() -> int:
     return n
 
 
+def _named_jit(fn, table: str):
+    """jit the generator under the name `devgen_<table>`: the name a
+    profile's `XLA Modules` line and idle-gap labels show for it."""
+    fn.__name__ = fn.__qualname__ = "devgen_" + table
+    # no-donate: generator args are two scalars (lo, hi); lanes are outputs
+    return jax.jit(fn)
+
+
 def _gen_flat(table: str, cols: tuple, cap: int, sf: float):
     n = H._counts(sf)
 
@@ -305,8 +313,7 @@ def _gen_flat(table: str, cols: tuple, cap: int, sf: float):
             for c, v in vals.items()
         }
 
-    # no-donate: generator args are two scalars (lo, hi); lanes are outputs
-    return jax.jit(fn)
+    return _named_jit(fn, table)
 
 
 def _gen_lineitem(cols: tuple, cap_orders: int, cap_rows: int, sf: float):
@@ -343,8 +350,7 @@ def _gen_lineitem(cols: tuple, cap_orders: int, cap_rows: int, sf: float):
             for c, v in vals.items()
         }
 
-    # no-donate: generator args are two scalars (lo, hi); lanes are outputs
-    return jax.jit(fn)
+    return _named_jit(fn, "lineitem")
 
 
 def supports(table: str, cols: Sequence[str]) -> bool:

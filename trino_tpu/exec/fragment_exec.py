@@ -22,6 +22,7 @@ from ..catalog import CatalogManager
 from ..page import Page
 from ..plan import nodes as P
 from ..spi import Split
+from ..utils.tracing import TRACER
 from .local import (
     ExecutionError,
     LocalExecutor,
@@ -98,6 +99,7 @@ class FragmentExecutor(LocalExecutor):
         staged = getattr(self, "_preuploaded", None)
         if staged is None:
             staged = self._preuploaded = {}
+        upload = TRACER.current_span()   # `tile_upload`, on the pool thread
         for nid, arrays in scans.items():
             if nid in staged:
                 continue
@@ -106,11 +108,15 @@ class FragmentExecutor(LocalExecutor):
                 "h2d:%s" % getattr(node, "table", "remote"), "h2d",
                 tree={"scan": arrays},
             )
-            lanes = self._dispatch(
-                lambda a=arrays, n=node, c=counts[nid], i=nid:
-                    self._device_lanes(n, a, c, nid=i),
-                bc,
-            )
+
+            def stage(a=arrays, n=node, c=counts[nid], i=nid):
+                # on the supervisor's watchdog thread, which has no span
+                # open: `upload` keeps what opens here (`devgen`) in the
+                # query's trace
+                with TRACER.span("stage_lanes", parent=upload):
+                    return self._device_lanes(n, a, c, nid=i)
+
+            lanes = self._dispatch(stage, bc)
             nbytes = sum(
                 int(getattr(v, "nbytes", 0) or 0)
                 + int(getattr(ok, "nbytes", 0) or 0)

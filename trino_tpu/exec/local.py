@@ -548,82 +548,64 @@ class LocalExecutor:
         # (MemoryRevokingScheduler -> spill, host RAM as the spill tier)
         limit = self.config.get("memory_limit_bytes")
         if limit and self.config.get("spill_enabled", True):
-            from . import spill, streaming
-
-            # DISTINCT aggregation first: the streaming fragmenter keeps
-            # a distinct Aggregate single-step behind one hash exchange,
-            # which locally gathers every input row into one in-memory
-            # fragment — the spill rewrite partitions host-side instead
-            sp = spill.plan_distinct_spill(self, plan, int(limit))
-            if sp is not None:
-                return spill.execute_spilled_distinct(self, plan, *sp)
-            # streaming (fragment-tiled) execution next: the general
-            # bounded-working-set path; shape-matched spill rewrites
-            # remain for plans the fragmenter cannot tile
-            frags = streaming.plan_streaming(self, plan, int(limit))
-            if frags is not None:
-                return streaming.execute_streaming(
-                    self, plan, frags, int(limit)
-                )
-            sp = spill.plan_spill(self, plan, int(limit))
-            if sp is not None:
-                return spill.execute_spilled_aggregation(self, plan, *sp)
-            sp = spill.plan_join_spill(self, plan, int(limit))
-            if sp is not None:
-                return spill.execute_spilled_join(self, plan, *sp)
-            sp = spill.plan_sort_spill(self, plan, int(limit))
-            if sp is not None:
-                return spill.execute_spilled_sort(self, plan, *sp)
-            sp = spill.plan_window_spill(self, plan, int(limit))
-            if sp is not None:
-                return spill.execute_spilled_window(self, plan, *sp)
+            with TRACER.span("stream_plan"):
+                out_of_core = self._plan_out_of_core(plan, int(limit))
+            if out_of_core is not None:
+                return out_of_core()
         # 1. host side: load scans, collect dictionaries — or adopt the
         # arrays a streaming prefetcher loaded on a background thread
         # while the previous tile computed on-device (double buffering)
-        pre = getattr(self, "_preloaded", None)
-        if pre is not None and pre[0] is plan:
-            _, scans, dicts, counts = pre
-            self._preloaded = None
-        else:
-            scans = {}
-            dicts = {}
-            counts = {}
-            self._load_scans(plan, scans, dicts, counts)
-        self._account_memory(scans, limit)
         pool = self.config.get("memory_pool")
         manager = self.config.get("memory_manager")
         self.device_bytes = 0
-        if manager is not None:
-            # HBM tier: every kernel is static-shape, so the device
-            # working set (padded batches + compiled program) is known
-            # before dispatch; a query that would blow HBM is blocked,
-            # spilled via revocation, or failed cleanly here instead of
-            # kernel-faulting the backend
-            from ..memory import QueryKilledError
-            from ..utils.memory import ExceededMemoryLimitError
-            from .streaming import estimate_program_bytes
+        exceeded = None
+        with TRACER.span("load_scans"):
+            pre = getattr(self, "_preloaded", None)
+            if pre is not None and pre[0] is plan:
+                _, scans, dicts, counts = pre
+                self._preloaded = None
+            else:
+                scans = {}
+                dicts = {}
+                counts = {}
+                self._load_scans(plan, scans, dicts, counts)
+            self._account_memory(scans, limit)
+            if manager is not None:
+                # HBM tier: every kernel is static-shape, so the device
+                # working set (padded batches + compiled program) is known
+                # before dispatch; a query that would blow HBM is blocked,
+                # spilled via revocation, or failed cleanly here instead
+                # of kernel-faulting the backend
+                from ..memory import QueryKilledError
+                from ..utils.memory import ExceededMemoryLimitError
+                from .streaming import estimate_program_bytes
 
-            est = int(max(self.scan_bytes,
-                          estimate_program_bytes(self, plan)))
-            try:
-                _blk_t0 = time.perf_counter()
-                manager.reserve(
-                    self.query_id, est, tier="device",
-                    timeout=float(
-                        self.config.get("memory_blocked_timeout_s") or 0.0
-                    ),
-                )
-                self.blocked_memory_s += time.perf_counter() - _blk_t0
-                self.device_bytes = est
-            except ExceededMemoryLimitError as exc:
-                manager.free(self.query_id, self.scan_bytes, tier="host")
-                self.scan_bytes = 0
-                if isinstance(exc, QueryKilledError):
-                    raise
+                est = int(max(self.scan_bytes,
+                              estimate_program_bytes(self, plan)))
+                try:
+                    _blk_t0 = time.perf_counter()
+                    manager.reserve(
+                        self.query_id, est, tier="device",
+                        timeout=float(
+                            self.config.get("memory_blocked_timeout_s")
+                            or 0.0
+                        ),
+                    )
+                    self.blocked_memory_s += time.perf_counter() - _blk_t0
+                    self.device_bytes = est
+                except ExceededMemoryLimitError as exc:
+                    manager.free(
+                        self.query_id, self.scan_bytes, tier="host"
+                    )
+                    self.scan_bytes = 0
+                    exceeded = exc
+        if exceeded is not None:
+            # outside the span: a streamed re-run opens its own phases
+            if not isinstance(exceeded, QueryKilledError):
                 out = self._try_forced_streaming(plan)
                 if out is not None:
                     return out
-                raise
+            raise exceeded
         try:
             self.dicts = dicts
             self.group_capacity = int(
@@ -701,9 +683,12 @@ class LocalExecutor:
                         )
                         self._last_crumb = bc
                         led_t0 = time.perf_counter()
-                        out_lanes, sel, ordered, checks = self._dispatch(
-                            lambda: self._run(plan, ctx), bc
-                        )
+                        with TRACER.span("launch"):
+                            out_lanes, sel, ordered, checks = (
+                                self._dispatch(
+                                    lambda: self._run(plan, ctx), bc
+                                )
+                            )
                         self._ledger_bracket(
                             (out_lanes, sel), "eager-%d" % attempt,
                             "eager", plan, scans, led_t0,
@@ -746,17 +731,18 @@ class LocalExecutor:
                             cause=ev["cause"],
                         )
                     last = getattr(self, "_last_crumb", None)
-                    (dup_vals, check_vals, coll_vals, wide_vals,
-                     sflag_vals, host_lanes, sel_np) = self._device_get(
-                        ([d for _, d in dups],
-                         [ng for ng, _, _ in checks],
-                         list(colls), list(wides), list(sflags),
-                         {s: out_lanes[s] for s in plan.symbols}, sel),
-                        self._dispatch_crumb(
-                            last.kernel if last else "device_get",
-                            "device_get",
-                        ),
-                    )
+                    with TRACER.span("device_get"):
+                        (dup_vals, check_vals, coll_vals, wide_vals,
+                         sflag_vals, host_lanes, sel_np) = self._device_get(
+                            ([d for _, d in dups],
+                             [ng for ng, _, _ in checks],
+                             list(colls), list(wides), list(sflags),
+                             {s: out_lanes[s] for s in plan.symbols}, sel),
+                            self._dispatch_crumb(
+                                last.kernel if last else "device_get",
+                                "device_get",
+                            ),
+                        )
                 except jax.errors.JaxRuntimeError as e:
                     # a compile-time HBM OOM is PERMANENT for the
                     # monolithic program — XLA's buffer assignment proved
@@ -846,8 +832,11 @@ class LocalExecutor:
                 )
                 for k in list(hints)[:-512]:
                     hints.pop(k, None)
-            self._finalize_kernel_profile(scans, counts, host_lanes, sel_np)
-            return self._materialize_host(plan, host_lanes, sel_np)
+            with TRACER.span("materialize_host"):
+                self._finalize_kernel_profile(
+                    scans, counts, host_lanes, sel_np
+                )
+                return self._materialize_host(plan, host_lanes, sel_np)
         finally:
             if manager is not None:
                 manager.free(self.query_id, self.scan_bytes, tier="host")
@@ -859,6 +848,38 @@ class LocalExecutor:
                 pool.free(self.query_id, self.scan_bytes)
 
     # ------------------------------------------------------------------
+    def _plan_out_of_core(self, plan, limit: int):
+        """The out-of-core planners, tried in order before the resident
+        path; returns the chosen path as a thunk, or None to stay
+        resident."""
+        from . import spill, streaming
+
+        # DISTINCT aggregation first: the streaming fragmenter keeps
+        # a distinct Aggregate single-step behind one hash exchange,
+        # which locally gathers every input row into one in-memory
+        # fragment — the spill rewrite partitions host-side instead
+        sp = spill.plan_distinct_spill(self, plan, limit)
+        if sp is not None:
+            return lambda: spill.execute_spilled_distinct(self, plan, *sp)
+        # streaming (fragment-tiled) execution next: the general
+        # bounded-working-set path; shape-matched spill rewrites
+        # remain for plans the fragmenter cannot tile
+        frags = streaming.plan_streaming(self, plan, limit)
+        if frags is not None:
+            return lambda: streaming.execute_streaming(
+                self, plan, frags, limit
+            )
+        for planner, run in (
+            (spill.plan_spill, spill.execute_spilled_aggregation),
+            (spill.plan_join_spill, spill.execute_spilled_join),
+            (spill.plan_sort_spill, spill.execute_spilled_sort),
+            (spill.plan_window_spill, spill.execute_spilled_window),
+        ):
+            sp = planner(self, plan, limit)
+            if sp is not None:
+                return lambda: run(self, plan, *sp)
+        return None
+
     def _try_forced_streaming(self, plan) -> Optional[Page]:
         """Compile-OOM fallback: re-run the query through the streaming
         tiled executor even though the scan-bytes gate did not trigger —
@@ -1218,23 +1239,25 @@ class LocalExecutor:
             if spec["table"] == "lineitem" else None
         )
         lo, hi, sf = int(spec["lo"]), int(spec["hi"]), float(spec["sf"])
-        t0 = time.time()
-        compiled = tpch_device.compile_lanes(
-            spec["table"], cols, lo, hi, cap, sf, cap_orders=cap_orders
-        )
-        compile_s = time.time() - t0
-        t0 = time.perf_counter()
-        # blocked: generation is timed (and watched) as its own dispatch
-        # instead of landing on the query's device_get
-        lanes = self._dispatch(
-            lambda: jax.block_until_ready(  # dispatch-guard: ok (in thunk)
-                tpch_device.device_lanes(
-                    spec["table"], cols, lo, hi, cap, sf,
-                    int(spec["count"]), cap_orders=cap_orders,
-                )
-            ),
-            bc,
-        )
+        with TRACER.span("devgen", table=spec["table"]) as sp:
+            t0 = time.time()
+            compiled = tpch_device.compile_lanes(
+                spec["table"], cols, lo, hi, cap, sf, cap_orders=cap_orders
+            )
+            compile_s = time.time() - t0
+            sp.attributes["compiled"] = bool(compiled)
+            t0 = time.perf_counter()
+            # blocked: generation is timed (and watched) as its own
+            # dispatch instead of landing on the query's device_get
+            lanes = self._dispatch(
+                lambda: jax.block_until_ready(  # dispatch-guard: ok (in thunk)
+                    tpch_device.device_lanes(
+                        spec["table"], cols, lo, hi, cap, sf,
+                        int(spec["count"]), cap_orders=cap_orders,
+                    )
+                ),
+                bc,
+            )
         prof = self.kernel_profile
         prof["devgenWallS"] = (
             prof.get("devgenWallS", 0.0) + time.perf_counter() - t0
@@ -1550,69 +1573,72 @@ class LocalExecutor:
         # share one executable.
         from ..cache.compile_cache import fragment_key, stable_key_digest
 
-        key, order, by_ord = fragment_key(
-            self, plan, scans, counts, self.ladder.quantize
-        )
-        # prep is keyed by plan ordinal, NOT id(node): dict keys are part
-        # of the jit pytree structure, so id-based keys would force a
-        # retrace (into the WRONG captured plan) for every session sharing
-        # an entry; ordinals make the structure session-invariant
-        prep = {}
-        donatable_ords = set()
-        for nid, arrays in scans.items():
-            lanes = dict(self._device_lanes(
-                self._scan_nodes.get(nid), arrays, counts[nid], nid
-            ))
-            # the true row count rides as a TRACED scalar: baking it as
-            # a constant would specialize the executable per exact count
-            # (streaming tiles differ by a few rows while sharing the
-            # padded shape — they must share one program)
-            lanes["__count__"] = jnp.asarray(counts[nid], dtype=jnp.int64)
-            o = order.get(nid, nid)
-            prep[o] = lanes
-            if getattr(self, "_lane_donatable", {}).get(nid):
-                donatable_ords.add(o)
-        # donation split: per-dispatch scan uploads ride in a separate
-        # pytree arg the compiled program may consume in place
-        # (donate_argnums, per the pjit residency protocol) — the
-        # copy-on-write round trip for every tile page disappears.
-        # Cache-resident lanes (scan cache hits, streaming build tables)
-        # stay in the non-donated arg.  CPU donation is a no-op warning,
-        # so only a real accelerator backend donates.
-        donate = (
-            bool(self.config.get("donate_pages", True))
-            and not self._device_fallback
-            and jax.default_backend() != "cpu"
-        )
-        if not donate:
+        # one phase: the fragment key, the lanes it is called with, the
+        # donation split and the cache lookup
+        with TRACER.span("device_lanes"):
+            key, order, by_ord = fragment_key(
+                self, plan, scans, counts, self.ladder.quantize
+            )
+            # prep is keyed by plan ordinal, NOT id(node): dict keys are part
+            # of the jit pytree structure, so id-based keys would force a
+            # retrace (into the WRONG captured plan) for every session sharing
+            # an entry; ordinals make the structure session-invariant
+            prep = {}
             donatable_ords = set()
-        # the split is part of the traced structure AND of the executable
-        # contract, so it keys the cache alongside the fused-agg mode
-        key = key + (
-            ("donate", donate, tuple(sorted(donatable_ords))),
-            ("megakernels", self._megakernel_mode()),
-        )
-        digest = stable_key_digest(key)[:12]
-        resident_prep = {
-            o: v for o, v in prep.items() if o not in donatable_ords
-        }
-        tile_prep = {
-            o: v for o, v in prep.items() if o in donatable_ords
-        }
-        if donate and tile_prep:
-            self.kernel_profile["donated_dispatches"] = (
-                self.kernel_profile.get("donated_dispatches", 0) + 1
+            for nid, arrays in scans.items():
+                lanes = dict(self._device_lanes(
+                    self._scan_nodes.get(nid), arrays, counts[nid], nid
+                ))
+                # the true row count rides as a TRACED scalar: baking it as
+                # a constant would specialize the executable per exact count
+                # (streaming tiles differ by a few rows while sharing the
+                # padded shape — they must share one program)
+                lanes["__count__"] = jnp.asarray(counts[nid], dtype=jnp.int64)
+                o = order.get(nid, nid)
+                prep[o] = lanes
+                if getattr(self, "_lane_donatable", {}).get(nid):
+                    donatable_ords.add(o)
+            # donation split: per-dispatch scan uploads ride in a separate
+            # pytree arg the compiled program may consume in place
+            # (donate_argnums, per the pjit residency protocol) — the
+            # copy-on-write round trip for every tile page disappears.
+            # Cache-resident lanes (scan cache hits, streaming build tables)
+            # stay in the non-donated arg.  CPU donation is a no-op warning,
+            # so only a real accelerator backend donates.
+            donate = (
+                bool(self.config.get("donate_pages", True))
+                and not self._device_fallback
+                and jax.default_backend() != "cpu"
             )
-            self.kernel_profile["donated_bytes"] = (
-                self.kernel_profile.get("donated_bytes", 0)
-                + sum(
-                    int(getattr(x, "nbytes", 0) or 0)
-                    for lanes in tile_prep.values()
-                    for lane in lanes.values()
-                    for x in (lane if isinstance(lane, tuple) else (lane,))
+            if not donate:
+                donatable_ords = set()
+            # the split is part of the traced structure AND of the executable
+            # contract, so it keys the cache alongside the fused-agg mode
+            key = key + (
+                ("donate", donate, tuple(sorted(donatable_ords))),
+                ("megakernels", self._megakernel_mode()),
+            )
+            digest = stable_key_digest(key)[:12]
+            resident_prep = {
+                o: v for o, v in prep.items() if o not in donatable_ords
+            }
+            tile_prep = {
+                o: v for o, v in prep.items() if o in donatable_ords
+            }
+            if donate and tile_prep:
+                self.kernel_profile["donated_dispatches"] = (
+                    self.kernel_profile.get("donated_dispatches", 0) + 1
                 )
-            )
-        entry = cache.get(key)
+                self.kernel_profile["donated_bytes"] = (
+                    self.kernel_profile.get("donated_bytes", 0)
+                    + sum(
+                        int(getattr(x, "nbytes", 0) or 0)
+                        for lanes in tile_prep.values()
+                        for lane in lanes.values()
+                        for x in (lane if isinstance(lane, tuple) else (lane,))
+                    )
+                )
+            entry = cache.get(key)
         if entry is None:
             cell: Dict[str, object] = {}
             # ordinal -> id(node) of the TRACING plan, for the closure
@@ -1653,6 +1679,9 @@ class LocalExecutor:
             # tracer span carries it: ladder rung > persistent-tier load
             # > shape miss vs first compile
             family = self._compile_family(plan)
+            # the program's name in profiles and idle-gap labels: the
+            # family, so every padding bucket of one plan reads alike
+            raw.__name__ = raw.__qualname__ = "frag_" + family
             persistent = bool(
                 getattr(cache, "persistent_known", None) is not None
                 and cache.persistent_known(key)
@@ -1690,9 +1719,10 @@ class LocalExecutor:
                 fn = self._compile_fragment(fn, resident_prep, tile_prep)
                 compile_s = time.time() - compile_start
                 led_t0 = time.perf_counter()
-                out = self._dispatch(
-                    lambda: fn(resident_prep, tile_prep), bc
-                )
+                with TRACER.span("launch"):
+                    out = self._dispatch(
+                        lambda: fn(resident_prep, tile_prep), bc
+                    )
                 self._ledger_bracket(out, digest, "jit", plan, scans, led_t0)
             _compile_obs.record_compile(
                 kernel=digest, family=family, cause=cause,
@@ -1716,12 +1746,13 @@ class LocalExecutor:
             self.dicts.update(cell["dicts"])
             # dispatch is async: a runtime error of this execution
             # surfaces at the execute() loop's device_get
-            bc = self._dispatch_crumb(digest, "jit", prep)
-            self._last_crumb = bc
-            led_t0 = time.perf_counter()
-            out = self._dispatch(
-                lambda: entry["fn"](resident_prep, tile_prep), bc
-            )
+            with TRACER.span("launch"):
+                bc = self._dispatch_crumb(digest, "jit", prep)
+                self._last_crumb = bc
+                led_t0 = time.perf_counter()
+                out = self._dispatch(
+                    lambda: entry["fn"](resident_prep, tile_prep), bc
+                )
             self._ledger_bracket(out, digest, "jit", plan, scans, led_t0)
             self._record_kernel(digest, compile_s=0.0, cached=True)
         out_lanes, sel, ngroups, dup_vals, colls, wides, sflags = out
@@ -1753,12 +1784,13 @@ class LocalExecutor:
         # single device->host transfer for the selection mask and every
         # output lane (per-array np.asarray would pay one host sync each)
         last = getattr(self, "_last_crumb", None)
-        host_lanes, sel_np = self._device_get(
-            ({s: lanes[s] for s in plan.symbols}, sel),
-            self._dispatch_crumb(
-                last.kernel if last else "materialize", "device_get"
-            ),
-        )
+        with TRACER.span("device_get"):
+            host_lanes, sel_np = self._device_get(
+                ({s: lanes[s] for s in plan.symbols}, sel),
+                self._dispatch_crumb(
+                    last.kernel if last else "materialize", "device_get"
+                ),
+            )
         return self._materialize_host(plan, host_lanes, sel_np)
 
     def _materialize_host(self, plan: P.Output, host_lanes, sel_np) -> Page:

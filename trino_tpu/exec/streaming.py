@@ -33,6 +33,7 @@ from typing import Dict, List, Optional
 from ..page import Page
 from ..plan import nodes as P
 from ..plan.fragment import fragment_plan
+from ..utils.tracing import TRACER
 
 # a tile's scan working set is bounded to limit/SAFETY so scan arrays +
 # kernel temporaries + partial state fit together (same factor the spill
@@ -341,21 +342,28 @@ def execute_streaming(executor, plan: P.Output, frags, memory_limit: int) -> Pag
             est_tile_rows = executor.ladder.quantize(max(est_tile_rows, 128))
             tile_starts = list(range(0, len(splits), per))
 
+            # the pool thread's spans join the query's trace
+            query_span = TRACER.current_span()
+
             def make_loaded(i: int) -> FragmentExecutor:
-                cfg = tile_config()
-                if est_tile_rows:
-                    cfg["scan_cap_override"] = est_tile_rows
-                fe = FragmentExecutor(
-                    executor.catalogs, cfg,
-                    {idx: splits[i: i + per]}, remote,
-                )
-                fe._streaming_cache = run_cache
-                fe.preload(f.root)
-                # start the next tile's H2D copies on this (prefetch)
-                # thread: jnp.asarray enqueues the transfer async, so it
-                # overlaps the CURRENT tile's kernel instead of
-                # serializing in front of the next dispatch
-                fe.preupload(f.root)
+                with TRACER.span("tile_stage", parent=query_span,
+                                 tile=i // per):
+                    cfg = tile_config()
+                    if est_tile_rows:
+                        cfg["scan_cap_override"] = est_tile_rows
+                    fe = FragmentExecutor(
+                        executor.catalogs, cfg,
+                        {idx: splits[i: i + per]}, remote,
+                    )
+                    fe._streaming_cache = run_cache
+                    with TRACER.span("tile_load"):
+                        fe.preload(f.root)
+                    # start the next tile's H2D copies on this (prefetch)
+                    # thread: jnp.asarray enqueues the transfer async, so
+                    # it overlaps the CURRENT tile's kernel instead of
+                    # serializing in front of the next dispatch
+                    with TRACER.span("tile_upload"):
+                        fe.preupload(f.root)
                 return fe
 
             # double-buffered tile pipeline: while tile i computes on the
@@ -380,7 +388,9 @@ def execute_streaming(executor, plan: P.Output, frags, memory_limit: int) -> Pag
                 )
                 nexti = depth
                 while pending:
-                    fe = pending.popleft().result()
+                    tile = len(out)
+                    with TRACER.span("tile_wait", tile=tile):
+                        fe = pending.popleft().result()
                     if nexti < len(tile_starts):
                         pending.append(
                             prefetch.submit(
@@ -388,8 +398,10 @@ def execute_streaming(executor, plan: P.Output, frags, memory_limit: int) -> Pag
                             )
                         )
                         nexti += 1
-                    out.append(fe.execute(f.root))
-                    _merge_tile_counters(executor, fe)
+                    with TRACER.span("tile_execute", tile=tile,
+                                     fragment=fid):
+                        out.append(fe.execute(f.root))
+                        _merge_tile_counters(executor, fe)
             pages_by_fragment[fid] = out
         else:
             splits_by_scan = {}
@@ -402,8 +414,9 @@ def execute_streaming(executor, plan: P.Output, frags, memory_limit: int) -> Pag
                 executor.catalogs, tile_config(), splits_by_scan, remote
             )
             fe._streaming_cache = run_cache
-            pages_by_fragment[fid] = [fe.execute(f.root)]
-            _merge_tile_counters(executor, fe)
+            with TRACER.span("tile_execute", tile=0, fragment=fid):
+                pages_by_fragment[fid] = [fe.execute(f.root)]
+                _merge_tile_counters(executor, fe)
         done.add(fid)
 
     run_fragment(0)

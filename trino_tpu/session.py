@@ -342,64 +342,72 @@ class Session:
         from .security import Identity
 
         identity = Identity(user) if user else self.identity
-        query_id = f"q_{uuid.uuid4().hex[:12]}"
-        created = self.events.query_created(query_id, sql)
-        entry = {
-            "query_id": query_id, "sql": sql, "state": "RUNNING",
-            "user": identity.user, "created": created,
-        }
-        self.history.put(entry)
+        with self.tracer.span("query_admit") as admit:
+            query_id = f"q_{uuid.uuid4().hex[:12]}"
+            created = self.events.query_created(query_id, sql)
+            entry = {
+                "query_id": query_id, "sql": sql, "state": "RUNNING",
+                "user": identity.user, "created": created,
+            }
+            self.history.put(entry)
+        # query_admit, query and query_finish follow one another: the
+        # later two join the first one's trace as its children
         try:
-            with self.tracer.span("query", query_id=query_id):
+            with self.tracer.span("query", parent=admit, query_id=query_id):
                 with self.tracer.span("parse"):
                     stmt = parse(sql)
                 self.access_control.check_can_execute_query(identity)
                 page = self._execute_statement(
                     stmt, sql, query_id, identity
                 )
-            self.events.query_completed(
-                query_id, sql, "FINISHED", created, page.count
-            )
-            entry.update(
-                state="FINISHED", finished=time.time(),
-                rows=page.count, wall_s=time.time() - created,
-            )
-            # only THIS query's timeline (last_timeline is kept across
-            # queries so system.runtime.operator_stats can read it)
-            tl = self.last_timeline
-            if tl and tl.get("queryId") == query_id:
-                entry["operators"] = tl.get("operators")
-            self.history.put(entry)
-            self._finalize_doctor(query_id, created)
+            with self.tracer.span("query_finish", parent=admit,
+                                  state="FINISHED"):
+                self.events.query_completed(
+                    query_id, sql, "FINISHED", created, page.count
+                )
+                entry.update(
+                    state="FINISHED", finished=time.time(),
+                    rows=page.count, wall_s=time.time() - created,
+                )
+                # only THIS query's timeline (last_timeline is kept across
+                # queries so system.runtime.operator_stats can read it)
+                tl = self.last_timeline
+                if tl and tl.get("queryId") == query_id:
+                    entry["operators"] = tl.get("operators")
+                self.history.put(entry)
+                self._finalize_doctor(query_id, created)
             return page
         except Exception as e:
             from .obs.doctor import classify_error
 
-            self.events.query_completed(
-                query_id, sql, "FAILED", created, error=str(e)
-            )
-            entry.update(
-                state="FAILED", finished=time.time(),
-                error=str(e), error_code=classify_error(e),
-                wall_s=time.time() - created,
-            )
-            self.history.put(entry)
-            try:
-                from .obs import journal
-
-                journal.emit(
-                    journal.QUERY_FAILED, query_id=query_id,
-                    severity=journal.ERROR, error=str(e)[:400],
-                    errorCode=classify_error(e),
+            with self.tracer.span("query_finish", parent=admit,
+                                  state="FAILED"):
+                self.events.query_completed(
+                    query_id, sql, "FAILED", created, error=str(e)
                 )
-            except Exception:  # noqa: BLE001 — journaling is best-effort
-                pass
-            self._finalize_doctor(query_id, created, error=e)
+                entry.update(
+                    state="FAILED", finished=time.time(),
+                    error=str(e), error_code=classify_error(e),
+                    wall_s=time.time() - created,
+                )
+                self.history.put(entry)
+                try:
+                    from .obs import journal
+
+                    journal.emit(
+                        journal.QUERY_FAILED, query_id=query_id,
+                        severity=journal.ERROR, error=str(e)[:400],
+                        errorCode=classify_error(e),
+                    )
+                except Exception:  # noqa: BLE001 — journaling is best-effort
+                    pass
+                self._finalize_doctor(query_id, created, error=e)
             raise
         finally:
             # batch-export completed spans on EVERY completion path —
             # success, failure, and non-Query statements alike (no-op
-            # without an attached OTLP exporter)
+            # without an attached OTLP exporter); query_finish has closed
+            # by now, so the batch holds it
             self.tracer.flush()
 
     def _finalize_doctor(self, query_id: str, created: float,

@@ -9,27 +9,116 @@ in-memory recorder; an exporter can forward to a real OTel endpoint.
 from __future__ import annotations
 
 import collections
-import contextlib
-import dataclasses
+import itertools
+import os
 import threading
 import time
-import uuid
 from typing import Any, Dict, List, Optional
 
+# ids: a per-process random prefix and a counter, formatted to the W3C
+# widths (32 hex trace id, 16 hex span id) — unique across the processes
+# of one distributed trace without a uuid4 per span
+_TRACE_PREFIX = os.urandom(8).hex()
+_SPAN_PREFIX = os.urandom(3).hex()
+_next_id = itertools.count(1).__next__
 
-@dataclasses.dataclass
+_annotation = None
+
+
+def _load_annotation():
+    """`jax.profiler.TraceAnnotation`, imported on first use: while a
+    profile runs the profiler records every span on the host plane, on
+    its own clock, beside the PJRT events."""
+    global _annotation
+    from jax.profiler import TraceAnnotation
+
+    _annotation = TraceAnnotation
+    return TraceAnnotation
+
+
 class Span:
-    name: str
-    trace_id: str
-    span_id: str
-    parent_id: Optional[str]
-    start: float
-    end: Optional[float] = None
-    attributes: Dict[str, Any] = dataclasses.field(default_factory=dict)
+    """One span, and its own context manager (opened by `Tracer.span`).
+
+    `start` is the wall clock (OTLP); the duration comes from
+    `time.perf_counter_ns()`, which cannot step.  The hex ids are
+    formatted when first read: most spans are leaves nobody asks."""
+
+    __slots__ = (
+        "name", "start", "attributes", "_tracer", "_remote", "_parent",
+        "_trace", "_n", "_span_id", "_stack", "_t0", "_ns", "_note",
+    )
+
+    def __init__(self, tracer: "Tracer", name: str,
+                 traceparent: Optional[str], parent: Optional["Span"],
+                 attributes: Dict[str, Any]):
+        self._tracer = tracer
+        self.name = name
+        self._remote = traceparent
+        self._parent = parent
+        self.attributes = attributes
+        self._ns = None
+
+    def __enter__(self) -> "Span":
+        local = self._tracer._local
+        try:
+            stack = local.stack
+        except AttributeError:
+            stack = local.stack = []
+        if stack:
+            self._parent = stack[-1]   # a local parent wins
+        parent = self._parent
+        if parent is not None:
+            self._trace = parent._trace
+        else:
+            remote = self._remote
+            if remote is not None:
+                remote = self._remote = parse_traceparent(remote)
+            # a remote trace id as it came, or this process's counter
+            self._trace = remote["trace_id"] if remote else _next_id()
+        self._n = _next_id()
+        self._span_id = None
+        stack.append(self)
+        self._stack = stack
+        note = self._note = (_annotation or _load_annotation())(self.name)
+        self.start = time.time()
+        note.__enter__()
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self._ns = time.perf_counter_ns() - self._t0
+        self._note.__exit__(exc_type, exc, tb)
+        self._stack.pop()
+        self._tracer.spans.append(self)
+
+    @property
+    def trace_id(self) -> str:
+        t = self._trace
+        return t if isinstance(t, str) else "%s%016x" % (_TRACE_PREFIX, t)
+
+    @property
+    def span_id(self) -> str:
+        sid = self._span_id
+        if sid is None:
+            sid = self._span_id = "%s%010x" % (_SPAN_PREFIX, self._n)
+        return sid
+
+    @property
+    def parent_id(self) -> Optional[str]:
+        if self._parent is not None:
+            return self._parent.span_id
+        return self._remote["parent_id"] if self._remote else None
+
+    @property
+    def end(self) -> Optional[float]:
+        return None if self._ns is None else self.start + self._ns / 1e9
 
     @property
     def duration_ms(self) -> float:
-        return ((self.end or time.time()) - self.start) * 1000
+        ns = self._ns
+        if ns is None:
+            ns = time.perf_counter_ns() - self._t0
+        return ns / 1e6
 
     @property
     def traceparent(self) -> str:
@@ -77,63 +166,32 @@ class Tracer:
 
     def __init__(self, max_spans: int = DEFAULT_MAX_SPANS):
         self.max_spans = max_spans
+        # appended and drained from several threads: deque.append and
+        # popleft are atomic, so no lock sits on the span path
         self.spans: "collections.deque[Span]" = collections.deque(maxlen=max_spans)
         self.exporter: Optional["OtlpFileExporter"] = None
         self._local = threading.local()
-        self._lock = threading.Lock()
 
-    def _stack(self) -> List[Span]:
-        if not hasattr(self._local, "stack"):
-            self._local.stack = []
-        return self._local.stack
-
-    @contextlib.contextmanager
-    def span(self, name: str, traceparent: Optional[str] = None, **attributes):
-        """Open a span.  A remote `traceparent` joins that trace when the
-        calling thread has no local parent (the Dapper cross-process link:
-        coordinator->worker dispatch, exchange fetch threads)."""
-        stack = self._stack()
-        parent = stack[-1] if stack else None
-        if parent is not None:
-            trace_id, parent_id = parent.trace_id, parent.span_id
-        else:
-            remote = parse_traceparent(traceparent)
-            if remote is not None:
-                trace_id, parent_id = remote["trace_id"], remote["parent_id"]
-            else:
-                trace_id, parent_id = uuid.uuid4().hex, None
-        s = Span(
-            name=name,
-            trace_id=trace_id,
-            span_id=uuid.uuid4().hex[:16],
-            parent_id=parent_id,
-            start=time.time(),
-            attributes=dict(attributes),
-        )
-        stack.append(s)
-        try:
-            yield s
-        finally:
-            s.end = time.time()
-            stack.pop()
-            with self._lock:
-                self.spans.append(s)
+    def span(self, name: str, traceparent: Optional[str] = None,
+             parent: Optional[Span] = None, **attributes) -> Span:
+        """Open a span (`with tracer.span(...) as s`).  When the calling
+        thread has no span open, `parent` — a Span of this process, open
+        or closed, from any thread (`current_span()` taken where the work
+        was handed over) — or else a remote `traceparent` (the Dapper
+        cross-process link: coordinator->worker dispatch, exchange fetch
+        threads) gives the new span its trace and its parent."""
+        return Span(self, name, traceparent, parent, attributes)
 
     def current_span(self) -> Optional[Span]:
-        stack = self._stack()
+        stack = getattr(self._local, "stack", None)
         return stack[-1] if stack else None
 
     def current_traceparent(self) -> Optional[str]:
         s = self.current_span()
         return s.traceparent if s is not None else None
 
-    def for_trace(self, trace_id: str) -> List[Span]:
-        with self._lock:
-            return [s for s in self.spans if s.trace_id == trace_id]
-
     def clear(self):
-        with self._lock:
-            self.spans.clear()
+        self.spans.clear()
 
     # -- export ---------------------------------------------------------
     def attach_exporter(self, exporter: "OtlpFileExporter"):
@@ -146,9 +204,12 @@ class Tracer:
         exporter = self.exporter
         if exporter is None:
             return
-        with self._lock:
-            spans = list(self.spans)
-            self.spans.clear()
+        spans, ring = [], self.spans
+        try:
+            while True:
+                spans.append(ring.popleft())
+        except IndexError:
+            pass
         if spans:
             exporter.export(spans)
 
@@ -210,8 +271,6 @@ TRACER = Tracer()
 
 # TRINO_TPU_OTLP_FILE wires the process tracer to a file exporter at
 # import (the etc/config.properties tracing.* binding analog)
-import os as _os
-
-_otlp = _os.environ.get("TRINO_TPU_OTLP_FILE")
+_otlp = os.environ.get("TRINO_TPU_OTLP_FILE")
 if _otlp:
     TRACER.attach_exporter(OtlpFileExporter(_otlp))
