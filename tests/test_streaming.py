@@ -118,6 +118,41 @@ def test_multiple_tiles_used(free):
     assert len(created) > 2, f"expected tiled executors, got {len(created)}"
 
 
+def test_streamed_tiles_count_lines_from_the_index(free):
+    """A streamed scan asks for the line count of the same order ranges on
+    every query; from the second execution on the block-prefix index
+    answers (connectors/tpch_device.lineitem_count_hashed) and only the
+    tiles' edge blocks go through the host hash.  The tiles' counter
+    reaches session.last_kernel_profile, and the answers do not change."""
+    from trino_tpu.connectors import tpch, tpch_device
+
+    asked = []
+    orig = tpch_device.lineitem_count_hashed
+
+    def spy(lo, hi):
+        asked.append((lo, hi))
+        return orig(lo, hi)
+
+    s = tpch_session(0.05, query_max_memory_bytes=12_000_000,
+                     result_cache=False)
+    tpch_device.lineitem_count_hashed = spy
+    try:
+        first = s.execute(Q1).to_pylist()
+        assert "lineCountOrdersHashed" in s.last_kernel_profile
+        asked.clear()
+        second = s.execute(Q1).to_pylist()
+    finally:
+        tpch_device.lineitem_count_hashed = orig
+    tiles = len(asked)
+    orders = tpch._counts(0.05)["orders"]
+    assert tiles >= 2 and sorted(asked)[0][0] == 0
+    assert sorted(asked)[-1][1] == orders
+    hashed = s.last_kernel_profile["lineCountOrdersHashed"]
+    assert hashed <= 2 * tiles * (tpch_device.LINE_COUNT_BLOCK - 1)
+    assert hashed < orders  # a full re-hash would read `orders`
+    assert first == second == free.execute(Q1).to_pylist()
+
+
 def test_pure_sort_falls_back_to_spill():
     """Non-reducing plans must refuse streaming (spilled sort owns them:
     tiling a bare scan would re-materialize the table downstream)."""

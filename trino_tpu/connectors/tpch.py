@@ -852,9 +852,11 @@ class TpchConnector(Connector):
         lo = (nb * ords[0]) // tot
         hi = (nb * (ords[-1] + 1)) // tot
         if table == "lineitem":
-            count = tpch_device.lineitem_count(lo, hi)
+            # exact, from the block-prefix index: a range asked for again
+            # (every tile of every streamed query) hashes its edges only
+            count, hashed = tpch_device.lineitem_count_hashed(lo, hi)
         else:
-            count = hi - lo
+            count, hashed = hi - lo, 0
         types = dict(SCHEMAS[table])
         dicts = {
             c: _VOCABS[c]
@@ -866,6 +868,7 @@ class TpchConnector(Connector):
         return {
             "table": table, "lo": lo, "hi": hi, "sf": self.sf,
             "count": count, "dicts": dicts, "widths": widths,
+            "orders_hashed": hashed,
         }
 
 

@@ -1174,6 +1174,11 @@ class LocalExecutor:
             spec = None
         if spec is None:
             return False
+        prof = self.kernel_profile
+        prof["lineCountOrdersHashed"] = (
+            prof.get("lineCountOrdersHashed", 0)
+            + int(spec.get("orders_hashed", 0))
+        )
         sym_of = {c: self._sym_for(node, c) for c in cols}
         count = int(spec["count"])
         merged = {

@@ -158,7 +158,7 @@ def estimate_program_bytes(executor, plan: P.PlanNode) -> float:
 _TILE_COUNTERS = (
     "preuploads", "preupload_bytes", "donated_dispatches",
     "donated_bytes", "fusedAggregates", "fusedTerms", "fusionRejects",
-    "devgenWallS", "devgenCompileS",
+    "devgenWallS", "devgenCompileS", "lineCountOrdersHashed",
 )
 
 
