@@ -482,9 +482,10 @@ class Smoke:
         shrinks = [e for e in journal.get_journal().tail()
                    if journal.MESH_SHRINK in json.dumps(e, default=str)]
         self.expect(not shrinks, "no mesh_shrink journal event")
-        # mesh scans are host-generated and uploaded sharded (the mesh
-        # executor has no device generator), so no devgen expectation
-        self.check_device_did_the_work(sessions, set())
+        # mesh scans are generated on the mesh, each shard in its own
+        # chip's HBM, and kept in the session's scan cache
+        self.check_device_did_the_work(
+            sessions, {"lineitem", "orders", "customer"})
 
     def main(self):
         self.start()

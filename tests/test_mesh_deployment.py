@@ -1,0 +1,307 @@
+"""TPC-H on a four-device mesh as a deployment: lineitem (orders, customer)
+sharded by order range, every shard generated on its own device and kept
+there in the session's scan cache, one cached SPMD program a fragment.
+
+The plain reference is the benchmark's (`benchmark/queries/*.py` over
+`benchmark/datagen.py`: numpy, exact scaled integers, nothing of the
+program); four of conftest's eight virtual CPU devices stand in for the
+four chips of one host."""
+import importlib.util
+import json
+import os
+import sys
+import threading
+
+import jax
+import numpy as np
+import pytest
+
+from trino_tpu.connectors import tpch, tpch_device
+from trino_tpu.exec.shapes import resolve_ladder
+from trino_tpu.obs import compile_observatory
+from trino_tpu.parallel import mesh_executor as MX
+from trino_tpu.session import tpch_session
+
+SF = 0.01
+NDEV = 4
+SEEDS = (11, 2800000007, 2**31 + 5)
+BENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark")
+PHASES = ("load_scans", "device_lanes", "launch", "device_get",
+          "materialize_host")
+
+
+def _bench_module(*parts):
+    """A file of benchmark/ under a name of its own (tests/ and benchmark/
+    both have short module names)."""
+    qdir = os.path.join(BENCH, "queries")
+    if qdir not in sys.path:
+        sys.path.append(qdir)   # the queries import their `_rows`
+    name = "bench_" + "_".join(parts)
+    if name not in sys.modules:
+        spec = importlib.util.spec_from_file_location(
+            name, os.path.join(BENCH, *parts) + ".py")
+        sys.modules[name] = mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+    return sys.modules[name]
+
+
+def _text(query, seed):
+    q = _bench_module("queries", query)
+    params = q.draw(np.random.default_rng(seed), q.RANGES)
+    return q, params, q.sql(params)
+
+
+def _mesh_session(ndev=NDEV, **props):
+    assert len(jax.devices()) >= ndev, "conftest provides 8 virtual devices"
+    return tpch_session(SF, distributed=True, num_devices=ndev,
+                        device_cpu_fallback=False, result_cache=False, **props)
+
+
+@pytest.fixture(scope="module")
+def mesh():
+    return _mesh_session()
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    return tpch_session(SF, device_cpu_fallback=False, result_cache=False)
+
+
+# -- (a) answers ------------------------------------------------------------
+
+
+@pytest.mark.parametrize("seed", SEEDS)
+@pytest.mark.parametrize("query", ["q1", "q6", "q3"])
+def test_mesh_answer_equals_the_reference_and_one_chip(query, seed, mesh,
+                                                       one_chip):
+    q, params, sql = _text(query, seed)
+    rows = mesh.execute(sql).to_pylist()
+    refs, ref_rows = q.reference(_bench_module("datagen"), SF, [params])
+    assert q.check(rows, refs[0]), (params, rows[:3])
+    assert rows == one_chip.execute(sql).to_pylist()
+    assert ref_rows["lineitem"] == tpch_device.lineitem_count(
+        0, tpch._counts(SF)["orders"])
+    prof = mesh.last_kernel_profile
+    assert [k["digest"][:7] for k in prof["kernels"]] == ["mesh:%d/" % NDEV]
+    shards = prof["scanShards"]
+    assert shards and all(
+        len({dev for dev, _ in sh}) == NDEV for sh in shards.values())
+
+
+# -- (b) the sharded generator ------------------------------------------------
+
+GEN_COLS = {
+    "lineitem": ("l_orderkey", "l_quantity", "l_extendedprice", "l_discount",
+                 "l_tax", "l_returnflag", "l_linestatus", "l_shipdate"),
+    "orders": ("o_orderkey", "o_custkey", "o_orderdate", "o_shippriority",
+               "o_orderstatus"),
+    "customer": ("c_custkey", "c_mktsegment", "c_nationkey"),
+}
+
+
+def _ranges(n, case):
+    if case == "uneven":        # n is not divisible by four
+        cuts = [n * d // NDEV for d in range(NDEV + 1)]
+    else:                       # three devices hold it all, the last nothing
+        cuts = [n * d // (NDEV - 1) for d in range(NDEV)] + [n]
+    return cuts[:-1], cuts[1:]
+
+
+@pytest.mark.parametrize("case", ["uneven", "empty_last_shard"])
+@pytest.mark.parametrize("table", sorted(GEN_COLS))
+def test_shards_concatenated_are_the_one_chip_lanes(table, case):
+    q = resolve_ladder({}).quantize
+    cols = GEN_COLS[table]
+    base = "orders" if table == "lineitem" else table
+    n = tpch._counts(SF)[base] - 3
+    assert n % NDEV
+    lo, hi = _ranges(n, case)
+    if table == "lineitem":
+        counts = [tpch_device.lineitem_count(a, b) for a, b in zip(lo, hi)]
+        cap_orders = q(max(b - a for a, b in zip(lo, hi)))
+        whole_orders = q(n)
+    else:
+        counts = [b - a for a, b in zip(lo, hi)]
+        cap_orders = whole_orders = None
+    assert (counts[-1] == 0) == (case == "empty_last_shard")
+    cap, total = q(max(counts)), sum(counts)
+    mesh = MX.default_mesh(NDEV)
+    assert tpch_device.compile_lanes(
+        table, cols, lo, hi, cap, SF, cap_orders=cap_orders, mesh=mesh
+    ) in (True, False)
+    # compiled ahead: the lanes' own call finds the executable
+    assert not tpch_device.compile_lanes(
+        table, cols, lo, hi, cap, SF, cap_orders=cap_orders, mesh=mesh)
+    sharded = tpch_device.device_lanes(
+        table, cols, lo, hi, cap, SF, counts, cap_orders=cap_orders, mesh=mesh)
+    whole = tpch_device.device_lanes(
+        table, cols, 0, n, q(total), SF, total, cap_orders=whole_orders)
+    for c in cols:
+        v, ok = sharded[c]
+        assert v.shape == (NDEV, cap) and ok.shape == (NDEV, cap)
+        assert len({sh.device.id for sh in v.addressable_shards}) == NDEV
+        assert all(sh.data.shape == (1, cap) for sh in v.addressable_shards)
+        v, ok = np.asarray(v), np.asarray(ok)
+        assert ok.all()
+        live = np.concatenate([v[d, :counts[d]] for d in range(NDEV)])
+        expect = np.asarray(whole[c][0])
+        assert live.dtype == expect.dtype
+        assert np.array_equal(live, expect[:total]), c
+        assert all(not v[d, counts[d]:].any() for d in range(NDEV)), c
+
+
+# -- (c) a warm query generates nothing and compiles nothing ------------------
+
+
+def _drain(session):
+    spans = list(session.tracer.spans)
+    session.tracer.spans.clear()
+    return spans
+
+
+@pytest.mark.parametrize("seed", SEEDS)
+def test_second_execution_finds_lanes_and_program(seed, mesh):
+    _q, _params, sql = _text("q1", seed + 1)
+    first = mesh.execute(sql).to_pylist()
+    observatory = compile_observatory.get_observatory()
+    compiles = sum(observatory.counts.values())
+    _drain(mesh)
+    assert mesh.execute(sql).to_pylist() == first
+    spans = _drain(mesh)
+    prof = mesh.last_kernel_profile
+    assert sum(observatory.counts.values()) == compiles
+    assert prof["summary"]["compiles"] == 0
+    assert prof["meshProgramCache"] == "hit"
+    assert not [s for s in spans if s.name in ("devgen", "xla_compile")]
+    assert not prof.get("devgenWallS")
+    # nothing re-hashed: at most the edge blocks of four ranges
+    assert prof.get("lineCountOrdersHashed", 0) <= \
+        2 * NDEV * tpch_device.LINE_COUNT_BLOCK
+    # the pushed-down DELTA is part of a scan's identity: this text's
+    # entry is the newest
+    key, entry = list(mesh._scan_cache.entries.items())[-1]
+    assert key[1] == "lineitem" and "l_tax" in key[2]
+    assert key[-1] == ("mesh",) + tuple(range(NDEV))
+    assert len(entry["devgen"]["shards"]) == NDEV
+    assert sum(n for _, _, n in entry["devgen"]["shards"]) == entry["total"]
+    assert not [c for c, (v, _) in entry["merged"].items()
+                if hasattr(v, "dtype")]
+    assert set(entry["dev"]) == set(entry["merged"])
+    for v, ok in entry["dev"].values():
+        for lane in (v, ok):
+            assert len({sh.device.id for sh in lane.addressable_shards}) == NDEV
+
+
+# -- (d) the generator compiles ahead of its supervised dispatch --------------
+
+
+def test_sharded_generator_compiles_before_the_supervised_dispatch(
+        monkeypatch):
+    tpch_device.clear_jit_cache()
+    inside = threading.local()
+    seen = []
+    real_dispatch = MX.MeshExecutor._dispatch
+    real_generator = tpch_device._generator
+
+    def dispatch(self, thunk, bc):
+        def watched():
+            inside.crumb = bc.kernel
+            try:
+                return thunk()
+            finally:
+                inside.crumb = None
+        seen.append(("dispatch", bc.kernel))
+        return real_dispatch(self, watched, bc)
+
+    def generator(*a, **kw):
+        fn, compiled_now = real_generator(*a, **kw)
+        seen.append(("generator", getattr(inside, "crumb", None),
+                     compiled_now))
+        return fn, compiled_now
+
+    monkeypatch.setattr(MX.MeshExecutor, "_dispatch", dispatch)
+    monkeypatch.setattr(tpch_device, "_generator", generator)
+    _q, _params, sql = _text("q6", SEEDS[0])
+    s = _mesh_session()
+    assert s.execute(sql).to_pylist()
+    gens = [e for e in seen if e[0] == "generator"]
+    # the compile happened, outside every supervised dispatch ...
+    assert [e for e in gens if e[2]] == [("generator", None, True)]
+    # ... and the supervised generator dispatch only ran the executable
+    assert ("generator", "devgen:lineitem", False) in gens
+    order = [e[:2] for e in seen]
+    assert order.index(("generator", None)) < order.index(
+        ("dispatch", "devgen:lineitem"))
+    assert s.last_kernel_profile["devgenCompileS"] > 0
+    assert s.last_kernel_profile["devgenWallS"] > 0
+    tpch_device.clear_jit_cache()
+
+
+# -- (e) the phase spans -------------------------------------------------------
+
+
+def test_phase_spans_open_once_a_query_and_tile_execute(mesh):
+    _q, _params, sql = _text("q1", SEEDS[0])
+    mesh.execute(sql)
+    shares = []
+    for _ in range(3):   # a share of a few milliseconds: the best of three
+        _drain(mesh)
+        mesh.execute(sql).to_pylist()
+        spans = _drain(mesh)
+        by_name = {}
+        for s in spans:
+            by_name.setdefault(s.name, []).append(s)
+        assert {n: len(by_name.get(n, ())) for n in PHASES + ("execute",)} \
+            == dict.fromkeys(PHASES + ("execute",), 1)
+        execute = by_name["execute"][0]
+        phases = [by_name[n][0] for n in PHASES]
+        # leaves of `execute`, on the query's own trace
+        assert all(p.parent_id == execute.span_id for p in phases)
+        assert len({p.trace_id for p in phases + [execute]}) == 1
+        inside = sum(p.duration_ms for p in phases)
+        assert inside <= execute.duration_ms
+        shares.append(100.0 * (execute.duration_ms - inside)
+                      / execute.duration_ms)
+    assert min(shares) < 10.0, shares
+
+
+# -- (f) another mesh finds neither the lanes nor the program -------------------
+
+
+def test_a_smaller_mesh_generates_and_compiles_its_own(mesh):
+    _q, _params, sql = _text("q6", SEEDS[1])
+    rows = mesh.execute(sql).to_pylist()
+    assert mesh.execute(sql).to_pylist() == rows
+    assert mesh.last_kernel_profile["meshProgramCache"] == "hit"
+    two = _mesh_session(ndev=2)
+    assert two.execute(sql).to_pylist() == rows
+    prof = two.last_kernel_profile
+    assert prof["meshProgramCache"] == "miss" and prof["devgenWallS"] > 0
+    assert prof["summary"]["compiles"] == 1
+    assert {k[-1] for k in two._scan_cache.entries} == {("mesh", 0, 1)}
+    assert all(len({d for d, _ in sh}) == 2
+               for sh in prof["scanShards"].values())
+
+
+def test_a_shrunk_mesh_reuses_neither_lanes_nor_executable():
+    from trino_tpu.runtime.supervisor import QUARANTINED
+
+    _q, _params, sql = _text("q6", SEEDS[2])
+    s = _mesh_session(device_probe_backoff_s=30.0)
+    rows = s.execute(sql).to_pylist()
+    assert s.execute(sql).to_pylist() == rows
+    assert s.last_kernel_profile["meshProgramCache"] == "hit"
+    # the cached program's launch loses device 0: the mesh shrinks to three
+    s.properties.set("fault_injection", json.dumps(
+        {"device_loss": {"nth": 1, "match": "mesh:"}}))
+    assert s.execute(sql).to_pylist() == rows
+    prof = s.last_kernel_profile
+    assert s.device_supervisor.device_state(device_id=0) == QUARANTINED
+    assert prof["meshShrinks"] >= 1
+    assert prof["meshProgramCache"] == "miss" and prof["devgenWallS"] > 0
+    assert [k["digest"][:7] for k in prof["kernels"]][-1] == "mesh:3/"
+    assert {k[-1] for k in s._scan_cache.entries} == {
+        ("mesh", 0, 1, 2, 3), ("mesh", 1, 2, 3)}
+    assert all({d for d, _ in sh} == {1, 2, 3}
+               for sh in prof["scanShards"].values())

@@ -109,7 +109,9 @@ def test_q3_mesh_parity_and_oracle(oracle_conn):
 
 def _capture_hlo(run):
     """Spy on the mesh executor's ahead-of-time compile and return the
-    compiled HLO text of every mesh program `run` triggers."""
+    compiled HLO text of every mesh program `run` triggers (sessions
+    with compile_cache=False: an executable another test already put in
+    the process-wide cache must not stand in for the compile)."""
     texts = []
     orig = MX.MeshExecutor._compile_fragment
 
@@ -128,7 +130,9 @@ def _capture_hlo(run):
 
 def test_mesh_fused_q6_hlo_shows_all_gather():
     texts = _capture_hlo(
-        lambda: _mesh_session(megakernels="on").execute(Q6)
+        lambda: _mesh_session(
+            megakernels="on", compile_cache=False
+        ).execute(Q6)
     )
     merged = [t for t in texts if "all-gather" in t]
     # the fused fragment merges per-shard partials with a tiled
@@ -138,7 +142,9 @@ def test_mesh_fused_q6_hlo_shows_all_gather():
 
 
 def test_mesh_repartition_hlo_shows_device_side_all_to_all():
-    texts = _capture_hlo(lambda: _mesh_session().execute(DISTINCT_SQL))
+    texts = _capture_hlo(
+        lambda: _mesh_session(compile_cache=False).execute(DISTINCT_SQL)
+    )
     assert texts, "no mesh program was compiled"
     ops = set()
     for t in texts:
@@ -225,7 +231,8 @@ def test_grouped_count_distinct_repartitions_not_gathers():
 
     MX._MeshTraceCtx._hash_repartition = spy
     try:
-        mesh = _mesh_session()
+        # the spy sees a trace, and a cached executable needs none
+        mesh = _mesh_session(compile_cache=False)
         got = mesh.execute(DISTINCT_SQL).to_pylist()
     finally:
         MX._MeshTraceCtx._hash_repartition = orig

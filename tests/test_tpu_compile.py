@@ -107,12 +107,46 @@ def test_lineitem_generator_q6_columns_sf1_fits_hbm(one_chip,
                                                     no_persistent_cache):
     q = resolve_ladder({}).quantize
     cols = ("l_quantity", "l_extendedprice", "l_discount", "l_shipdate")
-    fn = tpch_device._gen_lineitem(cols, q(1_500_000), q(ROWS["sf1"]), 1.0)
+    fn = jax.jit(
+        tpch_device._gen_lineitem(cols, q(1_500_000), q(ROWS["sf1"]), 1.0)
+    )
     scalar = jax.ShapeDtypeStruct((), jnp.int64, sharding=one_chip)
     mem = fn.lower(scalar, scalar).compile().memory_analysis()
     total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
              + mem.output_size_in_bytes)
     assert 0 < total < HBM_BYTES
+
+
+def test_sharded_lineitem_generator_q1_columns_sf10_on_four_chips(
+        topo, no_persistent_cache):
+    """The mesh's scan producer at the size of the four-chip cell: one
+    SPMD program, every chip generating its quarter of SF10's order space
+    (3.75 M orders, ~15.0 M rows, the 16,777,216 rung) into its own HBM."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    q = resolve_ladder({}).quantize
+    cols = ("l_quantity", "l_extendedprice", "l_discount", "l_tax",
+            "l_returnflag", "l_linestatus", "l_shipdate")
+    mesh = Mesh(np.array(topo.devices[:4]), ("workers",))
+    fn = jax.jit(tpch_device._per_shard(
+        tpch_device._gen_lineitem(
+            cols, q(15_000_000 // 4), q(ROWS["sf10"] // 4 + 20_000), 10.0),
+        mesh,
+    ))
+    ranges = jax.ShapeDtypeStruct(
+        (4,), jnp.int64,
+        sharding=NamedSharding(mesh, PartitionSpec("workers")))
+    compiled = fn.lower(ranges, ranges).compile()
+    mem = compiled.memory_analysis()   # bytes on each device
+    total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+             + mem.output_size_in_bytes)
+    assert 0 < total < HBM_BYTES // 4
+    # every shard is made where it stays: nothing crosses the interconnect
+    text = compiled.as_text()
+    assert not any(op in text for op in
+                   ("all-reduce", "all-gather", "all-to-all",
+                    "collective-permute"))
 
 
 @contextlib.contextmanager

@@ -30,6 +30,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from . import tpch as H
 
@@ -277,10 +278,15 @@ def _lineitem(cols, oj, ln, n: Dict[str, int]):
 
 
 # ---------------------------------------------------------------------
-# traced entry points (jitted once per (table, cols, caps, sf); lo/hi
-# ride as traced scalars so all same-shape tiles share one executable)
+# traced entry points (jitted once per (table, cols, caps, sf, mesh);
+# lo/hi ride as traced scalars so all same-shape tiles share one
+# executable).  On a mesh the same per-range function runs under
+# shard_map: lo/hi are [ndev] vectors, every device generates its own
+# (order-)range into its own HBM, and the lanes come back [ndev, cap]
+# sharded on the mesh axis — nothing crosses the host or the interconnect.
 
 _JIT_CACHE: Dict[tuple, object] = {}
+_SHARD_OK = "$ok"  # the sharded program's validity plane (no column is named so)
 
 
 def clear_jit_cache() -> int:
@@ -301,6 +307,22 @@ def _named_jit(fn, table: str):
     return jax.jit(fn)
 
 
+def _per_shard(fn, mesh):
+    """`fn(lo, hi)` of one range, run by every device of `mesh` over its
+    own entry of the [ndev] vectors lo/hi; lanes (and one all-true
+    validity plane) come back [ndev, cap], one row block a device."""
+    spec = PartitionSpec(mesh.axis_names[0])
+
+    def shard(lo, hi):
+        out = {c: v[None] for c, v in fn(lo[0], hi[0]).items()}
+        cap = next(iter(out.values())).shape[1]
+        out[_SHARD_OK] = jnp.ones((1, cap), dtype=bool)
+        return out
+
+    return jax.shard_map(shard, mesh=mesh, in_specs=(spec, spec),
+                         out_specs=spec, check_vma=False)
+
+
 def _gen_flat(table: str, cols: tuple, cap: int, sf: float):
     n = H._counts(sf)
 
@@ -314,7 +336,7 @@ def _gen_flat(table: str, cols: tuple, cap: int, sf: float):
             for c, v in vals.items()
         }
 
-    return _named_jit(fn, table)
+    return fn
 
 
 def _gen_lineitem(cols: tuple, cap_orders: int, cap_rows: int, sf: float):
@@ -351,7 +373,7 @@ def _gen_lineitem(cols: tuple, cap_orders: int, cap_rows: int, sf: float):
             for c, v in vals.items()
         }
 
-    return _named_jit(fn, "lineitem")
+    return fn
 
 
 def supports(table: str, cols: Sequence[str]) -> bool:
@@ -359,58 +381,83 @@ def supports(table: str, cols: Sequence[str]) -> bool:
     return dev is not None and all(c in dev for c in cols)
 
 
-def _generator(table: str, cols: tuple, lo: int, hi: int, cap: int,
-               sf: float, cap_orders: Optional[int]):
-    """(executable, compiled_now) for one (table, cols, caps, sf) shape:
-    the generator is compiled ahead of time on first use and cached."""
+def _generator(table: str, cols: tuple, lo, hi, cap: int,
+               sf: float, cap_orders: Optional[int], mesh=None):
+    """(executable, compiled_now) for one (table, cols, caps, sf, mesh)
+    shape: the generator is compiled ahead of time on first use and
+    cached.  With a `mesh`, lo/hi are one entry per device and the
+    executable is the SPMD program over exactly those devices."""
     if table == "lineitem":
         if cap_orders is None:
-            cap_orders = int(hi - lo)
+            cap_orders = int(np.max(np.subtract(hi, lo)))
         key = (table, cols, cap_orders, cap, sf)
     else:
         key = (table, cols, cap, sf)
+    if mesh is not None:
+        key += (mesh.axis_names, tuple(d.id for d in mesh.devices.flat))
     fn = _JIT_CACHE.get(key)
     if fn is not None:
         return fn, False
     if table == "lineitem":
-        jitted = _gen_lineitem(cols, cap_orders, cap, sf)
+        raw = _gen_lineitem(cols, cap_orders, cap, sf)
     else:
-        jitted = _gen_flat(table, cols, cap, sf)
-    scalar = jax.ShapeDtypeStruct((), jnp.int64)
-    fn = _JIT_CACHE[key] = jitted.lower(scalar, scalar).compile()
+        raw = _gen_flat(table, cols, cap, sf)
+    if mesh is None:
+        arg = jax.ShapeDtypeStruct((), jnp.int64)
+    else:
+        raw = _per_shard(raw, mesh)
+        arg = jax.ShapeDtypeStruct(
+            (mesh.devices.size,), jnp.int64,
+            sharding=NamedSharding(mesh, PartitionSpec(mesh.axis_names[0])),
+        )
+    fn = _JIT_CACHE[key] = _named_jit(raw, table).lower(arg, arg).compile()
     return fn, True
 
 
 def compile_lanes(
-    table: str, cols: Sequence[str], lo: int, hi: int, cap: int, sf: float,
-    cap_orders: Optional[int] = None,
+    table: str, cols: Sequence[str], lo, hi, cap: int, sf: float,
+    cap_orders: Optional[int] = None, mesh=None,
 ) -> bool:
     """Compile (if not yet cached) the generator `device_lanes` will run
     for these arguments; True when a compile happened.  Callers that
     supervise the generator dispatch with a watchdog call this first, so
     the watchdog times execution and not the XLA compile."""
-    return _generator(table, tuple(cols), lo, hi, cap, sf, cap_orders)[1]
+    return _generator(
+        table, tuple(cols), lo, hi, cap, sf, cap_orders, mesh
+    )[1]
 
 
 def device_lanes(
     table: str,
     cols: Sequence[str],
-    lo: int,
-    hi: int,
+    lo,
+    hi,
     cap: int,
     sf: float,
-    count: int,
+    count,
     cap_orders: Optional[int] = None,
+    mesh=None,
 ) -> Dict[str, Tuple[jnp.ndarray, jnp.ndarray]]:
     """Generate the padded device lanes for rows of `table` whose
     (order-)index lies in [lo, hi).  `cap` is the padded row capacity;
     `count` the exact live row count (host-computed for lineitem);
     `cap_orders` a STATIC upper bound on hi-lo (padded so streaming
-    tiles whose spans differ by a few rows share one executable)."""
+    tiles whose spans differ by a few rows share one executable).
+
+    With a `mesh`, lo/hi/count hold one entry per device: device d
+    generates [lo[d], hi[d]) into its own HBM (an empty range is an
+    all-dead shard) and every lane is [ndev, cap], sharded on the mesh
+    axis; the live rows of the shards, in device order, are the rows of
+    [lo[0], hi[-1]) when the ranges are contiguous."""
     cols = tuple(cols)
-    fn, _ = _generator(table, cols, lo, hi, cap, sf, cap_orders)
-    vals = fn(jnp.int64(lo), jnp.int64(hi))
-    ok = jnp.ones(cap, dtype=bool)
+    fn, _ = _generator(table, cols, lo, hi, cap, sf, cap_orders, mesh)
+    if mesh is None:
+        vals = fn(jnp.int64(lo), jnp.int64(hi))
+        ok = jnp.ones(cap, dtype=bool)
+    else:
+        vals = fn(np.asarray(lo, dtype=np.int64),
+                  np.asarray(hi, dtype=np.int64))
+        ok = vals[_SHARD_OK]
     return {c: (vals[c], ok) for c in cols}
 
 

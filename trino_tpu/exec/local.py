@@ -315,6 +315,9 @@ class LocalExecutor:
     """Executes an optimized logical plan on the local device(s)."""
 
     trace_ctx_cls: type  # bound after _TraceCtx definition
+    # the device mesh scans are sharded over (parallel/mesh_executor);
+    # None: one device holds every lane whole
+    mesh = None
 
     def __init__(self, catalogs: CatalogManager, config: Optional[dict] = None):
         self.catalogs = catalogs
@@ -608,40 +611,7 @@ class LocalExecutor:
             raise exceeded
         try:
             self.dicts = dicts
-            self.group_capacity = int(
-                self.config.get("group_capacity", DEFAULT_GROUP_CAPACITY)
-            )
-            self.join_factor = 1
-            self.compact_factor = 1
-            # join nodes whose build side turned out to hold duplicate (or
-            # hash-colliding) keys: re-traced with the expansion kernel
-            # (HashBuilderOperator never assumes uniqueness; we learn it)
-            self.force_expansion = set()
-            # direct-address joins whose domain proof failed at runtime
-            # (stale stats): first rung retries the sorted UNIQUE kernel
-            # (still exact for a unique key outside its claimed domain);
-            # only a genuine duplicate then escalates to expansion
-            self.force_no_direct = set()
-            self.group_salt = 0
-            self.topn_factor = int(
-                self.config.get("topn_initial_factor") or 1
-            )
-            self.force_wide_mul = False
-            # start at the last successful capacities for this plan: the
-            # overflow ladder re-runs (and on first touch, re-COMPILES) the
-            # whole fragment per rung, so remembering the landing spot makes
-            # warm repeats single-shot (FlatHash keeps its size the same way)
-            hints = self.config.get("capacity_hints")
-            hint = hints.get(id(plan)) if hints is not None else None
-            if hint is not None:
-                (self.group_capacity, self.join_factor, self.topn_factor,
-                 self.force_wide_mul, forced, _) = hint[:6]
-                self.compact_factor = hint[6] if len(hint) > 6 else 1
-                self.force_no_direct = (
-                    set(hint[7]) if len(hint) > 7 else set()
-                )
-                self.force_expansion = set(forced)
-            else:
+            if not self._ladder_start(plan):
                 est = self._estimate_group_capacity(plan, counts)
                 if est is not None:
                     self.group_capacity = max(self.group_capacity, est)
@@ -755,83 +725,16 @@ class LocalExecutor:
                         if stream_page is not None:
                             return stream_page
                     raise
-                fell_back = False
-                for (join_node, _), dup in zip(dups, dup_vals):
-                    if int(dup) > 0:
-                        if join_node is None:
-                            # ordinal from a foreign trace did not resolve
-                            # in this plan (should be impossible for
-                            # fingerprint-matched plans): no node to force
-                            raise ExecutionError(
-                                "duplicate build keys in unresolvable join"
-                            )
-                        if (
-                            getattr(join_node, "direct_domain", None)
-                            is not None
-                            and id(join_node) not in self.force_no_direct
-                        ):
-                            # direct-table domain/dup proof failed: retry
-                            # on the sorted unique kernel first
-                            self.force_no_direct.add(id(join_node))
-                        else:
-                            # duplicate (or colliding) build keys:
-                            # re-trace with the many-to-many expansion
-                            # kernel for this join
-                            self.force_expansion.add(id(join_node))
-                        fell_back = True
-                for cv in coll_vals:
-                    if int(cv) > 0:
-                        # locator hash collision in grouping: re-run
-                        # the fragment under a fresh salt (exactness)
-                        self.group_salt += 1
-                        fell_back = True
-                for wv in wide_vals:
-                    if int(wv) > 0 and not self.force_wide_mul:
-                        # decimal product/quotient near int64 range:
-                        # re-trace with the 128-bit kernels
-                        self.force_wide_mul = True
-                        fell_back = True
-                if fell_back:
-                    continue
-                over_kinds = set()
-                for ngroups, (_, cap, kind) in zip(check_vals, checks):
-                    if int(ngroups) > cap:
-                        over_kinds.add(kind)
-                if not over_kinds:
-                    # only a settled attempt may raise: a capacity overflow
-                    # or collision retry piles unrelated groups into one
-                    # segment, making the shadow flag spurious
-                    for sv in sflag_vals:
-                        if int(sv) > 0:
-                            raise ExecutionError(
-                                "sum overflows the bigint accumulator"
-                            )
+                if self._ladder_settled(
+                    [n for n, _ in dups], dup_vals, coll_vals, wide_vals,
+                    [(cap, kind) for _, cap, kind in checks], check_vals,
+                    sflag_vals,
+                ):
                     break
-                if "group" in over_kinds:
-                    self.group_capacity *= 8
-                if "join" in over_kinds:
-                    self.join_factor *= 8
-                if "topn" in over_kinds:
-                    self.topn_factor *= 8
-                if "compact" in over_kinds:
-                    # x8 rapidly reaches the input width, where
-                    # _maybe_compact becomes a no-op — a bad estimate
-                    # costs at most a couple of recompiles, never a loop
-                    self.compact_factor *= 8
             else:
                 raise ExecutionError("group capacity overflow after retries")
 
-            if hints is not None:
-                # the plan reference keeps id(plan) stable (no reuse after gc)
-                hints[id(plan)] = (
-                    self.group_capacity, self.join_factor,
-                    self.topn_factor, self.force_wide_mul,
-                    frozenset(self.force_expansion), plan,
-                    self.compact_factor,
-                    frozenset(self.force_no_direct),
-                )
-                for k in list(hints)[:-512]:
-                    hints.pop(k, None)
+            self._ladder_remember(plan)
             with TRACER.span("materialize_host"):
                 self._finalize_kernel_profile(
                     scans, counts, host_lanes, sel_np
@@ -846,6 +749,130 @@ class LocalExecutor:
                     )
             elif pool is not None:
                 pool.free(self.query_id, self.scan_bytes)
+
+    def _ladder_start(self, plan) -> bool:
+        """Set the retry ladder's state for one execution of `plan`: the
+        capacities the last execution of it settled on when the session
+        remembers them (True), else the first rung."""
+        self.group_capacity = int(
+            self.config.get("group_capacity", DEFAULT_GROUP_CAPACITY)
+        )
+        self.join_factor = 1
+        self.compact_factor = 1
+        # join nodes whose build side turned out to hold duplicate (or
+        # hash-colliding) keys: re-traced with the expansion kernel
+        # (HashBuilderOperator never assumes uniqueness; we learn it)
+        self.force_expansion = set()
+        # direct-address joins whose domain proof failed at runtime
+        # (stale stats): first rung retries the sorted UNIQUE kernel
+        # (still exact for a unique key outside its claimed domain);
+        # only a genuine duplicate then escalates to expansion
+        self.force_no_direct = set()
+        self.group_salt = 0
+        self.topn_factor = int(
+            self.config.get("topn_initial_factor") or 1
+        )
+        self.force_wide_mul = False
+        # start at the last successful capacities for this plan: the
+        # overflow ladder re-runs (and on first touch, re-COMPILES) the
+        # whole fragment per rung, so remembering the landing spot makes
+        # warm repeats single-shot (FlatHash keeps its size the same way)
+        hints = self.config.get("capacity_hints")
+        hint = hints.get(id(plan)) if hints is not None else None
+        if hint is None:
+            return False
+        (self.group_capacity, self.join_factor, self.topn_factor,
+         self.force_wide_mul, forced, _) = hint[:6]
+        self.compact_factor = hint[6] if len(hint) > 6 else 1
+        self.force_no_direct = set(hint[7]) if len(hint) > 7 else set()
+        self.force_expansion = set(forced)
+        return True
+
+    def _ladder_settled(self, dup_nodes, dup_vals, coll_vals, wide_vals,
+                        limits, check_vals, sflag_vals) -> bool:
+        """Read one attempt's control scalars: True when the attempt
+        stands, else the ladder state has moved to the next rung (a
+        forced kernel, a fresh salt, a larger capacity) and the fragment
+        is to be traced and run again."""
+        fell_back = False
+        for join_node, dup in zip(dup_nodes, dup_vals):
+            if int(dup) > 0:
+                if join_node is None:
+                    # ordinal from a foreign trace did not resolve
+                    # in this plan (should be impossible for
+                    # fingerprint-matched plans): no node to force
+                    raise ExecutionError(
+                        "duplicate build keys in unresolvable join"
+                    )
+                if (
+                    getattr(join_node, "direct_domain", None) is not None
+                    and id(join_node) not in self.force_no_direct
+                ):
+                    # direct-table domain/dup proof failed: retry
+                    # on the sorted unique kernel first
+                    self.force_no_direct.add(id(join_node))
+                else:
+                    # duplicate (or colliding) build keys: re-trace
+                    # with the many-to-many expansion kernel for
+                    # this join
+                    self.force_expansion.add(id(join_node))
+                fell_back = True
+        for cv in coll_vals:
+            if int(cv) > 0:
+                # locator hash collision in grouping: re-run the
+                # fragment under a fresh salt (exactness)
+                self.group_salt += 1
+                fell_back = True
+        for wv in wide_vals:
+            if int(wv) > 0 and not self.force_wide_mul:
+                # decimal product/quotient near int64 range: re-trace
+                # with the 128-bit kernels
+                self.force_wide_mul = True
+                fell_back = True
+        if fell_back:
+            return False
+        over_kinds = set()
+        for ngroups, (cap, kind) in zip(check_vals, limits):
+            if int(ngroups) > cap:
+                over_kinds.add(kind)
+        if not over_kinds:
+            # only a settled attempt may raise: a capacity overflow or
+            # collision retry piles unrelated groups into one segment,
+            # making the shadow flag spurious
+            for sv in sflag_vals:
+                if int(sv) > 0:
+                    raise ExecutionError(
+                        "sum overflows the bigint accumulator"
+                    )
+            return True
+        if "group" in over_kinds:
+            self.group_capacity *= 8
+        if "join" in over_kinds:
+            self.join_factor *= 8
+        if "topn" in over_kinds:
+            self.topn_factor *= 8
+        if "compact" in over_kinds:
+            # x8 rapidly reaches the input width, where _maybe_compact
+            # becomes a no-op — a bad estimate costs at most a couple
+            # of recompiles, never a loop
+            self.compact_factor *= 8
+        return False
+
+    def _ladder_remember(self, plan) -> None:
+        """Keep the rung this execution settled on for the next one."""
+        hints = self.config.get("capacity_hints")
+        if hints is None:
+            return
+        # the plan reference keeps id(plan) stable (no reuse after gc)
+        hints[id(plan)] = (
+            self.group_capacity, self.join_factor,
+            self.topn_factor, self.force_wide_mul,
+            frozenset(self.force_expansion), plan,
+            self.compact_factor,
+            frozenset(self.force_no_direct),
+        )
+        for k in list(hints)[:-512]:
+            hints.pop(k, None)
 
     # ------------------------------------------------------------------
     def _plan_out_of_core(self, plan, limit: int):
@@ -1084,9 +1111,13 @@ class LocalExecutor:
             conn, node, cols, splits, key, cache, scans, dicts, counts
         ):
             return
-        provider = conn.page_source_provider()
-        tmap = dict(node.types)
-        sym_of = {c: self._sym_for(node, c) for c in cols}
+        self._load_host_scan(node, splits, key, cache, scans, dicts, counts)
+
+    def _split_pages(self, node: P.TableScan, splits, cols, sym_of):
+        """The pages of `splits`, columns named by the plan's symbols and
+        each carrying its dictionary (the column's own, else the page
+        source's)."""
+        provider = self.catalogs.get(node.catalog).page_source_provider()
         pages: List[Page] = []
         for sp in splits:
             src = provider.create_page_source(sp, cols)
@@ -1106,6 +1137,17 @@ class LocalExecutor:
                     Page(new_cols, page.count,
                          [sym_of[c] for c in page.names])
                 )
+        return pages
+
+    def _load_host_scan(self, node: P.TableScan, splits, key, cache,
+                        scans, dicts, counts):
+        """The host side of `_load_one_scan`: read the splits' pages
+        through the connector, merge them into one array per column and
+        keep the result in the scan cache."""
+        cols = [c for _, c in node.assignments]
+        tmap = dict(node.types)
+        sym_of = {c: self._sym_for(node, c) for c in cols}
+        pages = self._split_pages(node, splits, cols, sym_of)
         symbols = [sym_of[c] for c in cols]
         types = [(s, tmap[s]) for s in symbols]
         merged, total = merge_pages_to_arrays(pages, symbols, types, dicts)
@@ -1169,7 +1211,7 @@ class LocalExecutor:
         ):
             return False
         try:
-            spec = devgen_fn(node.table, cols, splits)
+            spec = self._devgen_spec(devgen_fn, node.table, cols, splits)
         except Exception:  # noqa: BLE001 — any trouble: host path
             spec = None
         if spec is None:
@@ -1215,6 +1257,11 @@ class LocalExecutor:
             )
         return True
 
+    def _devgen_spec(self, devgen_fn, table, cols, splits):
+        """The connector's generation recipe for these splits (None: the
+        host path).  One device generates the whole range."""
+        return devgen_fn(table, cols, splits)
+
     def _generate_device_scan(self, spec: dict, syms, sym_to_col, cap):
         """Run the connector's on-device generator for one scan at padded
         capacity `cap`; returns {symbol: (values, ok)} resident in HBM.
@@ -1229,7 +1276,16 @@ class LocalExecutor:
         from ..connectors import tpch_device
 
         cols = [sym_to_col.get(s, s) for s in syms]
-        span = max(int(spec["hi"]) - int(spec["lo"]), 1)
+        # one range, or one per mesh device (`shards`: the recipe of a
+        # scan sharded by parallel/mesh_executor; lanes are then
+        # [ndev, cap], each shard generated in its own device's HBM)
+        shards = spec.get("shards")
+        if shards is None:
+            lo, hi, count = int(spec["lo"]), int(spec["hi"]), int(spec["count"])
+            span = hi - lo
+        else:
+            lo, hi, count = (list(x) for x in zip(*shards))
+            span = max(h - l for l, h in zip(lo, hi))
         widths = spec.get("widths") or {}
         bc = self._dispatch_crumb(
             "devgen:%s" % spec["table"], "devgen"
@@ -1240,14 +1296,17 @@ class LocalExecutor:
         }
         self.kernel_profile["last_breadcrumb"] = bc.to_dict()
         cap_orders = (
-            self.ladder.quantize(span)
+            self.ladder.quantize(max(span, 1))
             if spec["table"] == "lineitem" else None
         )
-        lo, hi, sf = int(spec["lo"]), int(spec["hi"]), float(spec["sf"])
-        with TRACER.span("devgen", table=spec["table"]) as sp:
+        sf = float(spec["sf"])
+        mesh = self.mesh if shards is not None else None
+        with TRACER.span("devgen", table=spec["table"],
+                         shards=len(shards or (None,))) as sp:
             t0 = time.time()
             compiled = tpch_device.compile_lanes(
-                spec["table"], cols, lo, hi, cap, sf, cap_orders=cap_orders
+                spec["table"], cols, lo, hi, cap, sf,
+                cap_orders=cap_orders, mesh=mesh,
             )
             compile_s = time.time() - t0
             sp.attributes["compiled"] = bool(compiled)
@@ -1257,8 +1316,8 @@ class LocalExecutor:
             lanes = self._dispatch(
                 lambda: jax.block_until_ready(  # dispatch-guard: ok (in thunk)
                     tpch_device.device_lanes(
-                        spec["table"], cols, lo, hi, cap, sf,
-                        int(spec["count"]), cap_orders=cap_orders,
+                        spec["table"], cols, lo, hi, cap, sf, count,
+                        cap_orders=cap_orders, mesh=mesh,
                     )
                 ),
                 bc,
@@ -1341,22 +1400,27 @@ class LocalExecutor:
                 if entry is not None:
                     entry["dev"][col] = gen_out[sym]
                 continue
-            if arr.shape[0] < cap:
-                pad = np.zeros(
-                    (cap - arr.shape[0],) + arr.shape[1:], dtype=arr.dtype
-                )
-                arr = np.concatenate([arr, pad])
-            v = jnp.asarray(arr)
-            if valid is None:
-                ok = jnp.ones(cap, dtype=bool)
-            else:
-                vv = np.zeros(cap, dtype=bool)
-                vv[: valid.shape[0]] = valid
-                ok = jnp.asarray(vv)
-            lanes[sym] = (v, ok)
+            lanes[sym] = self._upload_lane(arr, valid, cap)
             if entry is not None:
-                entry["dev"][col] = (v, ok)
+                entry["dev"][col] = lanes[sym]
         return lanes
+
+    def _upload_lane(self, arr, valid, cap):
+        """One host column as a device lane: (values padded to `cap`,
+        validity)."""
+        if arr.shape[0] < cap:
+            pad = np.zeros(
+                (cap - arr.shape[0],) + arr.shape[1:], dtype=arr.dtype
+            )
+            arr = np.concatenate([arr, pad])
+        v = jnp.asarray(arr)
+        if valid is None:
+            ok = jnp.ones(cap, dtype=bool)
+        else:
+            vv = np.zeros(cap, dtype=bool)
+            vv[: valid.shape[0]] = valid
+            ok = jnp.asarray(vv)
+        return v, ok
 
     @staticmethod
     def _sym_for(scan: P.TableScan, col: str) -> str:

@@ -37,6 +37,7 @@ from ..exec.local import (
     Batch,
     ExecutionError,
     LocalExecutor,
+    dict_fingerprint,
     merge_pages_to_arrays,
     _pad_capacity,
     _shape_summary,
@@ -50,8 +51,9 @@ from ..ops import aggregation as agg_ops
 from ..ops import join as join_ops
 from ..ops import sketches
 from ..ops import sort as sort_ops
+from ..ops import tree_nbytes
 from . import shuffle
-from ..page import Column, Page
+from ..page import Page
 from ..plan import nodes as P
 from ..runtime import Breadcrumb, DeviceFaultError
 
@@ -121,6 +123,22 @@ def _decode_direct_keys(domains, cap):
         code = (gids // stride) % radix
         ok = code < dom  # slot `dom` encodes NULL
         out.append((code.astype(jnp.int32), ok))
+    return out
+
+
+def _stack_shards(shards, cap: int) -> Dict[str, tuple]:
+    """Per-device host columns {sym: (values, validity or None)} as one
+    {sym: (values [ndev, cap, ...], validity [ndev, cap])}: device d's
+    rows lead row block d, zero padding (dead, invalid) follows."""
+    out = {}
+    for sym, (v0, _) in shards[0].items():
+        stacked = np.zeros((len(shards), cap) + v0.shape[1:], dtype=v0.dtype)
+        okstack = np.zeros((len(shards), cap), dtype=bool)
+        for d, shard in enumerate(shards):
+            v, ok = shard[sym]
+            stacked[d, : len(v)] = v
+            okstack[d, : len(v)] = True if ok is None else ok
+        out[sym] = (stacked, okstack)
     return out
 
 
@@ -266,68 +284,287 @@ class MeshExecutor(LocalExecutor):
                                       mode=mode, cause=cause)
 
     def _ledger_input_bytes(self, scans) -> int:
-        # mesh scan args are flat {sym: [ndev, cap]} ndarray dicts (the
-        # $ok validity plane is its own entry), not (value, ok) tuples
-        total = 0
-        for arrays in scans.values():
-            for v in arrays.values():
-                total += int(getattr(v, "nbytes", 0) or 0)
-        return total
+        # what the program was called with: {ordinal: {sym: (values, ok),
+        # "__count__": rows}}, every leaf [ndev, ...] on the mesh axis
+        return tree_nbytes(scans)
+
+    def _dispatched_cap(self, nid, count: int) -> int:
+        # every shard is padded to the rung of the largest one
+        return self.mesh.devices.size * super()._dispatched_cap(nid, count)
+
+    # -- scans: the one-chip path, sharded ------------------------------
+    def _mesh_ids(self) -> tuple:
+        return tuple(int(d.id) for d in self.mesh.devices.flat)
+
+    def _scan_cache_key(self, node: P.TableScan, splits):
+        """The one-chip key plus the devices the lanes are sharded over:
+        a mesh of another size, or one shrunk around a lost device, finds
+        neither these lanes nor (through `_jit_scan_component`) a program
+        compiled for them."""
+        key = super()._scan_cache_key(node, splits)
+        return None if key is None else key + (("mesh",) + self._mesh_ids(),)
+
+    def _devgen_spec(self, devgen_fn, table, cols, splits):
+        """One generation recipe a device: device d takes the splits
+        `splits[d::ndev]` (the NodeScheduler's round-robin, devices
+        standing in for workers), which a contiguous range connector
+        turns into its own (lo, hi, exact row count).  A device with no
+        split holds an empty shard."""
+        ndev = self.mesh.devices.size
+        groups = [splits[d::ndev] for d in range(ndev)]
+        specs = [devgen_fn(table, cols, g) if g else None for g in groups]
+        if any(sp is None and g for sp, g in zip(specs, groups)):
+            return None
+        live = [sp for sp in specs if sp is not None]
+        if not live:
+            return None
+        spec = dict(live[0])
+        spec["shards"] = [
+            (sp["lo"], sp["hi"], sp["count"]) if sp is not None else (0, 0, 0)
+            for sp in specs
+        ]
+        spec["lo"] = min(sp["lo"] for sp in live)
+        spec["hi"] = max(sp["hi"] for sp in live)
+        spec["count"] = sum(sp["count"] for sp in live)
+        spec["orders_hashed"] = sum(
+            sp.get("orders_hashed", 0) for sp in live
+        )
+        return spec
+
+    def _load_host_scan(self, node: P.TableScan, splits, key, cache,
+                        scans, dicts, counts):
+        """Scans no device can generate: each device's splits are read
+        and merged on the host, stacked [ndev, cap] (a row block a
+        device) and uploaded per query; the scan cache does not hold
+        them."""
+        ndev = self.mesh.devices.size
+        cols = [c for _, c in node.assignments]
+        sym_of = {c: self._sym_for(node, c) for c in cols}
+        symbols = [sym_of[c] for c in cols]
+        tmap = dict(node.types)
+        types = [(s, tmap[s]) for s in symbols]
+        per_dev: List[Dict[str, tuple]] = []
+        per_dev_dicts: List[Dict[str, np.ndarray]] = []
+        dev_counts: List[int] = []
+        for d in range(ndev):
+            ddicts: Dict[str, np.ndarray] = {}
+            merged_d, total = merge_pages_to_arrays(
+                self._split_pages(node, splits[d::ndev], cols, sym_of),
+                symbols, types, ddicts,
+            )
+            per_dev.append(merged_d)
+            per_dev_dicts.append(ddicts)
+            dev_counts.append(total)
+        self._merge_split_dicts(per_dev, per_dev_dicts, dicts)
+        for s, t in types:
+            if t.is_dictionary and s not in dicts:
+                dicts[s] = np.array([], dtype=object)
+        scans[id(node)] = _stack_shards(
+            per_dev, self.ladder.quantize(max(max(dev_counts), 1))
+        )
+        counts[id(node)] = np.array(dev_counts, dtype=np.int64)
+        self._scan_keys[id(node)] = key
+        self._scan_dictfp[id(node)] = dict_fingerprint(dicts, symbols)
+
+    def _upload_lane(self, arr, valid, cap):
+        # an [ndev, cap] stack (`_stack_shards`), one row block a device;
+        # supervised: a device lost while it receives is this crumb's
+        ndev = self.mesh.devices.size
+        return self._dispatch(
+            lambda: jax.device_put(  # dispatch-guard: ok (in thunk)
+                (arr, valid), NamedSharding(self.mesh, P_(AXIS))
+            ),
+            self._dispatch_crumb(
+                "mesh:%d/upload" % ndev, "upload", {"lane": arr}
+            ),
+        )
+
+    def _load_sharded_scans(self, plan: P.PlanNode, ndev: int):
+        """Every scan of the plan through `LocalExecutor._load_one_scan`
+        (scan cache, device generation recipe, host fall-back), sharded:
+        `scans[id(node)]` holds what `_device_lanes` turns into
+        [ndev, cap] lanes, `counts[id(node)]` the live rows of each
+        shard."""
+        scans: Dict[int, dict] = {}
+        counts: Dict[int, np.ndarray] = {}
+        dicts: Dict[str, np.ndarray] = {}
+        # preorder TableScan index: the same ordinal FragmentExecutor's
+        # _load_walk uses as the scheduler's split-assignment key, so the
+        # cross-host subclass can look up its ASSIGNED splits
+        scan_idx = [0]
+
+        def walk(node: P.PlanNode):
+            if isinstance(node, P.TableScan):
+                idx = scan_idx[0]
+                scan_idx[0] += 1
+                self._load_one_scan(
+                    node, self._scan_splits(node, idx, ndev),
+                    scans, dicts, counts,
+                )
+                spec = self._devgen.get(id(node))
+                if spec is not None:
+                    counts[id(node)] = np.array(
+                        [n for _, _, n in spec["shards"]], dtype=np.int64
+                    )
+                return
+            if isinstance(node, P.RemoteSource):
+                self._load_remote_source(node, ndev, scans, counts, dicts)
+                return
+            for s in node.sources:
+                walk(s)
+
+        walk(plan)
+        return scans, counts, dicts
 
     # ------------------------------------------------------------------
     def _execute_mesh(self, plan: P.PlanNode) -> Page:
         t_exec0 = time.perf_counter()
         self.mesh_tasks = []
         ndev = self.mesh.devices.size
-        scan_args, counts_args, dicts = self._load_sharded_scans(plan, ndev)
-        self.dicts = dicts
-        # skew pre-pass: measure each partitioned join key's real bucket
-        # load on the HOST arrays before tracing, so the shuffle chunk is
-        # sized for the observed skew up front instead of discovered by
-        # whole-fragment recompile rungs (weak #8: the recompile spiral)
-        self.shuffle_hints = self._skew_shuffle_hints(
-            plan, scan_args, counts_args, ndev
-        )
-        # one explicit sharded upload ([ndev, cap] stacks split on the
-        # mesh axis, one row block per device), shared by every ladder
-        # attempt; where each lane's shards landed goes on the profile
-        sharding = NamedSharding(self.mesh, P_(AXIS))
-        host_args = scan_args
-        scan_args = self._dispatch(
-            # supervised: runs inside this _dispatch thunk
-            lambda: jax.device_put(host_args, sharding),  # dispatch-guard: ok
-            self._dispatch_crumb("mesh:%d/upload" % ndev, "upload", host_args),
-        )
-        self.kernel_profile["scanShards"] = {
-            "%s.%s" % (nid, sym): [
-                (sh.device.id, int(np.prod(sh.data.shape)))
-                for sh in lane.addressable_shards
-            ]
-            for nid, lanes in scan_args.items()
-            for sym, lane in lanes.items()
-        }
-        self.group_capacity = int(self.config.get("group_capacity", 4096))
-        self.join_factor = 1
-        self.force_expansion = set()
-        self.force_no_direct = set()
-        self.group_salt = 0
-        self.topn_factor = 1
-        self.force_wide_mul = False
-
+        with TRACER.span("load_scans"):
+            scans, counts, dicts = self._load_sharded_scans(plan, ndev)
+            self.dicts = dicts
+            # skew pre-pass: where a partitioned join's key column is on
+            # the host (a scan no device generates), measure its real
+            # bucket load before tracing, so the shuffle chunk is sized
+            # for the observed skew up front.  Lanes resident in HBM are
+            # not pulled back for it: the capacity ladder sizes those
+            # shuffles, and the rung it settles on is remembered.
+            self.shuffle_hints = self._skew_shuffle_hints(
+                plan, scans, counts, ndev
+            )
+        self._ladder_start(plan)
         for attempt in range(7):
-            # class-attribute hook (LocalExecutor.trace_ctx_cls idiom):
-            # the cross-host slice executor swaps in _SliceTraceCtx
-            ctx = self.mesh_trace_ctx_cls(self, None, None)
+            self._ladder_attempt = attempt
+            out, cell, prep = self._run_sharded(plan, scans, counts)
+            # ONE supervised transfer for every retry-ladder check and
+            # the output lanes (a retry simply discards the lanes)
+            with TRACER.span("device_get"):
+                (checks, dups, colls, wides, sflags, host_lanes,
+                 sel_np) = self._device_get(
+                    out[2:] + ({s: out[0][s] for s in plan.symbols}, out[1]),
+                    self._dispatch_crumb(
+                        self._last_crumb.kernel, "device_get"
+                    ),
+                )
+            if self._ladder_settled(
+                cell["dup_nodes"], dups, colls, wides,
+                cell["caps"], checks, sflags,
+            ):
+                break
+        else:
+            raise ExecutionError("group capacity overflow after retries")
+        self._ladder_remember(plan)
 
-            def fragment(scans, counts):
-                ctx.scans = scans
-                ctx.counts = counts
+        with TRACER.span("materialize_host"):
+            totals = {nid: int(c.sum()) for nid, c in counts.items()}
+            self._finalize_kernel_profile(scans, totals, host_lanes, sel_np)
+            # what the program read, as it was padded and sharded
+            self.scan_bytes = self._ledger_input_bytes(prep)
+            if self.bandwidth_ledger is not None:
+                summary = self.kernel_profile["summary"]
+                summary.update(
+                    meshDevices=ndev,
+                    perShardGbps=round(summary["effectiveGbps"] / ndev, 6),
+                )
+            page = self._materialize_host(plan, host_lanes, sel_np)
+        if self.config.get("collect_node_stats"):
+            self._mesh_node_stats(
+                plan, scans, counts,
+                time.perf_counter() - t_exec0, ndev, page,
+            )
+        return page
+
+    def _run_sharded(self, plan: P.Output, scans, counts):
+        """`LocalExecutor._run_jitted` for the mesh: one SPMD program a
+        fragment, compiled once per (fragment key, mesh, ladder state)
+        into the session's jit cache and launched once a query.  Returns
+        (the program's outputs, its trace-time cell, the lanes it was
+        called with)."""
+        from ..cache.compile_cache import fragment_key, stable_key_digest
+
+        cache = self.config.get("jit_cache")
+        if cache is None:
+            cache = {}
+        ndev = self.mesh.devices.size
+        with TRACER.span("device_lanes"):
+            # a scan's rung is the rung of its largest shard
+            rows = {nid: max(int(c.max()), 1) for nid, c in counts.items()}
+            key, order, by_ord = fragment_key(
+                self, plan, scans, rows, self.ladder.quantize
+            )
+            key = key + (
+                ("mesh",) + self._mesh_ids(),
+                ("shuffle_hints", tuple(sorted(
+                    (order.get(nid, nid), side, cap)
+                    for (nid, side), cap in self.shuffle_hints.items()
+                ))),
+                ("megakernels", self._megakernel_mode()),
+            )
+            digest = "mesh:%d/%s" % (ndev, stable_key_digest(key)[:12])
+            # keyed by plan ordinal, as in _run_jitted: dict keys are part
+            # of the jit pytree, and ordinals make it session-invariant
+            prep = {}
+            for nid, arrays in scans.items():
+                lanes = dict(self._device_lanes(
+                    self._scan_nodes.get(nid), arrays, rows[nid], nid
+                ))
+                # each shard's live rows ride as a traced [ndev] vector
+                lanes["__count__"] = counts[nid]
+                prep[order.get(nid, nid)] = lanes
+            self.kernel_profile["scanShards"] = {
+                "%s.%s" % (o, sym): [
+                    (sh.device.id, int(np.prod(sh.data.shape)))
+                    for sh in lane[0].addressable_shards
+                ]
+                for o, lanes in prep.items()
+                for sym, lane in lanes.items() if sym != "__count__"
+            }
+            # unversioned sources may change without a shape change: no
+            # safe executable reuse (the jit path's rule)
+            keyed = all(
+                self._scan_keys.get(nid) is not None for nid in scans
+            )
+            entry = cache.get(key) if keyed else None
+        self.kernel_profile["meshProgramCache"] = (
+            "hit" if entry is not None else "miss"
+        )
+        bc = self._dispatch_crumb(digest, "mesh", prep)
+        self._last_crumb = bc
+        if entry is not None:
+            cell = entry["cell"]
+            self.dicts.update(cell["dicts"])
+            fn = entry["fn"]
+            led_t0 = time.perf_counter()
+            with TRACER.span("launch"):
+                out = self._dispatch(lambda: fn(prep), bc)
+            self._ledger_bracket(out, digest, "mesh", plan, prep, led_t0)
+            self._record_kernel(
+                digest, compile_s=0.0, cached=True, mode="mesh"
+            )
+        else:
+            cell = {}
+            ids = {o: i for i, o in order.items()}
+
+            def fragment(prep_arg):
+                # class-attribute hook (LocalExecutor.trace_ctx_cls
+                # idiom): the cross-host slice executor swaps in
+                # _SliceTraceCtx
+                ctx = self.mesh_trace_ctx_cls(
+                    self, {ids.get(o, o): v for o, v in prep_arg.items()},
+                    None,
+                )
                 batch = ctx.visit(plan.source)
                 if not batch.replicated:
                     batch = _gather_batch(batch)
-                out = {s: batch.lanes[s] for s in plan.symbols}
+                cell["caps"] = list(ctx.capacity_limits)
+                # dup-check join nodes as plan ordinals: another session
+                # hitting this entry resolves them to ITS plan's nodes
+                cell["dup_ords"] = [
+                    order.get(id(n), -1) for n, _ in ctx.dup_checks
+                ]
                 return (
-                    out,
+                    {s: batch.lanes[s] for s in plan.symbols},
                     batch.sel,
                     tuple(ctx.capacity_checks),
                     tuple(d for _, d in ctx.dup_checks),
@@ -341,37 +578,23 @@ class MeshExecutor(LocalExecutor):
                     ),
                 )
 
-            shard_fn = _shard_map(
-                fragment, self.mesh, (P_(AXIS), P_(AXIS)), P_()
-            )
-            digest = "mesh:%d/fragment-a%d" % (ndev, attempt)
             compile_start = time.time()
-            bc = self._dispatch_crumb(digest, "mesh", scan_args)
-            self._last_crumb = bc
-            # mesh compiles fresh each attempt (no executable cache):
-            # attempt 0 classifies by family warmth, later attempts are
-            # ladder rungs — same taxonomy as the jit path
-            family = "mesh%d:%s" % (ndev, self._compile_family(plan))
-            scan_rows = [
-                int(r)
-                for c in counts_args.values()
-                for r in np.asarray(c).reshape(-1)
-            ]
+            family = self._compile_family(plan)
+            fragment.__name__ = fragment.__qualname__ = "frag_" + family
+            family = "mesh%d:%s" % (ndev, family)
+            scan_rows = [int(r) for c in counts.values() for r in c]
             actual_rows = sum(scan_rows)
-            padded_rows = sum(
-                int(np.prod(v.shape))
-                for arrays in scan_args.values()
-                for v in list(arrays.values())[:1]
-            )
-            shape_sig = self._compile_shape_sig({
-                nid: int(np.max(np.asarray(c))) if len(
-                    np.asarray(c).reshape(-1)
-                ) else 0
-                for nid, c in counts_args.items()
-            })
-            shapes = _shape_summary(scan_args)
+            padded_rows = self._padded_rows(rows)
+            shape_sig = self._compile_shape_sig(rows)
+            shapes = _shape_summary(prep)
             cause = _compile_obs.get_observatory().classify(
-                family, shape_sig, ladder_attempt=attempt,
+                family, shape_sig,
+                ladder_attempt=self._ladder_attempt,
+                persistent=bool(
+                    keyed
+                    and getattr(cache, "persistent_known", None) is not None
+                    and cache.persistent_known(key)
+                ),
                 query_id=self.query_id,
             )
             with TRACER.span(
@@ -387,16 +610,16 @@ class MeshExecutor(LocalExecutor):
                 # trace + compile outside the watchdog (see
                 # LocalExecutor._run_jitted); only execution is supervised
                 fn = self._compile_fragment(
-                    jax.jit(shard_fn),  # dispatch-guard: ok (lazy wrapper)
-                    scan_args, counts_args,
+                    jax.jit(  # dispatch-guard: ok (lazy wrapper)
+                        _shard_map(fragment, self.mesh, (P_(AXIS),), P_())
+                    ),
+                    prep,
                 )
                 compile_s = time.time() - compile_start
                 led_t0 = time.perf_counter()
-                out = self._dispatch(
-                    lambda: fn(scan_args, counts_args), bc
-                )
-            self._ledger_bracket(out, digest, "mesh", plan, scan_args,
-                                 led_t0)
+                with TRACER.span("launch"):
+                    out = self._dispatch(lambda: fn(prep), bc)
+                self._ledger_bracket(out, digest, "mesh", plan, prep, led_t0)
             _compile_obs.record_compile(
                 kernel=digest, family=family, cause=cause,
                 mode="mesh", shapes=shapes, shape_sig=shape_sig,
@@ -411,83 +634,14 @@ class MeshExecutor(LocalExecutor):
                 digest, compile_s=compile_s,
                 cached=False, mode="mesh", cause=cause,
             )
-            # one supervised transfer covers every retry-ladder check
-            (checks, dups, colls, wides, sflags) = self._device_get(
-                out[2:], self._dispatch_crumb(digest, "device_get")
-            )
-            out_lanes, sel = out[0], out[1]
-            fell_back = False
-            for (join_node, _), d in zip(ctx.dup_checks, dups):
-                if int(d) > 0:
-                    if (
-                        getattr(join_node, "direct_domain", None)
-                        is not None
-                        and id(join_node) not in self.force_no_direct
-                    ):
-                        # direct-table proof failed: sorted unique first
-                        self.force_no_direct.add(id(join_node))
-                    else:
-                        # duplicate/colliding build keys: re-trace this
-                        # join with the many-to-many expansion kernel
-                        self.force_expansion.add(id(join_node))
-                    fell_back = True
-            for cv in colls:
-                if int(cv) > 0:
-                    self.group_salt += 1
-                    fell_back = True
-            for wv in wides:
-                if int(wv) > 0 and not self.force_wide_mul:
-                    self.force_wide_mul = True
-                    fell_back = True
-            if fell_back:
-                continue
-            over_kinds = set()
-            for n, (cap, kind) in zip(checks, ctx.capacity_limits):
-                if int(n) > cap:
-                    over_kinds.add(kind)
-            if not over_kinds:
-                # only a settled attempt may raise (capacity/collision
-                # retries make the shadow flag spurious)
-                for sv in sflags:
-                    if int(sv) > 0:
-                        raise ExecutionError(
-                            "sum overflows the bigint accumulator"
-                        )
-                break
-            if "group" in over_kinds:
-                self.group_capacity *= 8
-            if "join" in over_kinds:
-                self.join_factor *= 8
-            if "topn" in over_kinds:
-                self.topn_factor *= 8
-        else:
-            raise ExecutionError("group capacity overflow after retries")
-
-        # settle-time accounting: the local executor fills these during
-        # scan loading / profile finalize, neither of which runs on the
-        # mesh path — without them the bench reports 0 scan bytes and
-        # the per-shard GB/s satellite has nothing to divide
-        self.scan_bytes = self._ledger_input_bytes(scan_args)
-        led = self.bandwidth_ledger
-        if led is not None:
-            s = led.summary()
-            self.kernel_profile["bandwidth"] = led.entries()
-            self.kernel_profile.setdefault("summary", {}).update(
-                effectiveGbps=s["effectiveGbps"],
-                rooflinePct=s["rooflinePct"],
-                ledgerBytes=s["totalBytes"],
-                deviceWallS=s["deviceWallS"],
-                meshDevices=ndev,
-                perShardGbps=round(s["effectiveGbps"] / ndev, 6),
-            )
-
-        page = self._materialize(plan, out_lanes, sel, ctx.ordered_out)
-        if self.config.get("collect_node_stats"):
-            self._mesh_node_stats(
-                plan, scan_args, counts_args,
-                time.perf_counter() - t_exec0, ndev, page,
-            )
-        return page
+            cell["dicts"] = dict(self.dicts)
+            if keyed:
+                # the plan reference pins id(plan) (fingerprint memo)
+                cache[key] = {"fn": fn, "cell": cell, "plan": plan}
+        cell = dict(
+            cell, dup_nodes=[by_ord.get(o) for o in cell["dup_ords"]]
+        )
+        return out, cell, prep
 
     # ------------------------------------------------------------------
     def _mesh_node_stats(self, plan, scans, counts, wall_s, ndev, page):
@@ -512,12 +666,8 @@ class MeshExecutor(LocalExecutor):
         def walk(n):
             nonlocal total_rows, total_bytes
             if isinstance(n, P.TableScan):
-                cnts = counts.get(str(id(n)))
-                arrays = scans.get(str(id(n))) or {}
-                nbytes = sum(
-                    int(getattr(v, "nbytes", 0) or 0)
-                    for v in arrays.values()
-                )
+                cnts = counts.get(id(n))
+                nbytes = tree_nbytes(scans.get(id(n)))
                 rows = int(cnts.sum()) if cnts is not None else 0
                 total_rows += rows
                 total_bytes += nbytes
@@ -578,7 +728,9 @@ class MeshExecutor(LocalExecutor):
     # ------------------------------------------------------------------
     def _skew_shuffle_hints(self, plan, scans, counts, ndev):
         """Per (join-node, side) shuffle-chunk capacities measured on the
-        host scan arrays: bucket every traceable single-column join key
+        host scan arrays (scans no device generates; lanes resident in
+        HBM are never pulled back): bucket every traceable single-column
+        join key
         with the SAME splitmix the device shuffle uses and record the
         worst per-(sender, destination) load.  Filters below the join
         only remove rows, so the measurement is a safe overestimate; the
@@ -615,12 +767,16 @@ class MeshExecutor(LocalExecutor):
             if t is None:
                 return None
             scan_node, ssym = t
-            merged = scans.get(str(id(scan_node)))
+            merged = scans.get(id(scan_node))
             if merged is None or ssym not in merged:
                 return None
-            arr = merged[ssym]
-            lens = counts.get(str(id(scan_node)))
-            if arr.ndim != 2 or arr.dtype.kind not in "iu":
+            arr = merged[ssym][0]
+            lens = counts.get(id(scan_node))
+            if (
+                # lanes generated in HBM stay there: no hint
+                not isinstance(arr, np.ndarray)
+                or arr.ndim != 2 or arr.dtype.kind not in "iu"
+            ):
                 return None
             worst = 0
             for d in range(arr.shape[0]):
@@ -685,89 +841,6 @@ class MeshExecutor(LocalExecutor):
         return hints
 
     # ------------------------------------------------------------------
-    def _load_sharded_scans(self, plan: P.PlanNode, ndev: int):
-        scans: Dict[str, Dict[str, np.ndarray]] = {}
-        counts: Dict[str, np.ndarray] = {}
-        dicts: Dict[str, np.ndarray] = {}
-        # preorder TableScan index: the same ordinal FragmentExecutor's
-        # _load_walk uses as the scheduler's split-assignment key, so the
-        # cross-host subclass can look up its ASSIGNED splits
-        scan_idx = [0]
-
-        def walk(node: P.PlanNode):
-            if isinstance(node, P.TableScan):
-                idx = scan_idx[0]
-                scan_idx[0] += 1
-                conn = self.catalogs.get(node.catalog)
-                cols = [c for _, c in node.assignments]
-                provider = conn.page_source_provider()
-                sym_of = {c: self._sym_for(node, c) for c in cols}
-                symbols = [sym_of[c] for c in cols]
-                tmap = dict(node.types)
-                types = [(s, tmap[s]) for s in symbols]
-                splits = self._scan_splits(node, idx, ndev)
-                per_dev: List[Dict[str, tuple]] = []
-                per_dev_dicts: List[Dict[str, np.ndarray]] = []
-                dev_counts: List[int] = []
-                for d in range(ndev):
-                    pages = []
-                    for sp in splits[d::ndev]:
-                        src = provider.create_page_source(sp, cols)
-                        for page in src.pages():
-                            src_dicts = src.dictionaries()
-                            new_cols = [
-                                Column(
-                                    col.type, col.values, col.validity,
-                                    col.dictionary
-                                    if col.dictionary is not None
-                                    else src_dicts.get(c),
-                                )
-                                for c, col in zip(page.names, page.columns)
-                            ]
-                            pages.append(
-                                Page(new_cols, page.count,
-                                     [sym_of[c] for c in page.names])
-                            )
-                    ddicts: Dict[str, np.ndarray] = {}
-                    merged_d, total = merge_pages_to_arrays(
-                        pages, symbols, types, ddicts
-                    )
-                    per_dev.append(merged_d)
-                    per_dev_dicts.append(ddicts)
-                    dev_counts.append(total)
-                self._merge_split_dicts(per_dev, per_dev_dicts, dicts)
-                for s, t in types:
-                    if t.is_dictionary and s not in dicts:
-                        dicts[s] = np.array([], dtype=object)
-                cap = self.ladder.quantize(max(max(dev_counts), 1))
-                merged: Dict[str, np.ndarray] = {}
-                for c in cols:
-                    sym = sym_of[c]
-                    stacked = np.zeros(
-                        (ndev, cap), dtype=per_dev[0][sym][0].dtype
-                    )
-                    okstack = np.zeros((ndev, cap), dtype=bool)
-                    for d in range(ndev):
-                        v, ok = per_dev[d][sym]
-                        stacked[d, : dev_counts[d]] = v
-                        okstack[d, : dev_counts[d]] = (
-                            np.ones(dev_counts[d], dtype=bool)
-                            if ok is None else ok
-                        )
-                    merged[sym] = stacked
-                    merged[sym + "$ok"] = okstack
-                scans[str(id(node))] = merged
-                counts[str(id(node))] = np.array(dev_counts, dtype=np.int64)
-                return
-            if isinstance(node, P.RemoteSource):
-                self._load_remote_source(node, ndev, scans, counts, dicts)
-                return
-            for s in node.sources:
-                walk(s)
-
-        walk(plan)
-        return scans, counts, dicts
-
     def _scan_splits(self, node: P.TableScan, idx: int, ndev: int):
         """All of a table's splits — this executor owns the whole mesh.
         The cross-host subclass narrows this to the splits the
@@ -873,18 +946,12 @@ class _MeshTraceCtx(_TraceCtx):
 
     # -- leaves ---------------------------------------------------------
     def _visit_tablescan(self, node: P.TableScan) -> Batch:
-        arrays = self.scans[str(id(node))]
-        count = self.counts[str(id(node))][0]
-        lanes = {}
-        cap = None
-        for sym, arr in arrays.items():
-            if sym.endswith("$ok"):
-                continue
-            v = arr[0]  # local shard [1, cap] -> [cap]
-            cap = v.shape[0]
-            ok = arrays[sym + "$ok"][0]
-            lanes[sym] = (v, ok)
-        sel = jnp.arange(cap) < count
+        # this device's row block of every [ndev, cap] lane, and its own
+        # entry of the live-row vector
+        lanes = dict(self.scans[id(node)])
+        count = lanes.pop("__count__")[0]
+        lanes = {sym: (v[0], ok[0]) for sym, (v, ok) in lanes.items()}
+        sel = jnp.arange(self.ex._scan_caps[id(node)]) < count
         return Batch(lanes, sel, replicated=False)
 
     def _visit_values(self, node: P.Values) -> Batch:
@@ -1641,20 +1708,17 @@ class CrossHostFragmentExecutor(MeshExecutor):
             if t.is_dictionary and s not in local_dicts:
                 local_dicts[s] = np.array([], dtype=object)
         dicts.update(local_dicts)
-        cap = self.ladder.quantize(max(total, 1))
-        out: Dict[str, np.ndarray] = {}
-        for sym in node.symbols:
-            v, ok = merged[sym]
-            stacked = np.zeros((ndev, cap), dtype=v.dtype)
-            stacked[:, :total] = v[:total]
-            okstack = np.zeros((ndev, cap), dtype=bool)
-            okstack[:, :total] = (
-                np.ones(total, dtype=bool) if ok is None else ok[:total]
-            )
-            out[sym] = stacked
-            out[sym + "$ok"] = okstack
-        scans[str(id(node))] = out
-        counts[str(id(node))] = np.full(ndev, total, dtype=np.int64)
+        shard = {
+            sym: (v[:total], None if ok is None else ok[:total])
+            for sym, (v, ok) in merged.items() if sym in node.symbols
+        }
+        scans[id(node)] = _stack_shards(
+            [shard] * ndev, self.ladder.quantize(max(total, 1))
+        )
+        counts[id(node)] = np.full(ndev, total, dtype=np.int64)
+        self._scan_dictfp[id(node)] = dict_fingerprint(
+            local_dicts, list(node.symbols)
+        )
 
 
 # class-attribute hook resolution: _MeshTraceCtx is defined below
