@@ -59,6 +59,7 @@ def resident():
     """One warm resident query's spans (the second execution: plan and
     fragment caches hit)."""
     s = tpch_session(SF, result_cache=False)
+    _drain(s)  # the ring is the process's: earlier tests' spans go first
     s.execute(Q6)
     cold = _drain(s)
     page = s.execute(Q6)
@@ -70,8 +71,9 @@ def streamed():
     """One warm streamed query (a memory limit far under lineitem), with
     the thread and the open span of every prefetch-pool `preload` noted."""
     s = tpch_session(SF, result_cache=False, query_max_memory_bytes=600_000)
-    s.execute(Q1)
     _drain(s)
+    s.execute(Q1)
+    cold = _drain(s)
     seen = []
     real = FragmentExecutor.preload
 
@@ -84,7 +86,8 @@ def streamed():
         page = s.execute(Q1)
     finally:
         FragmentExecutor.preload = real
-    return {"spans": _drain(s), "preloads": seen, "page": page}
+    return {"spans": _drain(s), "preloads": seen, "page": page,
+            "cold": cold}
 
 
 # --- the tracer itself ----------------------------------------------------
@@ -275,11 +278,16 @@ def test_pool_thread_spans_hang_under_execute(streamed):
             assert _ancestors(s, by_id)[0] == "execute"
         if s.name in ("tile_load", "tile_upload"):
             assert _ancestors(s, by_id)[:2] == ["tile_stage", "execute"]
-        if s.name == "devgen":
-            # opened on the watchdog thread of the staged upload
-            assert _ancestors(s, by_id)[:3] == [
-                "stage_lanes", "tile_upload", "tile_stage"]
-    assert any(s.name == "devgen" for s in spans)
+    # the tiles are generated by the session's first query and found
+    # resident by this one; the generator's span opens on the watchdog
+    # thread of the staged upload
+    assert not any(s.name == "devgen" for s in spans)
+    cold = {s.span_id: s for s in streamed["cold"]}
+    generated = [s for s in streamed["cold"] if s.name == "devgen"]
+    assert generated
+    for s in generated:
+        assert _ancestors(s, cold)[:3] == [
+            "stage_lanes", "tile_upload", "tile_stage"]
 
 
 # --- the profiler records the spans ---------------------------------------

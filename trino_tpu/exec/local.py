@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
+import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
@@ -81,7 +82,11 @@ class DeviceScanCache:
     analog of a warm OS page cache is warm HBM — repeated scans of an
     unchanged (connector-versioned) table reuse uploaded device arrays,
     and skip the host->HBM copy (or the on-device regeneration).
-    Entries evict in insertion order once the byte budget is exceeded."""
+    Entries evict in insertion order once the byte budget is exceeded.
+
+    One lock covers the table and its tallies: a streamed query's prefetch
+    thread looks tiles up and puts them while the query thread reads, and
+    the memory manager may revoke (`drop_all`) from a third."""
 
     def __init__(self, max_bytes: int = 6 << 30):
         self.max_bytes = max_bytes
@@ -91,37 +96,52 @@ class DeviceScanCache:
         self.misses = 0
         self.puts = 0
         self.evictions = 0
+        self._lock = threading.Lock()
 
     def get(self, key: tuple, record: bool = True):
         """record=False for secondary lookups of an already-counted entry
         (the device-lane rebind path re-reads what _load_one_scan found)."""
-        entry = self.entries.get(key)
-        if record:
-            if entry is not None:
-                self.hits += 1
-            else:
-                self.misses += 1
-        return entry
+        with self._lock:
+            entry = self.entries.get(key)
+            if record:
+                if entry is not None:
+                    self.hits += 1
+                else:
+                    self.misses += 1
+            return entry
 
     def put(self, key: tuple, entry: dict, nbytes: int):
-        while self.bytes + nbytes > self.max_bytes and self.entries:
-            oldest = next(iter(self.entries))
-            self.bytes -= self.entries.pop(oldest).get("nbytes", 0)
+        with self._lock:
+            self._evict(key)  # a key put again replaces its entry
+            while self.bytes + nbytes > self.max_bytes and self.entries:
+                self._evict(next(iter(self.entries)))
+            entry["nbytes"] = nbytes
+            self.entries[key] = entry
+            self.bytes += nbytes
+            self.puts += 1
+
+    def _evict(self, key: tuple) -> None:
+        entry = self.entries.pop(key, None)
+        if entry is not None:
+            self.bytes -= entry.get("nbytes", 0)
             self.evictions += 1
-        entry["nbytes"] = nbytes
-        self.entries[key] = entry
-        self.bytes += nbytes
-        self.puts += 1
+
+    def drop(self, keys) -> None:
+        """Evict these entries, where held."""
+        with self._lock:
+            for key in keys:
+                self._evict(key)
 
     def drop_all(self) -> int:
         """Evict everything; returns bytes freed.  Registered with the
         LocalMemoryManager as a revocable resource — warm-HBM cache is
         the first thing to go under memory pressure."""
-        freed = self.bytes
-        self.evictions += len(self.entries)
-        self.entries.clear()
-        self.bytes = 0
-        return freed
+        with self._lock:
+            freed = self.bytes
+            self.evictions += len(self.entries)
+            self.entries.clear()
+            self.bytes = 0
+            return freed
 
     def stats(self) -> Dict[str, int]:
         return {
@@ -200,6 +220,14 @@ class _LazyDeviceLane:
 
     def __init__(self, nbytes: int):
         self.nbytes = int(nbytes)
+
+
+def devgen_lane_bytes(spec: dict, cols, rows: int) -> int:
+    """HBM bytes of a device-generated scan padded to `rows` rows: each
+    column's value lane at the recipe's width, and the one validity plane
+    the generator's lanes share."""
+    widths = spec.get("widths") or {}
+    return int(rows) * (sum(int(widths.get(c, 8)) for c in cols) + 1)
 
 
 def merge_pages_to_arrays(pages, symbols, types, dicts):
@@ -1245,6 +1273,10 @@ class LocalExecutor:
         self._devgen[id(node)] = spec
         if cache is not None and key is not None:
             col_of = {s: c for s, c in node.assignments}
+            # charged what the lanes will hold in HBM: every shard padded
+            # to the rung `_device_lanes` dispatches at (a streamed tile's
+            # shared shape is well above its own row count)
+            shard_rows = [n for _, _, n in spec.get("shards") or ()] or [count]
             cache.put(
                 key,
                 {
@@ -1253,7 +1285,12 @@ class LocalExecutor:
                     "total": count, "dev": {}, "dictfp": fp,
                     "devgen": spec,
                 },
-                sum(lane[0].nbytes for lane in merged.values()),
+                devgen_lane_bytes(
+                    spec, cols,
+                    len(shard_rows) * self._scan_cap(
+                        node, max(max(shard_rows), 1)
+                    ),
+                ),
             )
         return True
 
@@ -1332,19 +1369,30 @@ class LocalExecutor:
             )
         return {s: lanes[c] for s, c in zip(syms, cols)}
 
+    def _scan_cap(self, node, count) -> int:
+        """The rung one scan's lanes are padded to: the ladder's for its
+        row count, raised to the tiles' shared shape in a streamed tile."""
+        cap = self.ladder.quantize(count)
+        override = int(self.config.get("scan_cap_override") or 0)
+        if override and isinstance(node, P.TableScan):
+            cap = max(cap, override)
+        return cap
+
     def _device_lanes(self, node: P.TableScan, arrays, count, nid=None):
         """Pad + upload one scan's host arrays to device lanes, reusing
         cached device arrays when the scan is version-cacheable (the
         host->HBM transfer is the cold cost of a scan).
         `nid` keys the scan-keys table for node-less sources (streaming
         RemoteSource inputs, cached per run)."""
-        cap = self.ladder.quantize(count)
-        override = int(self.config.get("scan_cap_override") or 0)
-        if override and isinstance(node, P.TableScan):
-            cap = max(cap, override)
-        cache: Optional[DeviceScanCache] = self.config.get(
-            "scan_cache"
-        ) or getattr(self, "_streaming_cache", None)
+        cap = self._scan_cap(node, count)
+        # a table scan's lanes live in the session's cache where the
+        # executor was given it; a streamed run's remote inputs (and the
+        # tiles of a scan it does not keep) in the run's own
+        cache: Optional[DeviceScanCache] = getattr(
+            self, "_streaming_cache", None
+        )
+        if isinstance(node, P.TableScan):
+            cache = self.config.get("scan_cache") or cache
         if nid is None and node is not None:
             nid = id(node)
         if nid is not None:
@@ -1377,12 +1425,20 @@ class LocalExecutor:
             donatable = self._lane_donatable = {}
         if nid is not None:
             donatable[nid] = entry is None
+        # cached lanes are keyed by column alone: one padded to another
+        # rung than this dispatch's is not this program's input (the
+        # validity plane is [cap], or [ndev, cap] on a mesh)
+        held = {
+            col: lane
+            for col, lane in (entry["dev"] if entry is not None else {}).items()
+            if lane[1].shape[-1] == cap
+        }
         lanes = {}
         gen_out = None
         for sym, (arr, valid) in arrays.items():
             col = sym_to_col.get(sym, sym)
-            if entry is not None and col in entry["dev"]:
-                lanes[sym] = entry["dev"][col]
+            if col in held:
+                lanes[sym] = held[col]
                 continue
             if isinstance(arr, _LazyDeviceLane):
                 if gen_out is None:
@@ -1390,8 +1446,7 @@ class LocalExecutor:
                     lazy_syms = [
                         s for s, (a, _v) in arrays.items()
                         if isinstance(a, _LazyDeviceLane)
-                        and not (entry is not None
-                                 and sym_to_col.get(s, s) in entry["dev"])
+                        and sym_to_col.get(s, s) not in held
                     ]
                     gen_out = self._generate_device_scan(
                         spec, lazy_syms, sym_to_col, cap

@@ -24,6 +24,12 @@ streaming run (a shared DeviceScanCache entry keyed by fragment id), so
 tiles re-dispatch against resident build tables instead of re-uploading
 them (the LazyBlock-stays-resident analog: HBM residency saves the
 host->device copy per tile).
+
+A tiled scan's own lanes normally die with their tile.  When the
+connector generates the scan on the device and ALL its tiles fit the
+session's scan cache beside what is there (`_resident_tile_cache`), the
+tiles are kept in it, one entry a tile: the first query generates them,
+later ones find them and run the tile programs and nothing else.
 """
 from __future__ import annotations
 
@@ -107,6 +113,10 @@ def _wide_agg_count(plan: P.PlanNode) -> int:
     return n
 
 
+# HBM a wide-decimal aggregate adds to the compiled program, as a share
+# of its scan lanes (estimate_program_bytes' one calibration point)
+WIDE_AGG_FACTOR = 0.28
+
 # u64 lanes the generator program keeps live per row on top of its
 # output lanes: the row-index lane, the splitmix64 hash state, one value
 # lane (reused across columns), and lineitem's cumsum/searchsorted slot
@@ -147,9 +157,63 @@ def estimate_program_bytes(executor, plan: P.PlanNode) -> float:
     BEFORE submitting a compile that XLA would refuse for HBM."""
     scan = estimate_plan_scan_bytes(executor, plan)
     return (
-        scan * (1.0 + 0.28 * _wide_agg_count(plan))
+        scan * (1.0 + WIDE_AGG_FACTOR * _wide_agg_count(plan))
         + _devgen_temp_bytes(executor, plan)
     )
+
+
+def _resident_tile_cache(executor, frag, node, tile_splits, tile_rows: int):
+    """The session's scan cache when every tile of this streamed scan may
+    stay in it, else None (the tiles then live and die with their
+    dispatch, as a scan that is streamed because it does not fit must).
+
+    A scan is kept when the connector generates it on the device and
+    versions it (there are no host arrays to hold, and an entry is
+    regenerated from its recipe if dropped), the session caches scans,
+    and ALL its tiles at their padded size fit in what the cache has free
+    and, with the tile program's temporaries, under the device's limit.
+    All or none: the cache evicts in insertion order, so a cycle of tiles
+    through a budget one tile short evicts each tile just before it is
+    asked for again and never hits, while holding the bytes.  Nothing
+    else in the cache is evicted to make room; tiles already there (the
+    previous query's) count as held, so a warm query asks nothing more."""
+    from ..memory.pools import detect_device_bytes
+    from .local import devgen_lane_bytes
+
+    cache = executor.config.get("scan_cache")
+    conn = executor.catalogs.get(node.catalog)
+    devgen_fn = getattr(conn, "device_generation", None)
+    if cache is None or devgen_fn is None or not executor.config.get(
+        "device_generation", True
+    ):
+        return None
+    keys = [executor._scan_cache_key(node, sp) for sp in tile_splits]
+    if keys[0] is None:
+        return None
+    missing = sum(cache.get(k, record=False) is None for k in keys)
+    if not missing:
+        return cache
+    cols = [c for _, c in node.assignments]
+    try:
+        spec = devgen_fn(node.table, cols, tile_splits[0])
+    except Exception:  # noqa: BLE001 — the tiles then load on the host
+        spec = None
+    if spec is not None:
+        tile_bytes = devgen_lane_bytes(spec, cols, tile_rows)
+        after = cache.bytes + missing * tile_bytes
+        # beside the resident tiles: the tile program's wide-aggregate
+        # temporaries and, on a miss, the generator's
+        room = (
+            tile_bytes * WIDE_AGG_FACTOR * _wide_agg_count(frag.root)
+            + 8.0 * DEVGEN_TEMP_LANES * tile_rows
+        )
+        device = detect_device_bytes()
+        if after <= cache.max_bytes and (
+            device is None or after + room <= device
+        ):
+            return cache
+    cache.drop(keys)  # a part of the tiles is of no use
+    return None
 
 
 # additive per-dispatch counters a tile executor accumulates that must
@@ -159,6 +223,7 @@ _TILE_COUNTERS = (
     "preuploads", "preupload_bytes", "donated_dispatches",
     "donated_bytes", "fusedAggregates", "fusedTerms", "fusionRejects",
     "devgenWallS", "devgenCompileS", "lineCountOrdersHashed",
+    "residentTileHits", "residentTileMisses",
 )
 
 
@@ -286,13 +351,15 @@ def execute_streaming(executor, plan: P.Output, frags, memory_limit: int) -> Pag
     by_id = {f.id: f for f in frags}
     pages_by_fragment: Dict[int, List[Page]] = {}
     # device residency for build/remote inputs across tiles, scoped to
-    # this streaming run (tiles must not thrash the session scan cache).
-    # Cross-run isolation comes from the FRESH cache object per run; the
-    # remote cache keys themselves are stable so the jit-cache key (which
-    # embeds scan keys) stays warm across repeat executions.
+    # this streaming run.  Cross-run isolation comes from the FRESH cache
+    # object per run; the remote cache keys themselves are stable so the
+    # jit-cache key (which embeds scan keys) stays warm across repeat
+    # executions.  A tiled scan's own lanes are in neither cache and die
+    # with their dispatch, unless all its tiles fit the session's
+    # (`_resident_tile_cache`).
     run_cache = DeviceScanCache()
 
-    def tile_config() -> dict:
+    def tile_config(scan_cache=None) -> dict:
         cfg = dict(executor.config)
         # tiles quantize on the parent's resolved ladder object — not a
         # re-parse of the spec — so a census-tuned ladder file read at
@@ -307,7 +374,10 @@ def execute_streaming(executor, plan: P.Output, frags, memory_limit: int) -> Pag
         cfg.pop("memory_pool", None)
         cfg.pop("memory_manager", None)
         cfg["spill_enabled"] = False
-        cfg["scan_cache"] = None
+        # the session's cache only for a scan whose tiles are all kept:
+        # tiles that cycle through it one short of fitting would evict
+        # each other, and other tables' entries, and never hit
+        cfg["scan_cache"] = scan_cache
         return cfg
 
     done = set()
@@ -341,14 +411,19 @@ def execute_streaming(executor, plan: P.Output, frags, memory_limit: int) -> Pag
             # the same rung and reuse one compiled program engine-wide
             est_tile_rows = executor.ladder.quantize(max(est_tile_rows, 128))
             tile_starts = list(range(0, len(splits), per))
+            # decided once for the scan, before the first tile is staged
+            kept = _resident_tile_cache(
+                executor, f, scan_nodes[idx],
+                [splits[i: i + per] for i in tile_starts], est_tile_rows,
+            )
 
             # the pool thread's spans join the query's trace
             query_span = TRACER.current_span()
 
             def make_loaded(i: int) -> FragmentExecutor:
                 with TRACER.span("tile_stage", parent=query_span,
-                                 tile=i // per):
-                    cfg = tile_config()
+                                 tile=i // per) as stage:
+                    cfg = tile_config(kept)
                     if est_tile_rows:
                         cfg["scan_cap_override"] = est_tile_rows
                     fe = FragmentExecutor(
@@ -364,6 +439,14 @@ def execute_streaming(executor, plan: P.Output, frags, memory_limit: int) -> Pag
                     # serializing in front of the next dispatch
                     with TRACER.span("tile_upload"):
                         fe.preupload(f.root)
+                    # a device-generated tile was found resident, or its
+                    # generator has just run
+                    prof = fe.kernel_profile
+                    found = bool(fe._devgen) and not prof.get("devgenWallS")
+                    stage.attributes["resident"] = found
+                    if fe._devgen:
+                        prof["residentTileHits" if found
+                             else "residentTileMisses"] = 1
                 return fe
 
             # double-buffered tile pipeline: while tile i computes on the
