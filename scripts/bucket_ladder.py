@@ -4,11 +4,11 @@
 The compile observatory's shape census records, per kernel family, the
 row-count distribution real traffic presented (a bounded power-of-two
 sketch, persisted as ``census-*.json`` snapshots next to the ``co-*``
-ledger segments when ``compile_observatory_dir`` is set — e.g. by
-``BENCH_SERVE=smoke python bench.py``).  This tool turns that census
-into the direct input ROADMAP item 3 needs: an equi-height padding
-ladder (Ioannidis, *The History of Histograms*, VLDB 2003 — applied to
-row counts instead of values) whose rungs sit at equal-mass quantiles
+ledger segments by a session with ``compile_observatory_dir`` set).
+This tool turns that census into the direct input ROADMAP item 3
+needs: an equi-height padding ladder (Ioannidis, *The History of
+Histograms*, VLDB 2003 — applied to row counts instead of values)
+whose rungs sit at equal-mass quantiles
 of the observed distribution, with the predicted waste ratio
 (padded/actual rows) the ladder would have produced against the same
 traffic.

@@ -13,10 +13,9 @@ Two checks:
 
   2. **No unregistered pallas kernels.**  Every ``def *_kernel(`` in
      ``trino_tpu/ops/pallas_kernels.py`` must appear as a key in its
-     ``KERNEL_REGISTRY`` — the registry is what the kernel profile and
-     the bench artifacts use to attribute dispatches, so an unregistered
-     kernel is invisible to regression triage (how the round-5 bench crash
-     stayed unattributed for two rounds).
+     ``KERNEL_REGISTRY`` — the registry is what the kernel profile
+     uses to attribute dispatches, so an unregistered kernel is
+     invisible to regression triage.
 
 Run standalone (``python scripts/check_donation.py``, exit 1 on
 violations) or via ``scripts/lint.py`` / the tier-1 lint test.

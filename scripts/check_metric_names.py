@@ -47,8 +47,8 @@ JOURNAL_LITERAL_RE = re.compile(
     r'["\'](trino_tpu_journal_[a-z0-9_]*)["\']'
 )
 DOCTOR_LITERAL_RE = re.compile(r'["\'](trino_tpu_doctor_[a-z0-9_]*)["\']')
-# resource-group and autoscaler literals likewise: the serving bench and
-# the fairness acceptance tests assert on these series by full name
+# resource-group and autoscaler literals likewise: the fairness
+# acceptance tests assert on these series by full name
 RESOURCE_GROUP_LITERAL_RE = re.compile(
     r'["\'](trino_tpu_resource_group_[a-z0-9_]*)["\']'
 )
@@ -60,16 +60,14 @@ AUTOSCALER_LITERAL_RE = re.compile(
 COMPILE_LITERAL_RE = re.compile(
     r'["\'](trino_tpu_compile_[a-z0-9_]*)["\']'
 )
-# serving-observatory literals likewise: the serve-smoke SLO gate and
-# the signature-census acceptance tests assert on these series by full
-# name
+# serving-observatory literals likewise: the signature-census
+# acceptance tests assert on these series by full name
 SLO_LITERAL_RE = re.compile(r'["\'](trino_tpu_slo_[a-z0-9_]*)["\']')
 SIGNATURE_LITERAL_RE = re.compile(
     r'["\'](trino_tpu_signature_[a-z0-9_]*)["\']'
 )
-# object-store and lakehouse literals likewise: the lake bench phase and
-# the concurrent-writer acceptance tests assert on these series by full
-# name
+# object-store and lakehouse literals likewise: the concurrent-writer
+# acceptance tests assert on these series by full name
 OBJSTORE_LITERAL_RE = re.compile(
     r'["\'](trino_tpu_objstore_[a-z0-9_]*)["\']'
 )
@@ -87,7 +85,6 @@ SPAN_NAME_RE = re.compile(r"^[a-z][a-z0-9_]*$")
 RECORD_FIELD_RE = re.compile(r"^[a-z][a-zA-Z0-9]*$")
 
 SCAN_DIRS = ("trino_tpu", "tests", "scripts")
-SCAN_FILES = ("bench.py",)
 
 
 def iter_source_files(root: str):
@@ -99,10 +96,6 @@ def iter_source_files(root: str):
             for fn in filenames:
                 if fn.endswith(".py"):
                     yield os.path.join(dirpath, fn)
-    for fn in SCAN_FILES:
-        p = os.path.join(root, fn)
-        if os.path.exists(p):
-            yield p
 
 
 def check_tree(root: str):

@@ -21,7 +21,6 @@ import sys
 ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 
 SCAN_DIRS = ("trino_tpu", "scripts", "tests")
-SCAN_FILES = ("bench.py",)
 
 # the canonical home of the idiom; everything else must quantize
 # through exec.shapes (lane_align / PaddingLadder.quantize)
@@ -45,10 +44,6 @@ def _py_files():
             for fn in filenames:
                 if fn.endswith(".py"):
                     yield os.path.join(dirpath, fn)
-    for fn in SCAN_FILES:
-        p = os.path.join(ROOT, fn)
-        if os.path.exists(p):
-            yield p
 
 
 def main() -> int:

@@ -29,7 +29,6 @@ REGISTRATION_RE = re.compile(
 )
 
 SCAN_DIRS = ("trino_tpu", "tests", "scripts")
-SCAN_FILES = ("bench.py",)
 
 
 def iter_source_files(root: str):
@@ -41,10 +40,6 @@ def iter_source_files(root: str):
             for fn in filenames:
                 if fn.endswith(".py"):
                     yield os.path.join(dirpath, fn)
-    for fn in SCAN_FILES:
-        p = os.path.join(root, fn)
-        if os.path.exists(p):
-            yield p
 
 
 def check_tree(root: str):

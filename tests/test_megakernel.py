@@ -139,7 +139,6 @@ def test_megakernels_prop_validated():
         assert p.get("megakernels") == v
     with pytest.raises(ValueError):
         p.set("megakernels", "sometimes")
-    assert p.get("double_buffer_depth") == 1
     assert p.get("donate_pages") is True
 
 

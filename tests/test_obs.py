@@ -1,7 +1,5 @@
 """obs/ subsystem tests: flight recorder (ring rotation, kill -9 crash
-survival, fault attribution + standalone replay), HBM bandwidth ledger
-math against hand-computed scan bytes, and the bench regression
-sentinel's verdicts on synthetic and real BENCH trajectories.
+survival, fault attribution + standalone replay).
 """
 import glob
 import json
@@ -9,8 +7,6 @@ import os
 import signal
 import subprocess
 import sys
-import tempfile
-import time
 
 import numpy as np
 import pytest
@@ -26,7 +22,6 @@ from trino_tpu.session import tpch_session
 sys.path.insert(
     0, os.path.join(os.path.dirname(__file__), "..", "scripts")
 )
-import bench_sentinel  # noqa: E402
 import flightrec  # noqa: E402
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -185,179 +180,3 @@ def test_system_flight_recorder_table():
     assert rows, "default in-memory recorder captured nothing"
     kinds = {r[0] for r in rows}
     assert "dispatch" in kinds and "complete" in kinds
-
-
-# -- bandwidth ledger ---------------------------------------------------
-
-def test_ledger_math_matches_hand_computed_bytes():
-    """Acceptance: the ledger's inputBytes for a Q6-style scan matches
-    the hand-computed unpadded scan bytes within 10%, and GB/s is
-    exactly totalBytes / wall."""
-    s = tpch_session(0.01)
-    s.properties.set("bandwidth_ledger", True)
-    s.properties.set("result_cache", False)
-    page = s.execute(
-        "select sum(l_extendedprice * l_discount) from lineitem "
-        "where l_discount < 0.05"
-    )
-    assert page.count == 1
-    prof = s.last_kernel_profile
-    entries = prof.get("bandwidth")
-    assert entries, "ledger enabled but no entries recorded"
-    e = entries[0]
-    # the system table reads the CURRENT last profile — query it before
-    # any later statement overwrites that
-    rows = s.execute(
-        "select kernel, input_bytes, gbps "
-        "from system.runtime.kernel_bandwidth"
-    ).to_pylist()
-    assert any(r[0] == e["kernel"] for r in rows)
-    # hand-computed: two int64 value lanes (extendedprice, discount) at
-    # the table's unpadded row count; tpch columns are non-null, so no
-    # validity lanes ride along
-    nrows = s.execute(
-        "select count(*) from lineitem"
-    ).to_pylist()[0][0]
-    expected = 2 * nrows * 8
-    assert abs(e["inputBytes"] - expected) / expected < 0.10, (
-        e["inputBytes"], expected,
-    )
-    assert e["executions"] >= 1
-    assert e["deviceWallS"] > 0
-    total = e["inputBytes"] + e["outputBytes"] + e["intermediateBytes"]
-    assert e["totalBytes"] == total
-    assert e["gbps"] == pytest.approx(
-        e["totalBytes"] / e["deviceWallS"] / 1e9
-    )
-    # summary rolled into the kernel profile
-    summary = prof["summary"]
-    assert summary["ledgerBytes"] >= total
-    assert summary["effectiveGbps"] > 0
-
-
-def test_explain_analyze_shows_bandwidth_ledger():
-    s = tpch_session(0.001)
-    text = "\n".join(
-        r[0] for r in s.execute(
-            "explain analyze select sum(l_extendedprice) from lineitem"
-        ).to_pylist()
-    )
-    assert "HBM bandwidth ledger" in text
-    assert "GB/s" in text and "roofline" in text
-
-
-def test_ledger_off_by_default():
-    s = tpch_session(0.001)
-    s.execute("select count(*) from lineitem")
-    prof = s.last_kernel_profile or {}
-    assert "bandwidth" not in prof
-
-
-# -- bench sentinel -----------------------------------------------------
-
-def _wrap(n, rc, parsed=None, tail=""):
-    return {"n": n, "cmd": "bench", "rc": rc, "tail": tail,
-            "parsed": parsed}
-
-
-def _write_rounds(tmp_path, rounds):
-    for n, doc in rounds:
-        with open(
-            os.path.join(str(tmp_path), "BENCH_r%02d.json" % n), "w"
-        ) as f:
-            json.dump(doc, f)
-
-
-def test_sentinel_synthetic_trajectory(tmp_path):
-    cfg = lambda rps: {"configs": {"q6": {"rows_per_sec": rps}}}  # noqa: E731
-    _write_rounds(tmp_path, [
-        (1, _wrap(1, 0, cfg(100.0))),           # baseline
-        (2, _wrap(2, 0, cfg(101.0))),           # steady
-        (3, _wrap(3, 0, cfg(50.0))),            # regression (x0.50)
-        (4, _wrap(4, 0, cfg(140.0))),           # improved vs r03
-        (5, _wrap(5, 0, None,
-                  tail='"q6": {"error": "JaxRuntimeError: UNAVAILABLE: '
-                       'TPU worker process crashed"}')),
-    ])
-    rounds = [
-        bench_sentinel.load_round(p)
-        for p in sorted(glob.glob(str(tmp_path / "BENCH_r*.json")))
-    ]
-    verdicts = {v["round"]: v["verdict"]
-                for v in bench_sentinel.judge(rounds)}
-    assert verdicts == {
-        1: "baseline", 2: "steady", 3: "regression",
-        4: "improved", 5: "crash-introduced",
-    }
-
-
-def test_sentinel_flags_bandwidth_regression_when_wall_holds(tmp_path):
-    """Rows/s steady but the ledger's effective GB/s collapses: the same
-    answer is moving more bytes (fusion fell back, donation stopped) —
-    the sentinel must flag it even though wall-clock verdicts say steady.
-    Rounds without bandwidth data are never judged on it."""
-    cfg = lambda rps, gbps=None: {"configs": {"q6": dict(  # noqa: E731
-        {"rows_per_sec": rps},
-        **({"effective_gbps": gbps} if gbps is not None else {}),
-    )}}
-    _write_rounds(tmp_path, [
-        (1, _wrap(1, 0, cfg(100.0, 30.0))),   # baseline
-        (2, _wrap(2, 0, cfg(101.0, 12.0))),   # wall holds, GB/s x0.40
-        (3, _wrap(3, 0, cfg(100.0, 11.9))),   # vs r02: both hold now
-        (4, _wrap(4, 0, cfg(102.0))),         # no ledger data: no verdict
-    ])
-    rounds = [
-        bench_sentinel.load_round(p)
-        for p in sorted(glob.glob(str(tmp_path / "BENCH_r*.json")))
-    ]
-    verdicts = bench_sentinel.judge(rounds)
-    by_round = {v["round"]: v for v in verdicts}
-    assert by_round[2]["verdict"] == "bandwidth-regression"
-    assert by_round[2]["bw_ratio"] == 0.4
-    assert "despite wall holding" in by_round[2]["reason"]
-    assert by_round[3]["verdict"] == "steady"
-    assert by_round[4]["verdict"] == "steady"
-    assert "bw_ratio" not in by_round[4]
-    md = bench_sentinel.to_markdown(verdicts)
-    assert "r02 (bandwidth-regression)" in md
-
-
-def test_sentinel_timeout_round_is_regression(tmp_path):
-    _write_rounds(tmp_path, [
-        (1, _wrap(1, 0, {"configs": {"q6": {"rows_per_sec": 10.0}}})),
-        (2, _wrap(2, 124, None, tail="WARNING: something\n")),
-    ])
-    rounds = [
-        bench_sentinel.load_round(p)
-        for p in sorted(glob.glob(str(tmp_path / "BENCH_r*.json")))
-    ]
-    v = bench_sentinel.judge(rounds)[-1]
-    assert v["verdict"] == "regression"
-    assert "124" in v["reason"]
-
-
-def test_sentinel_recovers_configs_from_truncated_tail():
-    # head-truncated mid-object: the partial leader is skipped, the
-    # complete objects are recovered
-    tail = (
-        'per_sec": 1.0, "configs": {"a": {"rows_per_sec": 5.0}, '
-        '"b": {"rows_per_sec": 7.0, "scan_bytes": 10}, '
-        '"c": {"rows_per'
-    )
-    cfgs = bench_sentinel.recover_configs(tail)
-    assert set(cfgs) == {"a", "b"}
-    assert cfgs["b"]["rows_per_sec"] == 7.0
-
-
-def test_sentinel_markdown_names_flagged_rounds(tmp_path):
-    _write_rounds(tmp_path, [
-        (1, _wrap(1, 0, {"configs": {"q6": {"rows_per_sec": 10.0}}})),
-        (2, _wrap(2, 124, None)),
-    ])
-    rounds = [
-        bench_sentinel.load_round(p)
-        for p in sorted(glob.glob(str(tmp_path / "BENCH_r*.json")))
-    ]
-    md = bench_sentinel.to_markdown(bench_sentinel.judge(rounds))
-    assert "| r02 |" in md
-    assert "Flagged: r02 (regression)" in md

@@ -286,28 +286,6 @@ def test_seeded_slow_worker_is_flagged_and_hedged():
         r.stop()
 
 
-# --- sentinel drilldown --------------------------------------------------
-
-
-def test_sentinel_regression_names_worst_operator():
-    import bench_sentinel
-
-    base = {
-        "round": 1, "file": "r1", "rc": 0, "crashes": 0, "errors": 0,
-        "metrics": {"q6": 100.0},
-        "op_walls": {"Aggregate:3": 0.2, "TableScan:5": 0.3},
-    }
-    bad = {
-        "round": 2, "file": "r2", "rc": 0, "crashes": 0, "errors": 0,
-        "metrics": {"q6": 50.0},  # x0.50 < the 0.70 regression ratio
-        "op_walls": {"Aggregate:3": 1.4, "TableScan:5": 0.35},
-    }
-    verdicts = bench_sentinel.judge([base, bad])
-    assert verdicts[1]["verdict"] == "regression"
-    assert verdicts[1]["culprit_operator"] == "Aggregate:3"
-    assert "Aggregate:3" in verdicts[1]["reason"]
-
-
 # --- lint wiring ---------------------------------------------------------
 
 

@@ -3,16 +3,8 @@ the coordinator finalizes, the two-segment store survives restarts and
 torn tails, history backfill fills gaps without double counting, SLO
 burns journal throttled events the doctor ranks below overload, and the
 census/affinity/SLO surfaces answer over SQL and HTTP.
-
-The headline serving gate rides in scripts/check_serve_smoke.py: the
-steady-state phase of the serve smoke must record ZERO fast-window SLO
-burns (the fast tests here pin that gate's logic on synthetic
-artifacts; the slow end-to-end run lives in test_compile_observatory).
 """
 import json
-import os
-import subprocess
-import sys
 import urllib.request
 
 import pytest
@@ -21,8 +13,6 @@ from trino_tpu.obs import compile_observatory as co
 from trino_tpu.obs import doctor, journal
 from trino_tpu.obs import serving_observatory as so
 from trino_tpu.session import tpch_session
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 TPCH = (("tpch", "tpch", {"tpch.scale-factor": 0.01}),)
 
@@ -295,51 +285,3 @@ def test_coordinator_feeds_census_slo_and_http_surfaces():
         ), aff
 
 
-# --- the serve-smoke SLO gate --------------------------------------------
-
-
-def _gate(result: dict) -> subprocess.CompletedProcess:
-    doc = json.dumps({"bench_only": "serve_smoke", "result": result})
-    return subprocess.run(
-        [sys.executable,
-         os.path.join(REPO, "scripts", "check_serve_smoke.py")],
-        input=doc, capture_output=True, text=True, timeout=60,
-    )
-
-
-def _healthy_result(**over):
-    base = {
-        "failed_queries": 0,
-        "tenants": {"interactive": {"ok": 5, "p99_ms": 10.0}},
-        "fairness": {"starts_per_weight": {"interactive": 1.2}},
-        "steady_state_shape_miss_compiles": 0,
-        "ladder_size": 24, "max_programs_per_family": 2,
-        "qps": 5.0, "shed_total": 0,
-        "steady_fast_window_burns": 0,
-        "slo": {"interactive": {
-            "fast_burn_rate": 0.0, "slow_burn_rate": 0.0,
-            "peak_fast_burn": 0.0, "violations": 0, "observed": 5,
-        }},
-    }
-    base.update(over)
-    return base
-
-
-def test_check_serve_smoke_gates_slo_accounting_and_steady_burns():
-    assert _gate(_healthy_result()).returncode == 0
-    r = _gate(_healthy_result(slo={}))
-    assert r.returncode == 1
-    assert "SLO accounting missing" in r.stderr
-    r = _gate(_healthy_result(
-        slo={"interactive": {"violations": 0}}  # burn fields gone
-    ))
-    assert r.returncode == 1
-    assert "SLO accounting missing" in r.stderr
-    missing = _healthy_result()
-    del missing["steady_fast_window_burns"]
-    r = _gate(missing)
-    assert r.returncode == 1
-    assert "steady_fast_window_burns missing" in r.stderr
-    r = _gate(_healthy_result(steady_fast_window_burns=2))
-    assert r.returncode == 1
-    assert "SLO burn(s) during the" in r.stderr

@@ -243,7 +243,7 @@ def test_q6_device_loss_degrades_to_cpu_then_recovers(oracle_conn):
     assert sup.device_state() == ACTIVE  # recovered run stayed on device
 
 
-def test_kernel_profile_and_bench_forensics_carry_breadcrumb():
+def test_kernel_profile_and_process_forensics_carry_breadcrumb():
     s = Session(config={"result_cache": False})
     s.create_catalog("tpch", "tpch", {"tpch.scale-factor": SF})
     s.execute(Q6)
@@ -253,15 +253,11 @@ def test_kernel_profile_and_bench_forensics_carry_breadcrumb():
     assert bc is not None
     assert bc["kernel"]
     assert bc["mode"] in ("jit", "eager", "device_get", "gate")
-    # ... and mirrors it process-globally, which is what bench.py
-    # persists into the BENCH artifact for crashed configs
+    # ... and mirrors it process-globally, for whoever reads a crashed
+    # process's last dispatch without knowing which session made it
     from trino_tpu.runtime import last_breadcrumb
 
     assert (last_breadcrumb() or {}).get("kernel")
-    import bench
-
-    forensics = bench._crash_forensics()
-    assert forensics.get("last_dispatch", {}).get("kernel")
 
 
 # --- distributed chaos ----------------------------------------------------

@@ -4,8 +4,9 @@ The device path (connectors/tpch_device.py) must produce EXACTLY the
 arrays the numpy path (connectors/tpch.generate) produces — splitmix64 is
 pure integer math, so any divergence is a bug, not noise.
 
-This file proves parity on the CPU backend; ``chip_smoke.py`` holds the
-same generator to the host generator on real HBM at SF10.
+This file proves parity on the CPU backend; on real HBM at SF10 the
+benchmark (``benchmark/run.py``) holds the device generator's answers to
+its own numpy copy of the generator.
 """
 import numpy as np
 import pytest

@@ -363,13 +363,6 @@ SESSION_PROPERTIES: Dict[str, PropertyMetadata] = {
             _bool, True,
         ),
         PropertyMetadata(
-            "bandwidth_ledger",
-            "bracket every supervised dispatch with block_until_ready "
-            "and account bytes-touched / device wall into per-kernel "
-            "effective GB/s (EXPLAIN ANALYZE always collects it)",
-            _bool, False,
-        ),
-        PropertyMetadata(
             "reorder_joins",
             "stats-based join-graph reordering (ReorderJoins / "
             "EliminateCrossJoins analogs); off keeps the FROM order",
@@ -445,11 +438,6 @@ SESSION_PROPERTIES: Dict[str, PropertyMetadata] = {
             int, 1,
         ),
         PropertyMetadata(
-            "scan_cache_enabled",
-            "cache device-resident scans across queries (warm-HBM reuse)",
-            _bool, True,
-        ),
-        PropertyMetadata(
             "result_cache",
             "serve repeated deterministic queries from the fragment "
             "result cache (invalidated by connector data versions)",
@@ -510,13 +498,6 @@ SESSION_PROPERTIES: Dict[str, PropertyMetadata] = {
             "pass per scan column): auto (TPU only) | on (forces "
             "interpret mode off-TPU, for parity tests) | off",
             _megakernels, "auto",
-        ),
-        PropertyMetadata(
-            "double_buffer_depth",
-            "streaming tiles staged (host-decoded + H2D-uploaded) ahead "
-            "of the executing tile; each staged tile holds its scan "
-            "working set in HBM",
-            int, 1,
         ),
         PropertyMetadata(
             "donate_pages",

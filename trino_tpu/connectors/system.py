@@ -146,21 +146,6 @@ SCHEMAS: Dict[str, List] = {
         ("analyzed_at", T.DOUBLE),
         ("duration_s", T.DOUBLE),
     ],
-    # one row per kernel digest from the last ledger-enabled query's HBM
-    # bandwidth accounting (obs/bandwidth.py; session.last_kernel_profile)
-    "kernel_bandwidth": [
-        ("kernel", T.VARCHAR),
-        ("mode", T.VARCHAR),
-        ("task_id", T.VARCHAR),
-        ("executions", T.BIGINT),
-        ("input_bytes", T.BIGINT),
-        ("output_bytes", T.BIGINT),
-        ("intermediate_bytes", T.BIGINT),
-        ("total_bytes", T.BIGINT),
-        ("device_wall_s", T.DOUBLE),
-        ("gbps", T.DOUBLE),
-        ("roofline_pct", T.DOUBLE),
-    ],
     # the in-memory tail of the dispatch flight recorder
     # (obs/flight_recorder.py via the process device supervisor) —
     # seq-paired dispatch/complete/fault records, oldest first
@@ -544,28 +529,6 @@ class _SystemSource:
                 "data_version": [str(e["data_version"]) for e in entries],
                 "analyzed_at": [e["analyzed_at"] for e in entries],
                 "duration_s": [e["duration_s"] for e in entries],
-            }
-        if table == "kernel_bandwidth":
-            prof = getattr(s, "last_kernel_profile", None) or {}
-            entries = prof.get("bandwidth") or []
-            return {
-                "kernel": [e["kernel"] for e in entries],
-                "mode": [e["mode"] for e in entries],
-                "task_id": [e.get("taskId", "") for e in entries],
-                "executions": [e["executions"] for e in entries],
-                "input_bytes": [e["inputBytes"] for e in entries],
-                "output_bytes": [e["outputBytes"] for e in entries],
-                "intermediate_bytes": [
-                    e["intermediateBytes"] for e in entries
-                ],
-                "total_bytes": [e["totalBytes"] for e in entries],
-                "device_wall_s": [e["deviceWallS"] for e in entries],
-                "gbps": [e["gbps"] for e in entries],
-                # NaN off-TPU: the CPU backend has no HBM peak
-                "roofline_pct": [
-                    float("nan") if e["rooflinePct"] is None
-                    else e["rooflinePct"] for e in entries
-                ],
             }
         if table == "flight_recorder":
             import json as _json

@@ -62,10 +62,6 @@ class FragmentExecutor(LocalExecutor):
             for p in pages
             for c in p.columns
         )
-        # the bandwidth ledger accounts remote-exchange input once per
-        # task (the merged arrays also feed per-dispatch inputBytes)
-        if self.bandwidth_ledger is not None:
-            self.bandwidth_ledger.exchange_bytes += self.exchange_bytes
         # {(scan_preorder_index, symbol): [Domain]} from exec/dynamic_filter
         self.dynamic_filters = dynamic_filters or {}
         self.df_rows_pruned = 0

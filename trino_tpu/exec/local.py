@@ -40,8 +40,6 @@ from ..ops import aggregation as agg_ops
 from ..ops import join as join_ops
 from ..ops import sort as sort_ops
 from ..obs import compile_observatory as _compile_obs
-from ..obs.bandwidth import BandwidthLedger
-from ..ops import tree_nbytes
 from ..ops import window as window_ops
 from ..page import Column, Page, pad_to
 from ..plan import nodes as P
@@ -201,15 +199,6 @@ def _contains_host_aggs(plan: P.PlanNode) -> bool:
         lambda n: any(a.kind in HOST_STAGED_KINDS for a in n.aggs),
     )
 
-def _pad_capacity(n: int) -> int:
-    """Static tile capacity: next multiple of 128 (TPU lane width).
-
-    Back-compat alias of :func:`shapes.lane_align`; executor paths
-    quantize through ``self.ladder`` (the bucketed-batch ABI) instead,
-    so arbitrary row counts collapse onto a bounded set of shapes.
-    """
-    return shapes.lane_align(n)
-
 
 class _LazyDeviceLane:
     """Placeholder for a scan column that will be GENERATED on-device
@@ -359,8 +348,8 @@ class LocalExecutor:
         # bare executors (tests) resolve from the spec/file props here.
         self.ladder = shapes.resolve_ladder(self.config)
         # scan-node id -> capacity actually dispatched (ladder rung after
-        # scan_cap_override): kernel profile + bandwidth ledger report
-        # padded bytes from these, never from recomputed lane alignment
+        # scan_cap_override): the kernel profile reports padded rows
+        # from these, never from recomputed lane alignment
         self._scan_caps: Dict[int, int] = {}
         self.scan_bytes = 0
         # EXPLAIN ANALYZE: id(plan node) -> {rows, bytes, wall_s,
@@ -391,14 +380,6 @@ class LocalExecutor:
         # when wired, process default otherwise (bare executors in tests)
         self.supervisor = self.config.get("device_supervisor") \
             or default_supervisor()
-        # HBM bandwidth ledger (obs/bandwidth.py): per-kernel bytes/wall
-        # accounting behind the bandwidth_ledger session property (EXPLAIN
-        # ANALYZE forces it on) — the block_until_ready bracketing
-        # serializes the async dispatch pipeline, so it stays opt-in
-        self.bandwidth_ledger = (
-            BandwidthLedger()
-            if self.config.get("bandwidth_ledger") else None
-        )
         self.device_bytes = 0
         # True while re-executing on the CPU backend after a device fault:
         # dispatches bypass supervision (the watchdog side thread would
@@ -515,63 +496,6 @@ class LocalExecutor:
             return "on"
         return v
 
-    # -- HBM bandwidth ledger ------------------------------------------
-    def _ledger_input_bytes(self, scans) -> int:
-        """Padded host bytes fed to the program: the scan (and merged
-        exchange) arrays scaled to the ladder rung each scan actually
-        dispatched at (recorded by `_device_lanes`), so the ledger's
-        GB/s agrees with the buffers XLA really moved — and with the
-        padding ratios the observatory census reports."""
-        total = 0
-        for nid, arrays in scans.items():
-            rows = max(
-                (int(getattr(v, "shape", (0,))[0] or 0)
-                 for v, _ok in arrays.values() if hasattr(v, "shape")),
-                default=0,
-            )
-            cap = self._scan_caps.get(nid)
-            scale = (int(cap) / rows) if (cap and rows) else 1.0
-            for v, ok in arrays.values():
-                nb = int(getattr(v, "nbytes", 0) or 0)
-                if ok is not None:
-                    nb += int(getattr(ok, "nbytes", 0) or 0)
-                total += int(nb * scale)
-        return total
-
-    def _ledger_bracket(self, out, digest, mode, plan, scans, start):
-        """Close one ledger observation: drain the async dispatch
-        pipeline (supervised, so a wedge/loss during the sync still
-        breadcrumbs and flight-records) and fold bytes over the wall."""
-        led = self.bandwidth_ledger
-        if led is None:
-            return
-        bc = self._dispatch_crumb(digest, "sync")
-        self._dispatch(
-            lambda: jax.block_until_ready(out), bc  # dispatch-guard: ok
-        )
-        wall = time.perf_counter() - start
-        from . import streaming
-
-        try:
-            scan_est = streaming.estimate_plan_scan_bytes(self, plan)
-            inter = int(max(
-                0.0,
-                streaming.estimate_program_bytes(self, plan) - scan_est,
-            ))
-        except Exception:
-            # estimators reject exotic plans (e.g. UNNEST) — the ledger
-            # then reports input+output only rather than nothing
-            inter = 0
-        led.record(
-            digest,
-            mode,
-            input_bytes=self._ledger_input_bytes(scans),
-            output_bytes=tree_nbytes(out),
-            intermediate_bytes=inter,
-            wall_s=wall,
-            task_id=str(self.config.get("task_id") or ""),
-        )
-
     # ------------------------------------------------------------------
     def _execute_inner(self, plan: P.PlanNode) -> Page:
         # out-of-core path: when the estimated scan working set exceeds the
@@ -680,17 +604,12 @@ class LocalExecutor:
                             "eager-%d" % attempt, "eager", scans
                         )
                         self._last_crumb = bc
-                        led_t0 = time.perf_counter()
                         with TRACER.span("launch"):
                             out_lanes, sel, ordered, checks = (
                                 self._dispatch(
                                     lambda: self._run(plan, ctx), bc
                                 )
                             )
-                        self._ledger_bracket(
-                            (out_lanes, sel), "eager-%d" % attempt,
-                            "eager", plan, scans, led_t0,
-                        )
                         dups = ctx.dup_checks
                         colls = ctx.collision_checks
                         wides = ctx.lowering.overflow_flags
@@ -1396,9 +1315,9 @@ class LocalExecutor:
         if nid is None and node is not None:
             nid = id(node)
         if nid is not None:
-            # the rung actually dispatched — kernel profile and the
-            # bandwidth ledger read padded bytes from here, so EXPLAIN
-            # ANALYZE ratios match the observatory census
+            # the rung actually dispatched — the kernel profile reads
+            # padded rows from here, so EXPLAIN ANALYZE ratios match the
+            # observatory census
             self._scan_caps[nid] = cap
         # lanes staged ahead by FragmentExecutor.preupload (prefetch
         # thread): consume them instead of re-uploading.  Donatability
@@ -1664,16 +1583,6 @@ class LocalExecutor:
         REGISTRY.counter(
             "trino_tpu_kernel_d2h_bytes", "Estimated device-to-host result bytes"
         ).inc(d2h)
-        led = self.bandwidth_ledger
-        if led is not None:
-            s = led.summary()
-            self.kernel_profile["bandwidth"] = led.entries()
-            self.kernel_profile["summary"].update(
-                effectiveGbps=s["effectiveGbps"],
-                rooflinePct=s["rooflinePct"],
-                ledgerBytes=s["totalBytes"],
-                deviceWallS=s["deviceWallS"],
-            )
 
     # ------------------------------------------------------------------
     def _run_jitted(self, plan: P.Output, scans, counts):
@@ -1842,12 +1751,10 @@ class LocalExecutor:
                 # execution of the finished executable is supervised.
                 fn = self._compile_fragment(fn, resident_prep, tile_prep)
                 compile_s = time.time() - compile_start
-                led_t0 = time.perf_counter()
                 with TRACER.span("launch"):
                     out = self._dispatch(
                         lambda: fn(resident_prep, tile_prep), bc
                     )
-                self._ledger_bracket(out, digest, "jit", plan, scans, led_t0)
             _compile_obs.record_compile(
                 kernel=digest, family=family, cause=cause,
                 mode="jit", shapes=shapes,
@@ -1873,11 +1780,9 @@ class LocalExecutor:
             with TRACER.span("launch"):
                 bc = self._dispatch_crumb(digest, "jit", prep)
                 self._last_crumb = bc
-                led_t0 = time.perf_counter()
                 out = self._dispatch(
                     lambda: entry["fn"](resident_prep, tile_prep), bc
                 )
-            self._ledger_bracket(out, digest, "jit", plan, scans, led_t0)
             self._record_kernel(digest, compile_s=0.0, cached=True)
         out_lanes, sel, ngroups, dup_vals, colls, wides, sflags = out
         checks = [

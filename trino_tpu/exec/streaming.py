@@ -452,24 +452,21 @@ def execute_streaming(executor, plan: P.Output, frags, memory_limit: int) -> Pag
             # double-buffered tile pipeline: while tile i computes on the
             # device (the execute thread blocks in device_get), tile i+1's
             # host arrays generate/decode AND upload on the prefetch
-            # thread(s) — the steady state is bound by
-            # max(host, H2D, device), not their sum (SURVEY §7 hard part
-            # 6).  `double_buffer_depth` is how many tiles may be staged
-            # ahead of the executing one (each staged tile holds its scan
-            # working set in HBM, so depth multiplies tile residency).
+            # thread — the steady state is bound by max(host, H2D,
+            # device), not their sum (SURVEY §7 hard part 6).  One tile
+            # is staged ahead of the executing one: each staged tile
+            # holds its scan working set in HBM, and a resident tile
+            # stages in about a millisecond.
             from collections import deque
             from concurrent.futures import ThreadPoolExecutor
 
-            depth = max(
-                1, int(executor.config.get("double_buffer_depth", 1) or 1)
-            )
             out: List[Page] = []
-            with ThreadPoolExecutor(max_workers=depth) as prefetch:
+            with ThreadPoolExecutor(max_workers=1) as prefetch:
                 pending = deque(
                     prefetch.submit(make_loaded, i)
-                    for i in tile_starts[:depth]
+                    for i in tile_starts[:1]
                 )
-                nexti = depth
+                nexti = 1
                 while pending:
                     tile = len(out)
                     with TRACER.span("tile_wait", tile=tile):

@@ -1,6 +1,7 @@
-"""Observability subsystem: black-box flight recorder + HBM bandwidth ledger.
+"""Observability subsystem: black-box flight recorder, operator timeline,
+history, journal and doctor.
 
-Two always-on production-profiling surfaces in the spirit of Kanev et al.
+Always-on production-profiling surfaces in the spirit of Kanev et al.
 (*Profiling a Warehouse-Scale Computer*, ISCA 2015) and Dean & Barroso
 (*The Tail at Scale*, CACM 2013):
 
@@ -9,11 +10,6 @@ Two always-on production-profiling surfaces in the spirit of Kanev et al.
   when ``flight_recorder_dir`` is set) so the last N device dispatches
   survive a hard TPU crash and ``scripts/flightrec.py`` can bisect the
   culprit kernel offline;
-- :mod:`.bandwidth` — per-kernel bytes-touched / device-wall accounting
-  yielding effective GB/s and %-of-roofline per compiled program
-  (``bandwidth_ledger`` session property), surfaced through EXPLAIN
-  ANALYZE, ``/v1/query/{id}/profile``, ``system.runtime.kernel_bandwidth``
-  and the ``trino_tpu_kernel_bandwidth_*`` histograms;
 - :mod:`.opstats` — per-operator OperatorStats frames (rows/bytes/wall/
   blocked-time, estimated vs observed rows) rolled up pipeline -> task ->
   stage -> query into the EXPLAIN ANALYZE / ``system.runtime.operator_stats``
@@ -27,12 +23,11 @@ Two always-on production-profiling surfaces in the spirit of Kanev et al.
   correlated event (``event_journal_dir`` upgrades it to the crash-safe
   on-disk segments), backing ``system.runtime.events``;
 - :mod:`.doctor` — the query doctor: deterministic ordered-rule
-  correlation of the journal with the flight recorder, bandwidth
-  ledger, timeline, and history into a ranked causal verdict (EXPLAIN
+  correlation of the journal with the flight recorder, timeline,
+  and history into a ranked causal verdict (EXPLAIN
   ANALYZE "Diagnosis", ``system.runtime.diagnoses``,
   ``scripts/doctor.py``).
 """
-from .bandwidth import BandwidthLedger, roofline_bytes_per_s
 from .doctor import (
     DIAGNOSIS_FIELDS,
     classify_error,
@@ -73,8 +68,6 @@ from .opstats import (
 )
 
 __all__ = [
-    "BandwidthLedger",
-    "roofline_bytes_per_s",
     "DIAGNOSIS_FIELDS",
     "classify_error",
     "diagnose",

@@ -2,9 +2,8 @@
 
 At query finalize (and on demand for crashed queries via the persisted
 journal segments) the doctor correlates the incident journal
-(:mod:`.journal`) with the flight recorder, the HBM bandwidth ledger,
-the per-operator timeline, and the query history into one deterministic
-causal verdict:
+(:mod:`.journal`) with the flight recorder, the per-operator timeline,
+and the query history into one deterministic causal verdict:
 
     ROOT_CAUSE: device_fault — device_loss on node-2/devgen:lineitem
     -> quarantine -> CPU degraded re-run [events 3,4,7]

@@ -139,8 +139,8 @@ class _Segment:
             pass
 
 
-# most recent recorder constructed in this process: bench.py and crash
-# forensics read the tail without knowing which session/worker owns it
+# most recent recorder constructed in this process: crash forensics
+# read the tail without knowing which session/worker owns it
 _LAST_LOCK = threading.Lock()
 _LAST: Optional["FlightRecorder"] = None
 

@@ -1,9 +1,7 @@
 """Kernel building blocks (aggregation, join, sort, window, ...).
 
-Shared byte-accounting helpers live here: the bandwidth ledger
-(``trino_tpu/obs/bandwidth.py``) charges every supervised dispatch with
-the bytes its operator tree touches, and the lane pytrees it must walk
-are the same nested dict/tuple shapes the ops modules produce.
+The shared byte-accounting helper lives here: the lane pytrees it walks
+are the nested dict/tuple shapes the ops modules produce.
 """
 from __future__ import annotations
 

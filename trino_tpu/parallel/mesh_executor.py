@@ -39,10 +39,10 @@ from ..exec.local import (
     LocalExecutor,
     dict_fingerprint,
     merge_pages_to_arrays,
-    _pad_capacity,
     _shape_summary,
     _TraceCtx,
 )
+from ..exec.shapes import lane_align
 from ..obs import compile_observatory as _compile_obs
 from ..utils.tracing import TRACER
 from ..expr import ir
@@ -103,7 +103,7 @@ def _shuffle_chunk(cap: int, ndev: int, factor: int, quantize=None) -> int:
     cap/ndev rows per bucket with 2x skew slack, grown by the retry-ladder
     factor on overflow.  `quantize` is the executor ladder's rung
     function (plain lane alignment when absent)."""
-    q = quantize or _pad_capacity
+    q = quantize or lane_align
     return q(max(128, (2 * cap * factor) // ndev))
 
 
@@ -275,18 +275,13 @@ class MeshExecutor(LocalExecutor):
     def _record_kernel(self, digest, compile_s, cached, mode="jit",
                        cause=None):
         # every mesh-path kernel record carries the axis-size tag, so
-        # flight records, the bandwidth ledger, and bench profiles can
-        # tell 8-way from single-chip executions of the same plan
+        # flight records and kernel profiles can tell 8-way from
+        # single-chip executions of the same plan
         tag = "mesh:%d" % self.mesh.devices.size
         if not str(digest).startswith("mesh:"):
             digest = "%s/%s" % (tag, digest)
         return super()._record_kernel(digest, compile_s, cached,
                                       mode=mode, cause=cause)
-
-    def _ledger_input_bytes(self, scans) -> int:
-        # what the program was called with: {ordinal: {sym: (values, ok),
-        # "__count__": rows}}, every leaf [ndev, ...] on the mesh axis
-        return tree_nbytes(scans)
 
     def _dispatched_cap(self, nid, count: int) -> int:
         # every shard is padded to the rung of the largest one
@@ -459,14 +454,10 @@ class MeshExecutor(LocalExecutor):
         with TRACER.span("materialize_host"):
             totals = {nid: int(c.sum()) for nid, c in counts.items()}
             self._finalize_kernel_profile(scans, totals, host_lanes, sel_np)
-            # what the program read, as it was padded and sharded
-            self.scan_bytes = self._ledger_input_bytes(prep)
-            if self.bandwidth_ledger is not None:
-                summary = self.kernel_profile["summary"]
-                summary.update(
-                    meshDevices=ndev,
-                    perShardGbps=round(summary["effectiveGbps"] / ndev, 6),
-                )
+            # what the program read, as it was padded and sharded:
+            # {ordinal: {sym: (values, ok), "__count__": rows}}, every
+            # leaf [ndev, ...] on the mesh axis
+            self.scan_bytes = tree_nbytes(prep)
             page = self._materialize_host(plan, host_lanes, sel_np)
         if self.config.get("collect_node_stats"):
             self._mesh_node_stats(
@@ -535,10 +526,8 @@ class MeshExecutor(LocalExecutor):
             cell = entry["cell"]
             self.dicts.update(cell["dicts"])
             fn = entry["fn"]
-            led_t0 = time.perf_counter()
             with TRACER.span("launch"):
                 out = self._dispatch(lambda: fn(prep), bc)
-            self._ledger_bracket(out, digest, "mesh", plan, prep, led_t0)
             self._record_kernel(
                 digest, compile_s=0.0, cached=True, mode="mesh"
             )
@@ -616,10 +605,8 @@ class MeshExecutor(LocalExecutor):
                     prep,
                 )
                 compile_s = time.time() - compile_start
-                led_t0 = time.perf_counter()
                 with TRACER.span("launch"):
                     out = self._dispatch(lambda: fn(prep), bc)
-                self._ledger_bracket(out, digest, "mesh", plan, prep, led_t0)
             _compile_obs.record_compile(
                 kernel=digest, family=family, cause=cause,
                 mode="mesh", shapes=shapes, shape_sig=shape_sig,
@@ -1686,8 +1673,6 @@ class CrossHostFragmentExecutor(MeshExecutor):
             for p in pages
             for c in p.columns
         )
-        if self.bandwidth_ledger is not None:
-            self.bandwidth_ledger.exchange_bytes += self.exchange_bytes
 
     def _scan_splits(self, node: P.TableScan, idx: int, ndev: int):
         # ONLY the splits the coordinator assigned to this task, keyed by

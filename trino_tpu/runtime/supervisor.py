@@ -78,8 +78,7 @@ class Breadcrumb:
     """Crash forensics for ONE dispatch, recorded before it happens.
 
     When the dispatch never returns (crash, wedge, process death) this is
-    the only attribution that exists — which is why bench.py persists the
-    last one into the BENCH artifact for every crashed config."""
+    the only attribution that exists."""
 
     def __init__(
         self,
@@ -167,8 +166,8 @@ class _DeviceHealth:
 
 
 # process-wide forensics: the LAST breadcrumb recorded by ANY supervisor
-# instance, plus fallback counters — bench.py reads these after a config
-# crashed without having to know which session/worker dispatched
+# instance, plus fallback counters — readable after a crash without
+# knowing which session/worker dispatched
 _GLOBAL_LOCK = threading.Lock()
 _LAST_BREADCRUMB: Optional[Breadcrumb] = None
 _FALLBACKS = {"attempted": 0, "completed": 0}

@@ -1118,7 +1118,6 @@ class Coordinator:
         taken from the in-process executor otherwise."""
         tasks = []
         kernels = []
-        bandwidth = []
         summaries = []
         for t in getattr(q, "task_stats", []) or []:
             prof = t.get("kernelProfile")
@@ -1130,13 +1129,11 @@ class Coordinator:
                 "profile": prof,
             })
             kernels.extend(prof.get("kernels") or [])
-            bandwidth.extend(prof.get("bandwidth") or [])
             if prof.get("summary"):
                 summaries.append(prof["summary"])
         local = getattr(q, "kernel_profile", None)
         if not tasks and local:
             kernels = list(local.get("kernels") or [])
-            bandwidth = list(local.get("bandwidth") or [])
             if local.get("summary"):
                 summaries.append(local["summary"])
         summary = {}
@@ -1162,22 +1159,10 @@ class Coordinator:
                 "h2dBytes": sum(s.get("h2dBytes", 0) for s in summaries),
                 "d2hBytes": sum(s.get("d2hBytes", 0) for s in summaries),
             }
-            # HBM bandwidth ledger rollup: cluster-wide effective GB/s
-            # over the summed per-task byte/wall accounting
-            led_bytes = sum(s.get("ledgerBytes", 0) for s in summaries)
-            led_wall = sum(s.get("deviceWallS", 0.0) for s in summaries)
-            if led_bytes or led_wall:
-                summary["ledgerBytes"] = led_bytes
-                summary["deviceWallS"] = led_wall
-                summary["effectiveGbps"] = (
-                    led_bytes / led_wall / 1e9 if led_wall > 0 else 0.0
-                )
-        bandwidth.sort(key=lambda e: e.get("totalBytes", 0), reverse=True)
         return {
             "queryId": q.query_id,
             "state": q.state,
             "kernels": kernels,
-            "bandwidth": bandwidth,
             "summary": summary,
             "tasks": tasks,
         }

@@ -1,10 +1,9 @@
 """Standalone coordinator entrypoint:
 ``python -m trino_tpu.server.coordinator_main``.
 
-The coordinator-crash chaos harness (tests/test_recovery.py,
-``bench.py --chaos-coordinator``) needs a coordinator the OS can actually
-kill — an in-process CoordinatorServer shares its fate with the test
-runner, so kill -9 semantics (query state machine vaporized mid-flight,
+The coordinator-crash chaos harness (tests/test_recovery.py) needs a
+coordinator the OS can actually kill — an in-process CoordinatorServer
+shares its fate with the test runner, so kill -9 semantics (query state machine vaporized mid-flight,
 clients' sockets refuse instantly, only the mmap'd WAL survives) are only
 reachable with a real child process.  This entrypoint boots one
 distributed CoordinatorServer, prints a single JSON line

@@ -138,10 +138,9 @@ class Session:
                 census_max_families=_census_fams,
             )
         # ranked root-cause verdict of the most recent doctored query
-        # (bench.py attaches it to slow configs)
         self.last_diagnosis: Optional[dict] = None
         # stats of the most recent persistent-compile-cache prewarm
-        # (cold-start path; bench --serve surfaces them)
+        # (cold-start path)
         self.last_prewarm: Optional[dict] = None
         # operator timeline of the last instrumented execution (EXPLAIN
         # ANALYZE / operator_stats=true), backing
@@ -245,10 +244,7 @@ class Session:
             "memory_blocked_timeout_s": self.properties.get(
                 "memory_blocked_timeout_s"
             ),
-            "scan_cache": (
-                self._scan_cache
-                if self.properties.get("scan_cache_enabled") else None
-            ),
+            "scan_cache": self._scan_cache,
             "topn_initial_factor": self.properties.get(
                 "topn_initial_factor"
             ),
@@ -265,9 +261,6 @@ class Session:
             self.properties.get("device_generation")
         )
         exec_config["megakernels"] = self.properties.get("megakernels")
-        exec_config["double_buffer_depth"] = self.properties.get(
-            "double_buffer_depth"
-        )
         exec_config["donate_pages"] = self.properties.get("donate_pages")
         exec_config["broadcast_join_threshold_rows"] = self.properties.get(
             "broadcast_join_threshold_rows"
@@ -301,7 +294,7 @@ class Session:
                 # the OS cache and seed the observatory's family registry
                 # from the index, so boot-time compiles classify as
                 # persistent_load / first_compile — never shape_miss.
-                # Idempotent per directory; records stats for bench.
+                # Idempotent per directory.
                 warm = cc.prewarm(cache_dir)
                 if warm is not None:
                     self.last_prewarm = warm
@@ -309,9 +302,6 @@ class Session:
         # (a throwaway dict keeps the executor's duck-typed surface)
         exec_config["jit_cache"] = (
             cc if self.properties.get("compile_cache") else {}
-        )
-        exec_config["bandwidth_ledger"] = bool(
-            self.properties.get("bandwidth_ledger")
         )
         exec_config["capacity_hints"] = self._capacity_hints
         exec_config["fragment_cache"] = self._fragment_cache
@@ -993,9 +983,6 @@ class Session:
                 "collect_node_stats": True,
                 "spill_enabled": False,
                 "query_id": query_id,
-                # EXPLAIN ANALYZE always collects the HBM bandwidth
-                # ledger: its whole point is per-operator accounting
-                "bandwidth_ledger": True,
             },
         )
         t0 = time.perf_counter()
@@ -1067,27 +1054,6 @@ class Session:
                         text += f"\n  {cause}: {n}"
             else:
                 text += "\n  (no compiles this query)"
-        bandwidth = prof.get("bandwidth") or []
-        if bandwidth:
-            def _pct(v):
-                # no HBM peak off-TPU: never print a share of one
-                return "n/a" if v is None else f"{v:.3f}%"
-
-            text += (
-                "\n\nHBM bandwidth ledger "
-                f"(roofline {summary.get('effectiveGbps', 0.0):.2f} GB/s "
-                f"effective, {_pct(summary.get('rooflinePct'))} of "
-                "peak):"
-            )
-            for e in bandwidth:
-                text += (
-                    f"\n  kernel {e['kernel']} [{e['mode']}]: "
-                    f"{e['gbps']:.2f} GB/s "
-                    f"({_pct(e['rooflinePct'])} roofline), "
-                    f"in {e['inputBytes']}B, out {e['outputBytes']}B, "
-                    f"inter {e['intermediateBytes']}B over "
-                    f"{e['deviceWallS'] * 1000:.2f}ms device wall"
-                )
         # the doctor's causal verdict over the same evidence (EXPLAIN
         # ANALYZE is the interactive "why was this slow" surface)
         if self.properties.get("query_doctor"):

@@ -60,10 +60,6 @@ METRIC_NAME_RE = re.compile(
     % "|".join(METRIC_SUBSYSTEMS)
 )
 
-# Byte-volume buckets (64 KiB .. 16 GiB, x4 steps) for ``_bytes``
-# histograms such as the bandwidth ledger's per-dispatch byte volume.
-BYTES_BUCKETS = tuple(float(1 << s) for s in range(16, 35, 2))
-
 # Latency buckets in seconds; tuned for sub-millisecond kernels up to
 # multi-second distributed queries.
 DEFAULT_BUCKETS = (
