@@ -85,3 +85,35 @@ def assert_rows_match(actual, expected, tol=1e-2, ordered=True):
                 )
             else:
                 assert va == vb, f"row {i} col {j}: {va!r} != {vb!r}"
+
+
+def assert_q1_fuses_past_the_int64_gate(monkeypatch, make_session, sf=0.01):
+    """TPC-H Q1 with ``megakernel.SUM_GATE`` patched so low that the
+    table-wide int64 proof fails for ``sum_charge``: its wide
+    accumulators must fuse all the same (chunk recombination), in a
+    fresh session so that the fragment is traced, and answer as the
+    unfused path and sqlite do.  ``make_session(sf, **props)`` builds
+    the session under test; returns its outer kernel profile."""
+    from tpch_sql import QUERIES, oracle_dialect
+    from trino_tpu.ops import megakernel
+    from trino_tpu.session import tpch_session
+
+    q1 = QUERIES[1][0]
+    monkeypatch.setattr(megakernel, "SUM_GATE", 2 ** 40)
+    # detached from the process-wide jit cache, both sessions: this one
+    # has to trace, and neither may leave a program of TPC-H's own Q1
+    # text for a test that expects to compile it (test_tpu_compile)
+    props = {"result_cache": False, "compile_cache": False}
+    on = make_session(sf, megakernels="on", **props)
+    rows = on.execute(q1).to_pylist()
+    prof = on.last_kernel_profile
+    assert prof.get("fusedAggregates", 0) >= 1, prof
+    assert prof.get("fusedSumsPastInt64", 0) >= 1, prof
+    assert not prof.get("fusionRejects"), prof
+    off = tpch_session(sf, megakernels="off", **props)
+    assert rows == off.execute(q1).to_pylist()
+    conn = sqlite3.connect(":memory:")
+    load_tpch(conn, sf, ["lineitem"])
+    expected = conn.execute(oracle_dialect(q1)).fetchall()
+    assert_rows_match(rows, expected, tol=2e-2, ordered=True)
+    return prof

@@ -27,10 +27,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from oracle import assert_rows_match, load_tpch
+from oracle import (
+    assert_q1_fuses_past_the_int64_gate, assert_rows_match, load_tpch,
+)
 from tpch_sql import QUERIES, oracle_dialect
 from trino_tpu.connectors import tpch_device
+from trino_tpu.ops import megakernel
 from trino_tpu.ops import pallas_kernels as pk
+from trino_tpu.ops import wide_decimal as wd
 from trino_tpu.runtime.supervisor import QUARANTINED
 from trino_tpu.session import Session, tpch_session
 
@@ -117,6 +121,30 @@ def test_non_fusable_rejects_into_unfused_path(sql, reason_frag):
     assert prof.get("fusedAggregates") is None
     assert prof["fusionRejects"] >= 1
     assert reason_frag in prof["lastFusionReject"]
+    assert a.to_pylist() == off.execute(sql).to_pylist()
+
+
+def test_q1_fuses_past_the_int64_gate(monkeypatch):
+    """sum_charge's table-wide bound trips the (patched) int64 gate; a
+    wide accumulator recombines in chunks and needs no such proof."""
+    prof = assert_q1_fuses_past_the_int64_gate(monkeypatch, tpch_session)
+    assert _megakernels(prof)[0]["digest"].endswith("/t11/g12")
+
+
+def test_narrow_sum_keeps_the_int64_gate(monkeypatch):
+    """An integer sum ships a narrow $val that merges downstream by
+    int64 addition: for it the table-wide proof and its reject stay."""
+    monkeypatch.setattr(megakernel, "SUM_GATE", 2 ** 40)
+    sql = "select sum(l_orderkey * l_partkey), count(*) from lineitem"
+    on = tpch_session(0.01, megakernels="on", result_cache=False,
+                      compile_cache=False)
+    off = tpch_session(0.01, megakernels="off", result_cache=False)
+    a = on.execute(sql)
+    prof = on.last_kernel_profile
+    assert prof.get("fusedAggregates") is None
+    assert prof.get("fusedSumsPastInt64") is None
+    assert prof["fusionRejects"] >= 1
+    assert prof["lastFusionReject"] == "table-wide sum could exceed int64"
     assert a.to_pylist() == off.execute(sql).to_pylist()
 
 
@@ -208,6 +236,77 @@ def test_limb_split_product_recombination():
         cols, jnp.ones(n, dtype=bool), emit, 4, 1, interpret=True
     )
     assert _total(sums, (0, 16, 16, 32)) == int((a * b).sum())
+
+
+def _chunks_of(total: int):
+    return [total & 0xFFFFFFFF, (total >> 32) & 0xFFFFFFFF,
+            (total >> 64) & 0xFFFFFFFF, total >> 96]
+
+
+@pytest.mark.parametrize("sign", ["positive", "negative", "mixed"])
+@pytest.mark.parametrize(
+    "shifts", [(0,), (0, 16), (0, 16, 16, 32), (0, 16, 48)],
+    ids=lambda s: "sh" + "_".join(map(str, s)),
+)
+def test_term_sums_recombine_into_chunks_exactly(shifts, sign):
+    """The recombination alone: term sums of up to 2^46 in magnitude go
+    straight into the wide accumulator's four chunk lanes and equal the
+    python big-integer total's canonical chunks, for totals below 2^63
+    (shifts up to 16) and far past it (2^46 << 32 and << 48)."""
+    rng = np.random.default_rng(len(shifts) * 7 + len(sign))
+    groups = 96
+    mags = rng.integers(0, 2 ** 46, size=(len(shifts), groups),
+                        dtype=np.int64)
+    # the boundaries: the largest magnitude, zero, and both sides of
+    # the 32-bit cut that the recombination makes in every term sum
+    mags[:, 0] = 2 ** 46
+    mags[:, 1] = 0
+    mags[:, 2] = 2 ** 32 - 1
+    mags[:, 3] = 2 ** 32
+    mags[:, 4] = 1
+    if sign == "positive":
+        sums = mags
+    elif sign == "negative":
+        sums = -mags
+    else:
+        sums = mags * rng.choice(np.array([-1, 1]), size=mags.shape)
+    got = np.asarray(wd.shifted_sum_chunks(
+        [jnp.asarray(row) for row in sums], shifts))
+    totals = [
+        sum(int(sums[t, g]) << sh for t, sh in enumerate(shifts))
+        for g in range(groups)
+    ]
+    # both sides of int64: 2^46 << 16 stays inside, << 32 and << 48 not
+    assert (max(abs(t) for t in totals) > 2 ** 63) == (max(shifts) >= 32)
+    for g, total in enumerate(totals):
+        assert [int(c) for c in got[:, g]] == _chunks_of(total), (g, total)
+    # and the chunks are what the wide lane reads back as 128 bits
+    lo, hi = wd.limbs(wd.chunks_to_wide(list(jnp.asarray(got))))
+    for g, total in enumerate(totals):
+        assert (int(hi[g]) << 64) | (int(lo[g]) & (2 ** 64 - 1)) == total
+
+
+@pytest.mark.parametrize(
+    "rows, shifts, reason",
+    [
+        # Q1 SF10's sum_charge: four terms, proven
+        (59_998_443, (0, 16, 16, 32), None),
+        # a hundred Q1s' worth of terms at SF1000 still prove
+        (6_000_000_000, (0, 16, 16, 32) * 100, None),
+        # a term whose own int64 sum could wrap
+        (2 ** 44, (0,), "one term"),
+        # a shift that would index past the fourth chunk
+        (1000, (0, 96), "leaves the 128-bit"),
+        # more 31-bit-shifted high parts than one int64 lane holds
+        (2 ** 42, (31,) * 8, "chunk lanes"),
+    ],
+)
+def test_chunk_lane_proof(rows, shifts, reason):
+    if reason is None:
+        megakernel._prove_chunk_lanes(rows, shifts)
+        return
+    with pytest.raises(megakernel.Reject, match=reason):
+        megakernel._prove_chunk_lanes(rows, shifts)
 
 
 def test_fused_agg_sums_grouped_with_selection():

@@ -16,7 +16,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from oracle import assert_rows_match, load_tpch
+from oracle import (
+    assert_q1_fuses_past_the_int64_gate, assert_rows_match, load_tpch,
+)
 from tpch_sql import QUERIES, oracle_dialect
 from trino_tpu.obs import journal
 from trino_tpu.ops import sketches
@@ -93,6 +95,19 @@ def test_q1_mesh_fused_parity_and_oracle(oracle_conn):
     assert a.to_pylist() == b.to_pylist()
     expected = oracle_conn.execute(oracle_dialect(Q1)).fetchall()
     assert_rows_match(a.to_pylist(), expected, tol=2e-2, ordered=True)
+
+
+def test_q1_mesh_fuses_past_the_int64_gate(monkeypatch):
+    """Four shards: what crosses the mesh is each term's sum, so the
+    shard bodies fuse although sum_charge's TABLE-wide bound is past the
+    (patched) int64 gate; the chunks are cut after the merge."""
+    prof = assert_q1_fuses_past_the_int64_gate(
+        monkeypatch,
+        lambda sf, **props: tpch_session(
+            sf, distributed=True, num_devices=4, **props),
+    )
+    mk = _megakernels(prof)
+    assert mk and mk[0]["digest"].startswith("mesh:4/megakernel:")
 
 
 def test_q3_mesh_parity_and_oracle(oracle_conn):

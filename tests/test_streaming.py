@@ -7,6 +7,7 @@ fragment DAG (see exec/streaming.py docstring).
 """
 import pytest
 
+from oracle import assert_q1_fuses_past_the_int64_gate
 from trino_tpu.exec import streaming
 from trino_tpu.session import tpch_session
 
@@ -67,6 +68,18 @@ def test_q6_streams_exact(free):
 def test_q1_streams_exact(free):
     # grouped aggregation incl. wide decimal sums and avg across tiles
     assert _streamed(Q1) == free.execute(Q1).to_pylist()
+
+
+def test_streamed_q1_fuses_past_the_int64_gate(monkeypatch):
+    """Each tile's PARTIAL aggregate fuses although the table-wide bound
+    of sum_charge is past the (patched) int64 gate, and the tile
+    executors' counters reach the outer profile."""
+    prof = assert_q1_fuses_past_the_int64_gate(
+        monkeypatch,
+        lambda sf, **props: tpch_session(
+            sf, query_max_memory_bytes=1_000_000, **props),
+    )
+    assert prof["streamedFragments"] >= 2, prof
 
 
 def test_q3_streams_exact(free):

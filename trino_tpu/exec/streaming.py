@@ -222,6 +222,7 @@ def _resident_tile_cache(executor, frag, node, tile_splits, tile_rows: int):
 _TILE_COUNTERS = (
     "preuploads", "preupload_bytes", "donated_dispatches",
     "donated_bytes", "fusedAggregates", "fusedTerms", "fusionRejects",
+    "fusedSumsPastInt64",
     "devgenWallS", "devgenCompileS", "lineCountOrdersHashed",
     "residentTileHits", "residentTileMisses",
 )

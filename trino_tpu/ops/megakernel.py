@@ -26,9 +26,15 @@ The fusion is only attempted when it is PROVEN exact at plan time:
     against a short (<= 15-bit) factor -- the exact decomposition the
     flight-recorder bench rounds validated for Q1's extendedprice *
     (1 - discount) * (1 + tax);
-  - the whole-table int64 sum of each input is bounded below 2^62
-    (stats row count x value bound), so cross-chunk int64
-    accumulation and the plane/limb recombination shifts are exact.
+  - every term value is bounded by TERM_MAX, so a term's int64 sum over
+    the whole table (stats row count x TERM_MAX) cannot wrap; a NARROW
+    accumulator ($val / $sum: merged downstream by int64 addition)
+    additionally needs the whole-table sum of its input below 2^62
+    (row count x value bound), because its terms recombine by int64
+    shifts; a WIDE accumulator (any decimal sum/avg) recombines its
+    term sums straight into the four 32-bit chunk lanes
+    (wide_decimal.shifted_sum_chunks) and needs only the chunk-lane
+    bound of _prove_chunk_lanes.
 
 Anything unproven raises Reject and the executor silently falls back
 to the unfused path -- fusion is an optimization, never a semantics
@@ -58,8 +64,11 @@ I32_MAX = 2 ** 31 - 1
 # one [CHUNK_ROWS, 128] column of raw values this small sums in int32
 # without wrapping (CHUNK_ROWS * TERM_MAX < 2^31)
 TERM_MAX = I32_MAX // pk.CHUNK_ROWS
-# whole-table int64 sum headroom: rows * bound must stay below this
+# whole-table int64 sum headroom of a NARROW accumulator: rows * bound
+# must stay below this
 SUM_GATE = 2 ** 62
+# headroom of one chunk lane of a WIDE accumulator before the carry pass
+CHUNK_LANE_GATE = 2 ** 62
 # short factor cap for the limb split: 0xFFFF * LIMB_B_MAX < 2^31
 LIMB_B_MAX = 32767
 
@@ -417,6 +426,26 @@ def _key_domains(ex, node: P.Aggregate, mapping, types, env):
     return doms, (cap if node.keys else 1)
 
 
+def _prove_chunk_lanes(rows_bound: int, shifts: Sequence[int]) -> None:
+    """Plan-time proof for wd.shifted_sum_chunks over one wide
+    aggregate's terms: every term sum is at most TERM_MAX * rows_bound
+    in magnitude (the mesh merges these per-term sums across shards, so
+    the bound is table-wide there too); cut at 32 bits and shifted by
+    sh % 32 it adds under 2^32 to one chunk lane and under
+    2^31 + (high part << sh % 32) to the next."""
+    term = TERM_MAX * rows_bound
+    if term >= 2 ** 63:
+        raise Reject("one term's table-wide sum could exceed int64")
+    lane = total = 0
+    for sh in shifts:
+        if sh >= 96:
+            raise Reject(f"term shift {sh} leaves the 128-bit accumulator")
+        lane += (3 << 31) + (((term >> 32) + 1) << (sh % 32))
+        total += term << sh
+    if lane >= CHUNK_LANE_GATE or total >= 2 ** 126:
+        raise Reject("wide sum's chunk lanes could exceed int64")
+
+
 # ----------------------------------------------------------------------
 # entry point
 
@@ -458,9 +487,11 @@ def _run(ctx, node: P.Aggregate):
     # structural expression equality (sum+avg over one column share)
     terms: List[Tuple[Callable, int]] = [((lambda t: 1), 0)]
     rows_bound = max(int(stats.row_count), 1) + 256  # pad-capacity slack
-    input_terms: Dict[ir.Expr, List[Tuple[int, int]]] = {}
+    input_terms: Dict[ir.Expr, Tuple[List[Tuple[int, int]], int]] = {}
     plans: List[Optional[List[Tuple[int, int]]]] = []
-    for a in node.aggs:
+    specs = [a.to_spec() for a in node.aggs]
+    past_int64 = 0
+    for a, s in zip(node.aggs, specs):
         if a.kind == "count_star":
             plans.append(None)
             continue
@@ -475,16 +506,21 @@ def _run(ctx, node: P.Aggregate):
                     raise Reject(f"count over unproven column {c}")
             plans.append(None)
             continue
-        slots = input_terms.get(e)
-        if slots is None:
+        if e not in input_terms:
             tlist, hi = comp.decompose(e)
-            if rows_bound * hi >= SUM_GATE:
-                raise Reject("table-wide sum could exceed int64")
             slots = []
             for fn, sh in tlist:
                 slots.append((len(terms), sh))
                 terms.append((fn, sh))
-            input_terms[e] = slots
+            input_terms[e] = (slots, hi)
+        slots, hi = input_terms[e]
+        # the ACCUMULATOR decides the proof, not the input: one
+        # expression may feed a wide and a narrow aggregate
+        if s._wide_sum:
+            _prove_chunk_lanes(rows_bound, [sh for _i, sh in slots])
+            past_int64 += rows_bound * hi >= SUM_GATE
+        elif rows_bound * hi >= SUM_GATE:
+            raise Reject("table-wide sum could exceed int64")
         plans.append(slots)
 
     # the kernel reads each referenced column plus the key columns once
@@ -528,32 +564,34 @@ def _run(ctx, node: P.Aggregate):
     # mesh shard bodies: each device fused ITS split shard; the trace
     # context merges the int64 (term, group) partials across the mesh
     # before the shared finalize tail (identity on a single device).
-    # The SUM_GATE proof above bounds the TABLE-wide total, so the
-    # cross-shard sum of per-shard partials cannot wrap int64.
+    # What crosses the mesh is each TERM's sum, not a recombined total:
+    # term values are <= TERM_MAX and rows_bound is the TABLE's row
+    # count, so the cross-shard sum stays under TERM_MAX * rows_bound.
     sums = ctx._merge_fused_sums(sums)
     cnt = sums[0]
 
-    specs = [a.to_spec() for a in node.aggs]
     accs: Dict[str, jnp.ndarray] = {}
     for s, slots in zip(specs, plans):
         o = s.output
         if slots is None:  # count / count_star
             accs[f"{o}$count"] = cnt
             continue
-        val = jnp.zeros_like(cnt)
-        for i, sh in slots:
-            val = val + (sums[i] << jnp.int64(sh))
         if s._wide_sum:
-            # narrow fast path of the wide accumulator schema: the sum
-            # is proven to fit int64, shipped as 32-bit chunk lanes
-            cs = wd.normalize_chunks([
-                val & 0xFFFFFFFF, val >> jnp.int64(32),
-                jnp.zeros_like(val), jnp.zeros_like(val),
-            ])
+            # term sums -> the 128-bit accumulator's chunk lanes, never
+            # through an int64 total (_prove_chunk_lanes held at plan
+            # time); the canonical chunks the unfused path emits
+            cs = wd.shifted_sum_chunks(
+                [sums[i] for i, _sh in slots], [sh for _i, sh in slots],
+            )
             for i, c in enumerate(cs):
                 accs[f"{o}$c{i}"] = c
             accs[f"{o}$valid" if s.kind == "sum" else f"{o}$count"] = cnt
-        elif s.kind == "sum":
+            continue
+        # narrow accumulator: SUM_GATE proved the total fits int64
+        val = jnp.zeros_like(cnt)
+        for i, sh in slots:
+            val = val + (sums[i] << jnp.int64(sh))
+        if s.kind == "sum":
             accs[f"{o}$val"] = val
             accs[f"{o}$valid"] = cnt
         else:  # narrow avg
@@ -590,6 +628,11 @@ def _run(ctx, node: P.Aggregate):
     prof = ex.kernel_profile
     prof["fusedAggregates"] = prof.get("fusedAggregates", 0) + 1
     prof["fusedTerms"] = prof.get("fusedTerms", 0) + n_terms
+    if past_int64:
+        # aggregates the table-wide int64 gate would have refused
+        prof["fusedSumsPastInt64"] = (
+            prof.get("fusedSumsPastInt64", 0) + past_int64
+        )
     ex._record_kernel(
         "megakernel:%s/t%d/g%d" % (scan.table, n_terms, cap),
         0.0, True, mode="megakernel",

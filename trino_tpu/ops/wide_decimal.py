@@ -315,6 +315,25 @@ def normalize_chunks(chunks):
     return out
 
 
+def shifted_sum_chunks(sums, shifts):
+    """Canonical chunks of ``sum(s << sh)`` over int64 lanes ``sums``
+    and python-int ``shifts`` (each < 96), without ever forming the
+    total in an int64.  Each s is cut into its unsigned low and signed
+    high 32 bits BEFORE its shift, so one term adds under 2^32 to chunk
+    sh//32 and under 2^31 + (|s| >> 32 << sh%32) to the next: the
+    caller proves that lane bound; the total only has to fit 128 bits."""
+    lanes = [jnp.zeros_like(sums[0]) for _ in range(4)]
+    for s, sh in zip(sums, shifts):
+        q, r = divmod(sh, 32)
+        low = (s & _M32) << jnp.int64(r)  # < 2^63: no wrap
+        lanes[q] = lanes[q] + (low & _M32)
+        lanes[q + 1] = (
+            lanes[q + 1] + (low >> jnp.int64(32))
+            + ((s >> jnp.int64(32)) << jnp.int64(r))
+        )
+    return normalize_chunks(lanes)
+
+
 def chunks_to_wide(chunks) -> jnp.ndarray:
     """Canonical (normalized) chunks -> wide (…, 2) lane."""
     c0, c1, c2, c3 = chunks

@@ -923,10 +923,12 @@ class _MeshTraceCtx(_TraceCtx):
         """Megakernel shard bodies: merge the per-shard fused
         (term, group) int64 partials across the mesh before the shared
         finalize tail.  all_gather + local reduce rather than psum keeps
-        the exchange in the canonical all-gather/dynamic-slice HLO form;
-        exactness rides the megakernel's own SUM_GATE proof — the
-        TABLE-wide total clears the 2^62 gate, so the cross-shard sum of
-        per-shard partials cannot wrap int64."""
+        the exchange in the canonical all-gather/dynamic-slice HLO form.
+        Exact because what is merged is each TERM's sum, before any
+        recombination: the megakernel's plan-time proofs bound a term's
+        sum over the TABLE's row count (TERM_MAX x rows for a wide
+        accumulator, the 2^62 gate for a narrow one) inside int64, so
+        the cross-shard sum of per-shard partials cannot wrap."""
         return jax.tree_util.tree_map(
             lambda s: jnp.sum(jax.lax.all_gather(s, AXIS), axis=0), sums
         )
