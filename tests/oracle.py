@@ -8,7 +8,10 @@ decimal tolerance.
 """
 from __future__ import annotations
 
+import importlib.util
+import os
 import sqlite3
+import sys
 from typing import Iterable, Sequence
 
 import numpy as np
@@ -117,3 +120,22 @@ def assert_q1_fuses_past_the_int64_gate(monkeypatch, make_session, sf=0.01):
     expected = conn.execute(oracle_dialect(q1)).fetchall()
     assert_rows_match(rows, expected, tol=2e-2, ordered=True)
     return prof
+
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark")
+
+
+def bench_module(*parts):
+    """A file of benchmark/ under a name of its own (tests/ and benchmark/
+    both have short module names)."""
+    qdir = os.path.join(BENCH, "queries")
+    if qdir not in sys.path:
+        sys.path.append(qdir)   # the queries import their `_rows`
+    name = "bench_" + "_".join(parts)
+    if name not in sys.modules:
+        spec = importlib.util.spec_from_file_location(
+            name, os.path.join(BENCH, *parts) + ".py")
+        sys.modules[name] = mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+    return sys.modules[name]

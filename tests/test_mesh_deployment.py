@@ -6,10 +6,7 @@ The plain reference is the benchmark's (`benchmark/queries/*.py` over
 `benchmark/datagen.py`: numpy, exact scaled integers, nothing of the
 program); four of conftest's eight virtual CPU devices stand in for the
 four chips of one host."""
-import importlib.util
 import json
-import os
-import sys
 import threading
 
 import jax
@@ -22,32 +19,17 @@ from trino_tpu.obs import compile_observatory
 from trino_tpu.parallel import mesh_executor as MX
 from trino_tpu.session import tpch_session
 
+from oracle import bench_module
+
 SF = 0.01
 NDEV = 4
 SEEDS = (11, 2800000007, 2**31 + 5)
-BENCH = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "benchmark")
 PHASES = ("load_scans", "device_lanes", "launch", "device_get",
           "materialize_host")
 
 
-def _bench_module(*parts):
-    """A file of benchmark/ under a name of its own (tests/ and benchmark/
-    both have short module names)."""
-    qdir = os.path.join(BENCH, "queries")
-    if qdir not in sys.path:
-        sys.path.append(qdir)   # the queries import their `_rows`
-    name = "bench_" + "_".join(parts)
-    if name not in sys.modules:
-        spec = importlib.util.spec_from_file_location(
-            name, os.path.join(BENCH, *parts) + ".py")
-        sys.modules[name] = mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-    return sys.modules[name]
-
-
 def _text(query, seed):
-    q = _bench_module("queries", query)
+    q = bench_module("queries", query)
     params = q.draw(np.random.default_rng(seed), q.RANGES)
     return q, params, q.sql(params)
 
@@ -77,7 +59,7 @@ def test_mesh_answer_equals_the_reference_and_one_chip(query, seed, mesh,
                                                        one_chip):
     q, params, sql = _text(query, seed)
     rows = mesh.execute(sql).to_pylist()
-    refs, ref_rows = q.reference(_bench_module("datagen"), SF, [params])
+    refs, ref_rows = q.reference(bench_module("datagen"), SF, [params])
     assert q.check(rows, refs[0]), (params, rows[:3])
     assert rows == one_chip.execute(sql).to_pylist()
     assert ref_rows["lineitem"] == tpch_device.lineitem_count(

@@ -34,7 +34,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from .. import types as T
-from ..page import Column, Page
+from ..page import Column, FormattedKeys, Page
 from ..spi import (
     ColumnSchema,
     ColumnStatistics,
@@ -284,6 +284,30 @@ def _counts(sf: float) -> Dict[str, int]:
     }
 
 
+# key-formatted varchars: column -> (prefix, zero-padded digits, the
+# table whose row count is the key space; None: the clerks).  The lane holds
+# the code `key - 1` (host and device generators alike) and the dictionary
+# is one FormattedKeys over the whole key space, whatever the split.
+KEY_FORMATS: Dict[str, Tuple[str, int, Optional[str]]] = {
+    "s_name": ("Supplier#", 9, "supplier"),
+    "s_address": ("addr-s-", 0, "supplier"),
+    "c_name": ("Customer#", 9, "customer"),
+    "c_address": ("addr-c-", 0, "customer"),
+    "o_clerk": ("Clerk#", 9, None),
+}
+
+
+def _clerks(sf: float) -> int:
+    return max(1, int(1000 * sf))
+
+
+def formatted_keys(col: str, sf: float) -> FormattedKeys:
+    """The dictionary of a KEY_FORMATS column at this scale factor."""
+    prefix, width, table = KEY_FORMATS[col]
+    n = _clerks(sf) if table is None else _counts(sf)[table]
+    return FormattedKeys(prefix, width, 1, n)
+
+
 def _orderkey(j: np.ndarray) -> np.ndarray:
     """Sparse order keys: 8 used out of every 32 (OrderGenerator.makeOrderKey)."""
     return (j // 8) * 32 + (j % 8) + 1
@@ -352,10 +376,8 @@ class _Gen:
                 out[c] = uint_in(c, idx, 0, 24)
             elif c == "s_acctbal":
                 out[c] = uint_in(c, idx, -99999, 999999)
-            elif c == "s_name":
-                out[c] = ("Supplier#", key)  # lazy formatted
-            elif c == "s_address":
-                out[c] = ("addr-s-", key)
+            elif c in ("s_name", "s_address"):
+                out[c] = idx.astype(np.int32)  # code of FormattedKeys
             elif c == "s_phone":
                 out[c] = ("phone", uint_in("s_nationkey", idx, 0, 24), h64(c, idx))
             elif c == "s_comment":
@@ -374,10 +396,8 @@ class _Gen:
                 out[c] = uint_in(c, idx, -99999, 999999)
             elif c == "c_mktsegment":
                 out[c] = (h64(c, idx) % np.uint64(5)).astype(np.int32)
-            elif c == "c_name":
-                out[c] = ("Customer#", key)
-            elif c == "c_address":
-                out[c] = ("addr-c-", key)
+            elif c in ("c_name", "c_address"):
+                out[c] = idx.astype(np.int32)  # code of FormattedKeys
             elif c == "c_phone":
                 out[c] = ("phone", uint_in("c_nationkey", idx, 0, 24), h64(c, idx))
             elif c == "c_comment":
@@ -448,8 +468,9 @@ class _Gen:
             elif c == "o_shippriority":
                 out[c] = np.zeros(len(j), dtype=np.int64)
             elif c == "o_clerk":
-                nclerk = max(1, int(1000 * self.sf))
-                out[c] = ("Clerk#", uint_in(c, j, 1, nclerk))
+                out[c] = (uint_in(c, j, 1, _clerks(self.sf)) - 1).astype(
+                    np.int32
+                )
             elif c == "o_comment":
                 out[c] = (h64(c, j) % np.uint64(len(COMMENTS))).astype(np.int32)
         if need_status:
@@ -526,11 +547,11 @@ class _Gen:
 
 
 def _format_lazy(spec, schema_type) -> Tuple[np.ndarray, np.ndarray]:
-    """Materialize a lazily-specified high-cardinality string column as
-    (codes, dictionary).  Formatted-key specs (Supplier#N, phone) are
-    distinct so codes are arange; pname DEDUPES its dictionary and
-    remaps codes (names can repeat, and code equality must equal
-    string equality)."""
+    """Materialize a hash-composed string column (pname, phone) as
+    (codes, dictionary).  Phones are distinct so codes are arange; pname
+    DEDUPES its dictionary and remaps codes (names can repeat, and code
+    equality must equal string equality).  Key-formatted columns never
+    come here: KEY_FORMATS / FormattedKeys."""
     if spec[0] == "pname":
         _, keys = spec
         nw = np.uint64(len(P_NAME_WORDS))
@@ -556,24 +577,17 @@ def _format_lazy(spec, schema_type) -> Tuple[np.ndarray, np.ndarray]:
                 entries.append(s)
             codes[i] = code
         return codes, np.array(entries, dtype=object)
-    elif spec[0] == "phone":
-        _, cc, hh = spec
-        n1 = (hh >> np.uint64(10)) % np.uint64(900) + np.uint64(100)
-        n2 = (hh >> np.uint64(30)) % np.uint64(900) + np.uint64(100)
-        n3 = (hh >> np.uint64(45)) % np.uint64(9000) + np.uint64(1000)
-        d = np.array(
-            [
-                f"{10 + int(c)}-{int(a)}-{int(b)}-{int(x)}"
-                for c, a, b, x in zip(cc, n1, n2, n3)
-            ],
-            dtype=object,
-        )
-    else:
-        prefix, keys = spec
-        if prefix.endswith("#"):
-            d = np.array([f"{prefix}{int(k):09d}" for k in keys], dtype=object)
-        else:
-            d = np.array([f"{prefix}{int(k)}" for k in keys], dtype=object)
+    _, cc, hh = spec
+    n1 = (hh >> np.uint64(10)) % np.uint64(900) + np.uint64(100)
+    n2 = (hh >> np.uint64(30)) % np.uint64(900) + np.uint64(100)
+    n3 = (hh >> np.uint64(45)) % np.uint64(9000) + np.uint64(1000)
+    d = np.array(
+        [
+            f"{10 + int(c)}-{int(a)}-{int(b)}-{int(x)}"
+            for c, a, b, x in zip(cc, n1, n2, n3)
+        ],
+        dtype=object,
+    )
     codes = np.arange(len(d), dtype=np.int32)
     return codes, d
 
@@ -623,12 +637,14 @@ def generate(
     types = dict(schema)
     for c in cols:
         v = raw[c]
-        if isinstance(v, tuple):  # lazy high-cardinality string
+        if isinstance(v, tuple):  # hash-composed string (pname, phone)
             codes, d = _format_lazy(v, types[c])
             values[c], dicts[c] = codes, d
         else:
             values[c] = v
-            if types[c].is_dictionary:
+            if c in KEY_FORMATS:
+                dicts[c] = formatted_keys(c, sf)
+            elif types[c].is_dictionary:
                 dicts[c] = _VOCABS[c]
     return values, dicts, count
 
@@ -794,7 +810,9 @@ class TpchPageSource(PageSource):
         types = dict(SCHEMAS[self.split.table])
         out = dict(self._dicts)
         for c in self.columns:
-            if types[c].is_dictionary and c in _VOCABS and c not in out:
+            if c in KEY_FORMATS:
+                out.setdefault(c, formatted_keys(c, self.sf))
+            elif types[c].is_dictionary and c in _VOCABS and c not in out:
                 out[c] = _VOCABS[c]
         return out
 
@@ -863,6 +881,9 @@ class TpchConnector(Connector):
             for c in cols
             if types[c].is_dictionary and c in _VOCABS
         }
+        dicts.update(
+            (c, formatted_keys(c, self.sf)) for c in cols if c in KEY_FORMATS
+        )
         widths = {c: 4 if types[c].is_dictionary or types[c].name == "date"
                   else 8 for c in cols}
         return {

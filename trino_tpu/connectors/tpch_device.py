@@ -18,9 +18,12 @@ bit-parity with the host generator is enforced by
 tests/test_tpch_device.py (splitmix64 is pure integer math: jnp.uint64
 and np.uint64 agree exactly).
 
-Columns whose host path formats per-row strings (names, phones,
-addresses, clerks) are not device-generatable; a scan touching one falls
-back to the host generator wholesale.
+Key-formatted varchars (c_name, s_name, the addresses, o_clerk) are device
+columns too: the lane is the code `key - 1` and the dictionary a
+`page.FormattedKeys`, which formats an entry when a result row reads it.
+Columns whose strings are composed from hashes (phones, p_name) are not
+device-generatable; a scan touching one falls back to the host generator
+wholesale.
 """
 from __future__ import annotations
 
@@ -90,19 +93,21 @@ def _line_count(j: jnp.ndarray) -> jnp.ndarray:
 # zero padding); `lo`/`hi` are TRACED scalars so every streaming tile of
 # the same padded shape shares one compiled generator.
 
-# columns the device path can produce (everything except host-formatted
-# lazy strings); comments/names with fixed vocabularies are dict CODES
+# columns the device path can produce (everything except the hash-composed
+# strings); comments/names with fixed vocabularies and the key-formatted
+# varchars of tpch.KEY_FORMATS are dict CODES
 DEVICE_COLS: Dict[str, frozenset] = {
     "region": frozenset({"r_regionkey", "r_name", "r_comment"}),
     "nation": frozenset(
         {"n_nationkey", "n_name", "n_regionkey", "n_comment"}
     ),
     "supplier": frozenset(
-        {"s_suppkey", "s_nationkey", "s_acctbal", "s_comment"}
+        {"s_suppkey", "s_nationkey", "s_acctbal", "s_comment", "s_name",
+         "s_address"}
     ),
     "customer": frozenset(
         {"c_custkey", "c_nationkey", "c_acctbal", "c_mktsegment",
-         "c_comment"}
+         "c_comment", "c_name", "c_address"}
     ),
     "part": frozenset(
         {"p_partkey", "p_mfgr", "p_brand", "p_type", "p_size",
@@ -114,7 +119,8 @@ DEVICE_COLS: Dict[str, frozenset] = {
     ),
     "orders": frozenset(
         {"o_orderkey", "o_custkey", "o_orderstatus", "o_totalprice",
-         "o_orderdate", "o_orderpriority", "o_shippriority", "o_comment"}
+         "o_orderdate", "o_orderpriority", "o_shippriority", "o_comment",
+         "o_clerk"}
     ),
     "lineitem": frozenset(
         {"l_orderkey", "l_partkey", "l_suppkey", "l_linenumber",
@@ -147,6 +153,12 @@ def _base_table(table: str, cols, idx, n: Dict[str, int], sf: float):
             out[c] = region_of[jnp.clip(idx, 0, len(H.NATIONS) - 1)]
         elif c in ("s_suppkey", "c_custkey", "p_partkey"):
             out[c] = key
+        elif c in ("s_name", "s_address", "c_name", "c_address"):
+            out[c] = idx.astype(jnp.int32)  # code of H.formatted_keys(c)
+        elif c == "o_clerk":
+            out[c] = (_uint_in(c, idx, 1, H._clerks(sf)) - 1).astype(
+                jnp.int32
+            )
         elif c in ("s_nationkey", "c_nationkey"):
             out[c] = _uint_in(c, idx, 0, 24)
         elif c in ("s_acctbal", "c_acctbal"):

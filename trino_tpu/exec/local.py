@@ -41,7 +41,7 @@ from ..ops import join as join_ops
 from ..ops import sort as sort_ops
 from ..obs import compile_observatory as _compile_obs
 from ..ops import window as window_ops
-from ..page import Column, Page, pad_to
+from ..page import Column, FormattedKeys, Page, pad_to, same_dictionary
 from ..plan import nodes as P
 from ..runtime import Breadcrumb, DeviceFaultError, default_supervisor
 from ..spi import Split
@@ -242,9 +242,7 @@ def merge_pages_to_arrays(pages, symbols, types, dicts):
                 page_dicts.append(d)
             shared = True
             for d in page_dicts[1:]:
-                if d is not page_dicts[0] and not np.array_equal(
-                    page_dicts[0], d
-                ):
+                if not same_dictionary(page_dicts[0], d):
                     shared = False
                     break
             if shared:
@@ -306,9 +304,21 @@ def dict_fingerprint(dicts: Dict[str, np.ndarray], symbols) -> int:
         if d is None:
             continue
         h.update(f"{s}\x1f{len(d)}\x1f".encode())
+        if isinstance(d, FormattedKeys):  # its fields say every entry
+            h.update(d.fingerprint().encode())
+            continue
         for x in d:
             h.update(str(x).encode() + b"\x00")
     return int.from_bytes(h.digest(), "little")
+
+
+# which lowering each operator of a traced fragment took (one dictionary
+# update per traced operator; a warm query of a cached program carries
+# none).  Names in PERF.md section 3.
+OP_COUNTERS = (
+    "sortGroupBys", "sortGroupRows", "sortGroupCapacity", "directGroupBys",
+    "directJoins", "sortJoins", "semiJoins", "lazyDictionaryColumns",
+)
 
 
 def _is_null_expr(e: ir.Expr) -> bool:
@@ -610,6 +620,7 @@ class LocalExecutor:
                                     lambda: self._run(plan, ctx), bc
                                 )
                             )
+                        self._note_op_counts(ctx.op_counts)
                         dups = ctx.dup_checks
                         colls = ctx.collision_checks
                         wides = ctx.lowering.overflow_flags
@@ -1429,7 +1440,23 @@ class LocalExecutor:
                 walk(s)
 
         walk(plan)
+        # NDV products wildly overestimate for correlated keys (brand_id
+        # determines brand; orderkey determines orderdate), a filter or a
+        # join below leaves few of a key's values, and every segment op
+        # pays O(capacity).  Cap the first try; the overflow ladder (x8
+        # per rung) covers genuinely huge group counts with one recompile
+        # instead of every query paying worst-case capacity.  One case
+        # is no estimate: ONE key grouped straight off its whole scan
+        # (Q18's 1.5M orders of lineitem) has exactly its NDV in groups,
+        # and stands uncapped — a rung short there costs a second trace
+        # and compile of the whole fragment.
+        first_try_cap = 1 << 18
         best = None
+
+        def whole_scan(n: P.PlanNode) -> bool:
+            while isinstance(n, P.Project):
+                n = n.source
+            return isinstance(n, P.TableScan) and not n.constraint
 
         def walk2(n: P.PlanNode):
             nonlocal best
@@ -1439,20 +1466,18 @@ class LocalExecutor:
                     est *= ndv.get(k, float(DEFAULT_GROUP_CAPACITY))
                     if est > 1e12:
                         break
-                est = min(est, float(max_rows) or est)
-                best = max(best or 0, int(est))
+                exact = (len(n.keys) == 1 and n.keys[0] in ndv
+                         and whole_scan(n.source))
+                if not exact:
+                    est = min(est * 2, first_try_cap)
+                best = max(best or 0, int(min(est, float(max_rows) or est)))
             for s in n.sources:
                 walk2(s)
 
         walk2(plan)
         if best is None or best <= DEFAULT_GROUP_CAPACITY:
             return None
-        # NDV products wildly overestimate for correlated keys (brand_id
-        # determines brand; orderkey determines orderdate), and every
-        # segment op pays O(capacity).  Cap the first try; the overflow
-        # ladder (x8 per rung) covers genuinely huge group counts with one
-        # recompile instead of every query paying worst-case capacity.
-        return self.ladder.quantize(min(best * 2, max_rows, 1 << 18))
+        return self.ladder.quantize(best)
 
     # ------------------------------------------------------------------
     def _compile_family(self, plan) -> str:
@@ -1687,6 +1712,7 @@ class LocalExecutor:
                 )
                 ctx.prepared = True
                 out_lanes, sel, ordered, checks = self._run(plan, ctx)
+                cell["op_counts"] = dict(ctx.op_counts)
                 cell["ordered"] = ordered
                 cell["caps"] = [(c, k) for _, c, k in checks]
                 # dup-check join nodes are recorded as plan ordinals so a
@@ -1768,6 +1794,7 @@ class LocalExecutor:
             self._record_kernel(
                 digest, compile_s=compile_s, cached=False, cause=cause
             )
+            self._note_op_counts(cell["op_counts"])
             cell["dicts"] = dict(self.dicts)
             # the plan reference pins id(plan) (fingerprint memo validity)
             entry = {"fn": fn, "cell": cell, "plan": plan}
@@ -1794,6 +1821,15 @@ class LocalExecutor:
         ]
         return (out_lanes, sel, cell["ordered"], checks, dups, colls,
                 wides, sflags)
+
+    def _note_op_counts(self, op_counts: Dict[str, int]) -> None:
+        """The operator counters of the trace just made stand in the
+        kernel profile as ONE trace's: a capacity-ladder retrace lowers
+        the operators again, and its counts replace the last rung's."""
+        prof = self.kernel_profile
+        for name in OP_COUNTERS:
+            prof.pop(name, None)
+        prof.update(op_counts)
 
     @staticmethod
     def _compile_fragment(fn, *args):
@@ -1837,7 +1873,17 @@ class LocalExecutor:
                 # limb): widen host-side so clients decode two limbs
                 vals = np.stack([vals, vals >> np.int64(63)], axis=-1)
             validity = None if valid.all() else valid
-            cols.append(Column(t, vals, validity, self.dicts.get(sym)))
+            d = self.dicts.get(sym)
+            if isinstance(d, FormattedKeys) and n < len(d):
+                # the page carries the entries its rows read, formatted
+                # now; the scan's dictionary stays four fields
+                with TRACER.span("dictionary_format", rows=n):
+                    live = vals >= 0
+                    used, inverse = np.unique(vals[live], return_inverse=True)
+                    vals = np.full(n, -1, dtype=np.int32)
+                    vals[live] = inverse
+                    d = d[used]
+            cols.append(Column(t, vals, validity, d))
         return Page(cols, n, list(plan.names))
 
 
@@ -1855,8 +1901,14 @@ class _TraceCtx:
         # via wide chunk accumulators; bigint wrap raises loudly per SQL
         # semantics, never silently)
         self.sum_overflow: List[jnp.ndarray] = []
+        # which lowering each operator of THIS trace took (OP_COUNTERS);
+        # the executor copies them into its kernel profile after the trace
+        self.op_counts: Dict[str, int] = {}
         self.lowering = LoweringContext(ex.dicts)
         self.lowering.force_wide_mul = getattr(ex, 'force_wide_mul', False)
+
+    def _count(self, name: str, n: int = 1) -> None:
+        self.op_counts[name] = self.op_counts.get(name, 0) + int(n)
 
     # -- dispatch -------------------------------------------------------
     def visit(self, node: P.PlanNode) -> Batch:
@@ -1922,6 +1974,11 @@ class _TraceCtx:
             lanes = self.ex._device_lanes(node, self.scans[id(node)], count)
             cnt = count
         sel = jnp.arange(cap) < cnt
+        lazy = sum(
+            isinstance(self.ex.dicts.get(s), FormattedKeys) for s in lanes
+        )
+        if lazy:
+            self._count("lazyDictionaryColumns", lazy)
         return Batch(lanes, sel)
 
     def _visit_values(self, node: P.Values) -> Batch:
@@ -2333,6 +2390,7 @@ class _TraceCtx:
         key_lanes = [b.lanes[k] for k in node.keys]
         domains = self._direct_domains(node.keys, types)
         if domains is not None:
+            self._count("directGroupBys")
             gid, cap = agg_ops.direct_group_ids(key_lanes, domains)
             accs = reduce_rows(b.lanes, gid, b.sel, cap)
             # _seg_count picks the masked/pallas form at small caps — a
@@ -2344,6 +2402,9 @@ class _TraceCtx:
             host_src = (b.lanes, gid, b.sel)
         else:
             cap = min(self.ex.group_capacity, b.sel.shape[0])
+            self._count("sortGroupBys")
+            self._count("sortGroupRows", b.sel.shape[0])
+            self._count("sortGroupCapacity", cap)
             perm, gid, ngroups = self._group_sort(key_lanes, b.sel, cap)
             self._note_capacity(ngroups, cap)
             sel_sorted = b.sel[perm]
@@ -2411,6 +2472,8 @@ class _TraceCtx:
             d = self.ex.dicts.get(sym)
             if d is None or len(d) == 0:
                 return sym, d if d is not None else np.array([], dtype=object)
+            if getattr(d, "sorted_by_code", False):
+                return sym, d  # a code is its own rank
             order = np.argsort(np.array([str(x) for x in d]))
             rank = np.empty(len(d), dtype=np.int32)
             rank[order] = np.arange(len(d), dtype=np.int32)
@@ -2507,6 +2570,7 @@ class _TraceCtx:
         if node.expansion or id(node) in getattr(
             self.ex, "force_expansion", ()
         ):
+            self._count("sortJoins")
             return self._expansion_join(node, left, right)
         # unique-keyed build on right, probe on left
         lkeys = [left.lanes[l] for l, _ in node.criteria]
@@ -2527,6 +2591,7 @@ class _TraceCtx:
             # dense-domain direct addressing: one scatter builds, one
             # gather probes; a violation/duplicate count retries on the
             # sorted unique kernel (then expansion if genuinely dup)
+            self._count("directJoins")
             lo, hi = node.direct_domain
             dsrc = join_ops.build_direct(
                 bkey, right.sel, lo, hi - lo + 1
@@ -2534,6 +2599,7 @@ class _TraceCtx:
             self.dup_checks.append((node, dsrc.violations))
             row, matched = join_ops.probe_direct(dsrc, pkey, left.sel)
         else:
+            self._count("sortJoins")
             src = join_ops.build_unique(bkey, right.sel)
             self.dup_checks.append((node, src.dup_count))
             row, matched = join_ops.probe(src, pkey, left.sel)
@@ -2712,7 +2778,7 @@ class _TraceCtx:
                 raise ExecutionError(
                     f"join key {l}={r} mixes varchar dictionary and non-dict"
                 )
-            if dl is not None and dl is not dr and not np.array_equal(dl, dr):
+            if dl is not None and not same_dictionary(dl, dr):
                 raise ExecutionError(
                     f"join on varchar keys {l}={r} requires shared dictionary"
                 )
@@ -2771,6 +2837,7 @@ class _TraceCtx:
         (sorted search, any match counts).  Single-column keys compare the
         real value directly (collision-free); multi-column keys and residual
         predicates go through the expansion path with exact verification."""
+        self._count("semiJoins")
         skeys = [src.lanes[k] for k in node.source_keys]
         fkeys0 = [filt.lanes[k] for k in node.filtering_keys]
         if (
@@ -2869,8 +2936,11 @@ class _TraceCtx:
         out = []
         for k in keys:
             d = self.ex.dicts.get(k.column)
-            if d is not None and len(d) == 0:
-                d = None  # zero-row split: codes are all sentinels
+            if d is not None and (
+                len(d) == 0  # zero-row split: codes are all sentinels
+                or getattr(d, "sorted_by_code", False)  # code == rank
+            ):
+                d = None
             if d is not None:
                 # DENSE ranks: generated dictionaries can carry duplicate
                 # strings under distinct codes, and ordinal ranks would

@@ -251,7 +251,9 @@ def _merge_tile_counters(executor, fe) -> None:
     executor.kernel_profile["streamedFragments"] = (
         executor.kernel_profile.get("streamedFragments", 0) + 1
     )
-    for k in _TILE_COUNTERS:
+    from .local import OP_COUNTERS
+
+    for k in _TILE_COUNTERS + OP_COUNTERS:
         v = prof.get(k)
         if v:
             executor.kernel_profile[k] = (

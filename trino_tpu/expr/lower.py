@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import types as T
+from ..page import FormattedKeys, same_dictionary
 from . import ir
 from .functions import FUNCTIONS, align_numeric, decimal_rescale, dict_gather, round_half_away
 
@@ -111,6 +112,9 @@ class LoweringContext:
 
     def dict_code_for(self, col, s: str) -> int:
         d = self._dict_of(col)
+        if isinstance(d, FormattedKeys):
+            code = d.index_of(s)
+            return code if code >= 0 else -2
         idx = np.nonzero(d == s)[0]
         return int(idx[0]) if len(idx) else -2  # -2: never matches any code
 
@@ -205,7 +209,7 @@ def _lower_comparison(node: ir.Comparison, cols, ev, ctx: LoweringContext) -> La
         ln = node.left.name if isinstance(node.left, ir.ColumnRef) else None
         rn = node.right.name if isinstance(node.right, ir.ColumnRef) else None
         da, db = ctx.dictionaries.get(ln), ctx.dictionaries.get(rn)
-        shared = da is not None and db is not None and np.array_equal(da, db)
+        shared = da is not None and db is not None and same_dictionary(da, db)
         if not (shared and node.op in ("=", "<>", "!=", "is_distinct")):
             raise NotImplementedError(
                 "varchar column-vs-column comparison requires a shared "

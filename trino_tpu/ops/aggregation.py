@@ -58,6 +58,7 @@ import jax.numpy as jnp
 
 from .. import types as T
 from ..expr.lower import Lane
+from .join import row_ids
 
 I64_MAX = 2**62  # python int (see ops/int128.py const-arg note)
 
@@ -377,9 +378,12 @@ def sort_group_ids(
     n = key_lanes[0][0].shape[0]
     hk = _group_hash(key_lanes, salt)
     key = jnp.where(sel, hk, jnp.int64(2**61))  # dead rows sort last
+    # row ids as the last key of an unstable sort: the stable order, at a
+    # fraction of the compile (join.row_ids)
     sorted_key, perm = jax.lax.sort(
-        (key, jnp.arange(n, dtype=jnp.int64)), num_keys=1
+        (key, row_ids(n)), num_keys=2, is_stable=False
     )
+    perm = perm.astype(jnp.int64)
     sel_sorted = sorted_key < jnp.int64(2**61)
     diff = jnp.concatenate(
         [jnp.ones(1, bool), sorted_key[1:] != sorted_key[:-1]]
@@ -688,9 +692,11 @@ class SortedSegments:
         self.gid = gid
         self.cap = cap
         self.n = gid.shape[0]
-        probe = jnp.arange(cap, dtype=jnp.int64)
-        self.starts = merge_rank(gid, probe, side="left")
-        self.ends = merge_rank(gid, probe, side="right")
+        # group ids are below cap: they sort as the row ids' dtype
+        probe = row_ids(cap)
+        keys = gid.astype(probe.dtype)
+        self.starts = merge_rank(keys, probe, side="left")
+        self.ends = merge_rank(keys, probe, side="right")
         self.counts_all = self.ends - self.starts  # incl. non-live rows
 
     def _range_diff(self, cs: jnp.ndarray) -> jnp.ndarray:

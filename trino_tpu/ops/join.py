@@ -43,42 +43,50 @@ from ..expr.lower import Lane
 _SENTINEL = 2**63 - 1  # python int (see ops/int128.py const-arg note)
 
 
+def row_ids(n: int) -> jnp.ndarray:
+    """Row numbers 0..n-1 as a sort operand.  XLA:TPU's sort compiles (and
+    runs) by the 32-bit words it carries, whatever the row count: an int64
+    key with an int64 payload took 185 s to compile (8M rows, for a
+    described v5e on the sandbox's host), with an int32 one as the last KEY
+    of an unstable sort 50 s (same order: the ids are unique).  So ids ride
+    as int32 wherever the static row count allows."""
+    return jnp.arange(n, dtype=jnp.int32 if n < 2**31 else jnp.int64)
+
+
 def _sort_live_first(kv, live, n):
     dead = (~live).astype(jnp.int32)
     sorted_keys, _, perm = jax.lax.sort(
-        (kv, dead, jnp.arange(n, dtype=jnp.int64)), num_keys=2
+        (kv, dead, row_ids(n)), num_keys=3, is_stable=False
     )
-    return sorted_keys, perm
+    return sorted_keys, perm.astype(jnp.int64)
 
 
 def merge_rank(sorted_build: jnp.ndarray, probe: jnp.ndarray, side: str):
     """For each probe key: the number of build keys strictly below it
     (side='left') or at-or-below it (side='right') — searchsorted by
-    sort-merge.  One stable single-key sort of [build ++ probe] where the
+    sort-merge.  One sort of [build ++ probe] by (key, position), so the
     concatenation order breaks ties (build-first = right, probe-first =
     left), then a cumulative count of build elements."""
     nb = sorted_build.shape[0]
     m = probe.shape[0]
+    ids = row_ids(nb + m)
     if side == "left":
         keys = jnp.concatenate([probe, sorted_build])
-        _, perm = jax.lax.sort(
-            (keys, jnp.arange(nb + m, dtype=jnp.int64)), num_keys=1
-        )
+        _, perm = jax.lax.sort((keys, ids), num_keys=2, is_stable=False)
         is_build = perm >= m
         probe_idx = jnp.where(is_build, m, perm)
     else:
         keys = jnp.concatenate([sorted_build, probe])
-        _, perm = jax.lax.sort(
-            (keys, jnp.arange(nb + m, dtype=jnp.int64)), num_keys=1
-        )
+        _, perm = jax.lax.sort((keys, ids), num_keys=2, is_stable=False)
         is_build = perm < nb
         probe_idx = jnp.where(is_build, m, perm - nb)
-    cb = jnp.cumsum(is_build.astype(jnp.int64))
+    cb = jnp.cumsum(is_build.astype(ids.dtype))
     # route each cb back to its probe row by SORTING on probe_idx
     # (probes get 0..m-1, build rows sink at m): a scatter here cost
-    # ~0.6s at 10M (XLA:TPU ~16M updates/s) vs ~0.15s for the sort
-    _, back = jax.lax.sort((probe_idx, cb), num_keys=1)
-    return back[:m]
+    # ~0.6s at 10M (XLA:TPU ~16M updates/s) vs ~0.15s for the sort.
+    # Unstable: the probes' ids are unique, and nothing past them is read
+    _, back = jax.lax.sort((probe_idx, cb), num_keys=1, is_stable=False)
+    return back[:m].astype(jnp.int64)
 
 
 class LookupSource(NamedTuple):
@@ -226,7 +234,7 @@ def probe_counts(
         [jnp.ones(1, bool),
          source.sorted_keys[1:] != source.sorted_keys[:-1]]
     )
-    idx = jnp.arange(nb, dtype=jnp.int64)
+    idx = row_ids(nb)   # an int64 cummax compiles for 80 s, an int32 one for 5
     run_start = jax.lax.cummax(jnp.where(boundary, idx, 0))
     nxt = jnp.concatenate([boundary[1:], jnp.ones(1, bool)])
     run_end = jax.lax.cummin(

@@ -28,6 +28,100 @@ import numpy as np
 from . import types as T
 
 
+class FormattedKeys:
+    """Dictionary of a key-formatted varchar column (TPC-H `c_name`,
+    `s_name`, `o_clerk`, the addresses): entry `i` is `prefix` followed by
+    the integer `first + i`, zero-padded to `width` digits when `width` is
+    set.  It answers what a dictionary array is asked (`len`, `d[i]`,
+    slices, index arrays, iteration, `np.asarray`) and formats an entry
+    only when it is read, so a 1.5 M-customer scan carries four fields and
+    a 100-row result formats 100 strings.  `formatted` counts them."""
+
+    __slots__ = ("prefix", "width", "first", "n", "formatted")
+
+    def __init__(self, prefix: str, width: int, first: int, n: int):
+        self.prefix, self.width = prefix, int(width)
+        self.first, self.n = int(first), int(n)
+        self.formatted = 0
+
+    def __len__(self) -> int:
+        return self.n
+
+    @property
+    def sorted_by_code(self) -> bool:
+        """Zero-padded keys sort as their integers: a code is its own
+        lexicographic rank, and ORDER BY / min / max need no rank table."""
+        return self.width > 0 and self.first >= 0 and (
+            self.first + self.n <= 10 ** self.width
+        )
+
+    def fingerprint(self) -> str:
+        """What `dict_fingerprint` hashes in place of the entries."""
+        return "%s\x1e%d\x1e%d\x1e%d" % (
+            self.prefix, self.width, self.first, self.n)
+
+    def _entry(self, i: int) -> str:
+        self.formatted += 1
+        return self._peek(i)
+
+    def __getitem__(self, i):
+        if isinstance(i, slice):
+            lo, hi, step = i.indices(self.n)
+            if step == 1:
+                return FormattedKeys(
+                    self.prefix, self.width, self.first + lo, max(hi - lo, 0)
+                )
+            i = np.arange(lo, hi, step)
+        if isinstance(i, (int, np.integer)):
+            i = int(i)
+            if not -self.n <= i < self.n:
+                raise IndexError(i)
+            return self._entry(i % self.n)
+        idx = np.asarray(i)
+        if idx.dtype == bool:
+            idx = np.nonzero(idx)[0]
+        out = np.empty(idx.shape, dtype=object)
+        flat = out.reshape(-1)
+        for j, k in enumerate(idx.reshape(-1).tolist()):
+            if not -self.n <= k < self.n:
+                raise IndexError(k)
+            flat[j] = self._entry(k % self.n)
+        return out
+
+    def __iter__(self):
+        return (self._entry(i) for i in range(self.n))
+
+    def __array__(self, dtype=None, copy=None):
+        out = self[np.arange(self.n)]
+        return out if dtype is None else out.astype(dtype)
+
+    def index_of(self, s: str) -> int:
+        """Code of the entry that reads `s`, or -1: parsed, nothing is
+        formatted (what an equality predicate on the column asks)."""
+        digits = s[len(self.prefix):] if s.startswith(self.prefix) else ""
+        if not (digits.isascii() and digits.isdigit()):
+            return -1
+        i = int(digits) - self.first
+        return i if 0 <= i < self.n and self._peek(i) == s else -1
+
+    def _peek(self, i: int) -> str:
+        return "%s%0*d" % (self.prefix, self.width, self.first + i)
+
+    def __repr__(self):
+        return "FormattedKeys(%r, %d, %d, %d)" % (
+            self.prefix, self.width, self.first, self.n)
+
+
+def same_dictionary(a, b) -> bool:
+    """Do two dictionaries hold the same entries under the same codes?
+    Two FormattedKeys compare by their four fields, nothing is formatted."""
+    if a is b:
+        return True
+    if isinstance(a, FormattedKeys) and isinstance(b, FormattedKeys):
+        return a.fingerprint() == b.fingerprint()
+    return np.array_equal(a, b)
+
+
 @dataclasses.dataclass
 class Column:
     """One column of a Page: values + optional validity + optional dictionary.

@@ -53,7 +53,7 @@ from ..ops import sketches
 from ..ops import sort as sort_ops
 from ..ops import tree_nbytes
 from . import shuffle
-from ..page import Page
+from ..page import Page, same_dictionary
 from ..plan import nodes as P
 from ..runtime import Breadcrumb, DeviceFaultError
 
@@ -547,6 +547,7 @@ class MeshExecutor(LocalExecutor):
                 if not batch.replicated:
                     batch = _gather_batch(batch)
                 cell["caps"] = list(ctx.capacity_limits)
+                cell["op_counts"] = dict(ctx.op_counts)  # one shard's
                 # dup-check join nodes as plan ordinals: another session
                 # hitting this entry resolves them to ITS plan's nodes
                 cell["dup_ords"] = [
@@ -621,6 +622,7 @@ class MeshExecutor(LocalExecutor):
                 digest, compile_s=compile_s,
                 cached=False, mode="mesh", cause=cause,
             )
+            self._note_op_counts(cell["op_counts"])
             cell["dicts"] = dict(self.dicts)
             if keyed:
                 # the plan reference pins id(plan) (fingerprint memo)
@@ -863,7 +865,7 @@ class MeshExecutor(LocalExecutor):
             present = [dd.get(sym) for dd in per_dev_dicts]
             base = next((d for d in present if d is not None), None)
             if all(
-                d is None or d is base or np.array_equal(d, base)
+                d is None or same_dictionary(d, base)
                 for d in present
             ):
                 dicts[sym] = base
