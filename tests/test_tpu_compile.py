@@ -192,3 +192,31 @@ def test_fused_scan_aggregate_fragment_compiles_for_v5e(
         rows = s.execute(QUERIES[query][0]).to_pylist()
     assert rows
     assert texts and all("tpu_custom_call" in t for t in texts)
+
+
+def test_compaction_at_q3_sizes_is_one_sort_for_v5e(
+        one_chip, no_persistent_cache):
+    """`_maybe_compact` at `tpch_sf1.q3`'s own sizes (lineitem's 8,388,608
+    slots after `l_shipdate >` into the 4,194,304 rung): row ids by one
+    single-key sort, then the stacked row gather of two int64 lanes.  The
+    `jnp.nonzero` it replaced compiled to a scatter-add fusion (744 ms a
+    query on the chip) with 219 MB of temporaries."""
+    from trino_tpu.ops.filter_project import compact_indices, permute_lanes
+
+    n, cap = 8_388_608, 4_194_304
+
+    def compact(sel, a, b):
+        idx = compact_indices(sel, cap)
+        return idx, permute_lanes({"a": (a, sel), "b": (b, sel)}, idx)
+
+    def sds(dtype):
+        return jax.ShapeDtypeStruct((n,), dtype, sharding=one_chip)
+
+    compiled = jax.jit(compact).lower(
+        sds(jnp.bool_), sds(jnp.int64), sds(jnp.int64)).compile()
+    text = compiled.as_text()
+    # (function names ride in the metadata: none here may say "scatter")
+    assert " sort(" in text and "scatter" not in text
+    mem = compiled.memory_analysis()
+    assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes) < HBM_BYTES

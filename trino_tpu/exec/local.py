@@ -318,6 +318,7 @@ def dict_fingerprint(dicts: Dict[str, np.ndarray], symbols) -> int:
 OP_COUNTERS = (
     "sortGroupBys", "sortGroupRows", "sortGroupCapacity", "directGroupBys",
     "directJoins", "sortJoins", "semiJoins", "lazyDictionaryColumns",
+    "compactions", "compactRows", "compactCapacity",
 )
 
 
@@ -2030,9 +2031,11 @@ class _TraceCtx:
     def _maybe_compact(self, b: Batch, node) -> Batch:
         """Tighten survivors into a smaller static capacity (the
         optimizer's compact_rows estimate, grown by the ladder's
-        compact_factor).  One jnp.nonzero + one stacked row-gather;
-        every downstream sort/gather then runs at the tightened width
-        and the fragment's HBM peak shrinks with it.  Exactness: the
+        compact_factor).  One single-key int32 sort for the survivors'
+        row ids (ops/filter_project.compact_indices: no scatter, no
+        int64 scan) + one stacked row-gather; every downstream
+        sort/gather then runs at the tightened width and the fragment's
+        HBM peak shrinks with it.  Exactness: the
         true survivor count rides the capacity checks — overflow re-runs
         with a wider (eventually input-width, i.e. no-op) capacity."""
         est = getattr(node, "compact_rows", None)
@@ -2048,10 +2051,13 @@ class _TraceCtx:
         n = b.sel.shape[0]
         if cap >= n:
             return b
-        from ..ops.filter_project import permute_lanes
+        from ..ops.filter_project import compact_indices, permute_lanes
 
+        self._count("compactions")
+        self._count("compactRows", n)
+        self._count("compactCapacity", cap)
         total = b.sel.sum()
-        idx = jnp.nonzero(b.sel, size=cap, fill_value=0)[0]
+        idx = compact_indices(b.sel, cap)
         self._note_capacity(total, cap, "compact")
         lanes = permute_lanes(b.lanes, idx)
         sel = jnp.arange(cap) < total

