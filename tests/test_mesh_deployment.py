@@ -223,9 +223,16 @@ def test_sharded_generator_compiles_before_the_supervised_dispatch(
 # -- (e) the phase spans -------------------------------------------------------
 
 
-def test_phase_spans_open_once_a_query_and_tile_execute(mesh):
-    _q, _params, sql = _text("q1", SEEDS[0])
+@pytest.mark.parametrize("query", ["q1", "q3"])
+def test_phase_spans_open_once_a_query_and_tile_execute(query, mesh):
+    _q, _params, sql = _text(query, SEEDS[0])
+    _drain(mesh)
     mesh.execute(sql)
+    # a table is generated once, by the first query that scans it, and a
+    # phase opens once in that query too (Q3: three tables, three `devgen`s)
+    first = [s.name for s in _drain(mesh)]
+    assert first.count("devgen") <= len(_q.TABLES)
+    assert [first.count(n) for n in PHASES] == [1] * len(PHASES)
     shares = []
     for _ in range(3):   # a share of a few milliseconds: the best of three
         _drain(mesh)
@@ -287,3 +294,90 @@ def test_a_shrunk_mesh_reuses_neither_lanes_nor_executable():
         ("mesh", 0, 1, 2, 3), ("mesh", 1, 2, 3)}
     assert all({d for d, _ in sh} == {1, 2, 3}
                for sh in prof["scanShards"].values())
+
+
+# -- (g) the mesh's exchanges and sort group-bys are counted at trace time ----
+
+EXCHANGE_COUNTERS = ("broadcastExchanges", "broadcastExchangeSlots",
+                     "partitionedExchanges", "partitionedExchangeSlots",
+                     "groupStateExchangeSlots")
+
+
+@pytest.fixture(scope="module")
+def q3_traced():
+    """Q3 traced under each join distribution (`compile_cache=False`: the
+    counters are written by the query that traces the fragment, and the
+    executable cache is process-wide), with the reference's answer."""
+    q, params, sql = _text("q3", SEEDS[1])
+    refs, _ = q.reference(bench_module("datagen"), SF, [params])
+    out = {}
+    for dist in ("automatic", "partitioned"):
+        s = _mesh_session(compile_cache=False, join_distribution_type=dist)
+        rows = s.execute(sql).to_pylist()
+        out[dist] = (rows, q.check(rows, refs[0]),
+                     dict(s.last_kernel_profile))
+    return out
+
+
+def _shard_cap(prof, column):
+    (shards,) = [sh for name, sh in prof["scanShards"].items()
+                 if name.endswith("." + column)]
+    return shards[0][1]
+
+
+@pytest.mark.parametrize("counter, expected", [
+    ("broadcastExchanges", 2),
+    ("partitionedExchanges", 0), ("partitionedExchangeSlots", 0),
+    ("sortGroupBys", 2), ("directJoins", 2), ("compactions", 0)])
+def test_q3_counts_two_broadcast_joins_and_a_two_step_group_by(
+        q3_traced, counter, expected):
+    rows, correct, prof = q3_traced["automatic"]
+    assert correct, rows[:3]
+    assert prof.get(counter, 0) == expected
+
+
+def test_q3_exchange_slots_are_the_shards_slots_times_the_mesh(q3_traced):
+    _rows, _ok, prof = q3_traced["automatic"]
+    customer, orders, lineitem = (
+        _shard_cap(prof, c) for c in ("c_custkey", "o_orderkey", "l_orderkey"))
+    # customer is gathered as it lies; orders x customer keeps orders' slots
+    # (the mesh does not compact), so what is gathered is slots, not rows
+    assert prof["broadcastExchangeSlots"] == NDEV * (customer + orders)
+    # local group sort over the probe's slots, final one over the gathered
+    # partial state of every device
+    local_cap = prof["groupStateExchangeSlots"] // NDEV
+    assert 0 < local_cap <= lineitem
+    assert prof["sortGroupRows"] == lineitem + NDEV * local_cap
+    assert prof["sortGroupCapacity"] >= local_cap
+
+
+def test_partitioned_q3_counts_its_repartitions_and_answers_alike(q3_traced):
+    rows, correct, prof = q3_traced["partitioned"]
+    assert correct, rows[:3]
+    assert rows == q3_traced["automatic"][0]
+    assert prof["partitionedExchanges"] >= 2
+    assert prof["partitionedExchangeSlots"] >= 2 * NDEV * 128
+    assert prof["partitionedExchangeSlots"] % NDEV == 0
+
+
+def test_fused_q1_on_the_mesh_carries_no_exchange_or_sort_counter():
+    _q, _params, sql = _text("q1", SEEDS[0] + 7)
+    # `megakernels="on"`: off the chip the fused kernel runs interpreted
+    s = _mesh_session(compile_cache=False, megakernels="on")
+    assert s.execute(sql).to_pylist()
+    prof = s.last_kernel_profile
+    assert prof["meshProgramCache"] == "miss" and prof.get("fusedAggregates")
+    assert not [c for c in EXCHANGE_COUNTERS + ("sortGroupBys",) if c in prof]
+
+
+@pytest.mark.parametrize("profiles, value", [
+    ([{"broadcastExchangeSlots": 100, "groupStateExchangeSlots": 20}], 120),
+    # a warm execution of a cached program carries none: the trace's count
+    ([{"broadcastExchangeSlots": 8, "partitionedExchangeSlots": 4}, {}], 12),
+    # a capacity retrace replaces the rung before it
+    ([{"groupStateExchangeSlots": 5}, {"groupStateExchangeSlots": 9}], 9),
+    ([{"partitionedExchangeSlots": 0}], 0),
+    ([{}, {"sortGroupRows": 7}], None), ([], None)])
+def test_exchange_slots_reader(profiles, value):
+    reader = bench_module("layers", "exchange_slots_per_query")
+    assert reader.read({"setup_profiles": profiles}) == value

@@ -220,3 +220,86 @@ def test_compaction_at_q3_sizes_is_one_sort_for_v5e(
     mem = compiled.memory_analysis()
     assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
             + mem.output_size_in_bytes) < HBM_BYTES
+
+
+def test_mesh_q3_fragment_at_sf10_shards_fits_four_chips(
+        topo, no_persistent_cache, monkeypatch):
+    """The SPMD fragment of `tpch_sf10_mesh4_q3.q3` at its own shard sizes
+    (lineitem 16,777,216 slots a chip, orders 4,194,304, customer 524,288),
+    compiled for the described 2x2 host.  The session plans Q3 at SF 10 on a
+    mesh of the described chips; the generators hand out shapes where they
+    would hand out lanes, and the query stops where it would launch.  Before
+    PR 35 the chip's compiler refused this program: the wide sum stacked its
+    four chunk lanes (n, 4) for one `segment_sum`, which XLA:TPU pads 32x
+    (two buffers of 8 GB: "Used 16.63G of 15.75G hbm")."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from oracle import bench_module
+    from trino_tpu.parallel import mesh_executor as MX
+
+    mesh = Mesh(np.array(topo.devices[:4]), ("workers",))
+    sharded = NamedSharding(mesh, PartitionSpec("workers"))
+
+    class Lane(jax.ShapeDtypeStruct):
+        addressable_shards = ()      # `scanShards` of the kernel profile
+
+    def generator(table, cols, lo, hi, cap, sf, cap_orders, mesh=None):
+        raw = (tpch_device._gen_lineitem(cols, cap_orders, cap, sf)
+               if table == "lineitem"
+               else tpch_device._gen_flat(table, cols, cap, sf))
+        ranges = jax.ShapeDtypeStruct((4,), jnp.int64, sharding=sharded)
+        shapes = jax.eval_shape(
+            tpch_device._per_shard(raw, mesh), ranges, ranges)
+        lanes = jax.tree.map(
+            lambda s: Lane(s.shape, s.dtype, sharding=sharded), shapes)
+        return (lambda lo, hi: lanes), False
+
+    class Launch(Exception):
+        pass
+
+    seen = {}
+
+    class Ctx(MX._MeshTraceCtx):
+        def __init__(self, *a):
+            super().__init__(*a)
+            seen["ctx"] = self
+
+    def compile_only(fn, prep):
+        seen["shapes"] = sorted({
+            lane[0].shape for lanes in prep.values()
+            for sym, lane in lanes.items() if sym != "__count__"})
+        seen["compiled"] = fn.lower(prep).compile()
+        raise Launch()
+
+    monkeypatch.setattr(MX, "default_mesh", lambda n=None: mesh)
+    monkeypatch.setattr(tpch_device, "_generator", generator)
+    monkeypatch.setattr(MX.MeshExecutor, "mesh_trace_ctx_cls", Ctx)
+    monkeypatch.setattr(MX.MeshExecutor, "_compile_fragment",
+                        staticmethod(compile_only))
+    # trace as the chip would (as `_fragments_compiled_for` does)
+    monkeypatch.setattr(pk, "enabled", lambda: True)
+    monkeypatch.setattr(agg_ops, "_use_masked",
+                        lambda cap: cap <= agg_ops._SMALL_SEG_CAP)
+    q3 = bench_module("queries", "q3")
+    s = tpch_session(10.0, distributed=True, num_devices=4,
+                     device_cpu_fallback=False, result_cache=False,
+                     compile_cache=False)
+    with pytest.raises(Launch):
+        s.execute(q3.sql({"segment": "BUILDING", "date": "1995-03-15"}))
+    assert seen["shapes"] == [(4, 524_288), (4, 4_194_304), (4, 16_777_216)]
+    counts = seen["ctx"].op_counts
+    assert counts["broadcastExchanges"] == 2
+    assert counts["broadcastExchangeSlots"] == 4 * (524_288 + 4_194_304)
+    # the plan-time estimate's rung on the first trace: no retrace to come
+    assert counts["groupStateExchangeSlots"] == 4 * 262_144
+    assert counts["sortGroupRows"] == 16_777_216 + 4 * 262_144
+    assert "partitionedExchanges" not in counts
+    mem = seen["compiled"].memory_analysis()   # bytes on each device
+    assert 0 < (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+                + mem.output_size_in_bytes) < HBM_BYTES // 2
+    # the exchanges: the two builds and the partial group state gathered,
+    # overflow flags summed; nothing repartitioned under `automatic`
+    text = seen["compiled"].as_text()
+    assert "all-gather" in text and "all-reduce" in text
+    assert "all-to-all" not in text and "collective-permute" not in text

@@ -418,3 +418,43 @@ def test_wide_union_mixes_lane_forms():
         "select d from u1 intersect select a * b from u2"
     ).to_pylist()
     assert rows == [(D("12.5000"),)]
+
+
+@pytest.mark.parametrize("rows_limit", [0, 1 << 21])
+@pytest.mark.parametrize("wide_rows", [True, False])
+def test_sorted_chunk_sums_equal_python_ints(wide_rows, rows_limit,
+                                             monkeypatch):
+    """`seg_sum_chunks` over sorted group ids: past `_STACKED_CHUNK_ROWS`
+    rows (forced here by a limit of 0) each chunk lane is a prefix sum and
+    a range difference, below it the stacked scatter; both are the exact
+    128-bit sums, negatives and dead rows included."""
+    import jax.numpy as jnp
+
+    from trino_tpu.ops import aggregation as agg_ops
+    from trino_tpu.ops import wide_decimal as wd
+
+    monkeypatch.setattr(wd, "_STACKED_CHUNK_ROWS", rows_limit)
+    rng = random.Random(35)
+    n, cap = 4096, 64
+    gid = sorted(rng.randrange(cap - 3) for _ in range(n))   # empty groups too
+    live = [rng.random() < 0.8 for _ in range(n)]
+    top = 10**30 if wide_rows else 2**62
+    vals = [rng.randrange(-top, top) for _ in range(n)]
+    want = [0] * cap
+    for g, ok, v in zip(gid, live, vals):
+        want[g] += v if ok else 0
+    gid_l = jnp.asarray(np.array(gid, dtype=np.int64))
+    live_l = jnp.asarray(np.array(live))
+    if wide_rows:
+        pairs = [wd.from_python_int(v) for v in vals]
+        lane = wd.make_wide(
+            jnp.asarray(np.array([p[0] for p in pairs], dtype=np.int64)),
+            jnp.asarray(np.array([p[1] for p in pairs], dtype=np.int64)))
+        chunks = wd.wide_row_chunks(lane, live_l)
+    else:
+        chunks = wd.narrow_row_chunks(
+            jnp.asarray(np.array(vals, dtype=np.int64)), live_l)
+    seg = agg_ops.SortedSegments(gid_l, cap)
+    got = wd.chunks_to_wide(wd.seg_sum_chunks(chunks, gid_l, cap, seg=seg))
+    lo, hi = (np.asarray(x) for x in wd.limbs(got))
+    assert wd.to_python_ints(lo, hi, np.ones(cap, bool)) == want

@@ -314,11 +314,15 @@ def dict_fingerprint(dicts: Dict[str, np.ndarray], symbols) -> int:
 
 # which lowering each operator of a traced fragment took (one dictionary
 # update per traced operator; a warm query of a cached program carries
-# none).  Names in PERF.md section 3.
+# none).  Names in PERF.md section 3.  The last five are the mesh's
+# (parallel/mesh_executor): its exchanges, in slots one device receives.
 OP_COUNTERS = (
     "sortGroupBys", "sortGroupRows", "sortGroupCapacity", "directGroupBys",
     "directJoins", "sortJoins", "semiJoins", "lazyDictionaryColumns",
     "compactions", "compactRows", "compactCapacity",
+    "broadcastExchanges", "broadcastExchangeSlots",
+    "partitionedExchanges", "partitionedExchangeSlots",
+    "groupStateExchangeSlots",
 )
 
 
@@ -574,10 +578,7 @@ class LocalExecutor:
             raise exceeded
         try:
             self.dicts = dicts
-            if not self._ladder_start(plan):
-                est = self._estimate_group_capacity(plan, counts)
-                if est is not None:
-                    self.group_capacity = max(self.group_capacity, est)
+            self._ladder_start(plan, counts)
 
             use_jit = (
                 self.config.get("jit_fragments")
@@ -709,10 +710,12 @@ class LocalExecutor:
             elif pool is not None:
                 pool.free(self.query_id, self.scan_bytes)
 
-    def _ladder_start(self, plan) -> bool:
+    def _ladder_start(self, plan, counts) -> None:
         """Set the retry ladder's state for one execution of `plan`: the
         capacities the last execution of it settled on when the session
-        remembers them (True), else the first rung."""
+        remembers them, else the first rung, its group capacity raised to
+        the plan-time estimate over the scans' row `counts` (a rung short
+        is a second trace and compile of the whole fragment)."""
         self.group_capacity = int(
             self.config.get("group_capacity", DEFAULT_GROUP_CAPACITY)
         )
@@ -739,13 +742,15 @@ class LocalExecutor:
         hints = self.config.get("capacity_hints")
         hint = hints.get(id(plan)) if hints is not None else None
         if hint is None:
-            return False
+            est = self._estimate_group_capacity(plan, counts)
+            if est is not None:
+                self.group_capacity = max(self.group_capacity, est)
+            return
         (self.group_capacity, self.join_factor, self.topn_factor,
          self.force_wide_mul, forced, _) = hint[:6]
         self.compact_factor = hint[6] if len(hint) > 6 else 1
         self.force_no_direct = set(hint[7]) if len(hint) > 7 else set()
         self.force_expansion = set(forced)
-        return True
 
     def _ladder_settled(self, dup_nodes, dup_vals, coll_vals, wide_vals,
                         limits, check_vals, sflag_vals) -> bool:
@@ -1911,6 +1916,12 @@ class _TraceCtx:
     def _count(self, name: str, n: int = 1) -> None:
         self.op_counts[name] = self.op_counts.get(name, 0) + int(n)
 
+    def _count_sort_group(self, slots: int, cap: int) -> None:
+        """One `_group_sort` over `slots` input slots at capacity `cap`."""
+        self._count("sortGroupBys")
+        self._count("sortGroupRows", slots)
+        self._count("sortGroupCapacity", cap)
+
     # -- dispatch -------------------------------------------------------
     def visit(self, node: P.PlanNode) -> Batch:
         m = getattr(self, f"_visit_{type(node).__name__.lower()}", None)
@@ -2408,9 +2419,7 @@ class _TraceCtx:
             host_src = (b.lanes, gid, b.sel)
         else:
             cap = min(self.ex.group_capacity, b.sel.shape[0])
-            self._count("sortGroupBys")
-            self._count("sortGroupRows", b.sel.shape[0])
-            self._count("sortGroupCapacity", cap)
+            self._count_sort_group(b.sel.shape[0], cap)
             perm, gid, ngroups = self._group_sort(key_lanes, b.sel, cap)
             self._note_capacity(ngroups, cap)
             sel_sorted = b.sel[perm]

@@ -850,7 +850,7 @@ def accumulate(
                         if wd.is_wide(v)
                         else wd.narrow_row_chunks(v, live)
                     )
-                    cs = wd.seg_sum_chunks(chunks, gid, cap)
+                    cs = wd.seg_sum_chunks(chunks, gid, cap, seg=seg)
                 else:
                     vv = jnp.where(live, v.astype(jnp.int64), 0)
                     ssum = seg_isum(vv)

@@ -342,7 +342,15 @@ def chunks_to_wide(chunks) -> jnp.ndarray:
     return make_wide(lo, hi)
 
 
-def seg_sum_chunks(row_chunks, gid: jnp.ndarray, cap: int):
+# XLA:TPU lays the stacked (n, 4) operand of `seg_sum_chunks`' scatter out
+# with its minor dimension padded to 128 lanes: 1 KiB a row over its two
+# 32-bit planes, 2 GiB at 2^21 rows, and at the 16,777,216 slots of a mesh
+# shard (which is never compacted) two buffers of 8 GB that no chip holds
+# (PR 35).  Past this many rows a sorted caller sums the lanes one by one.
+_STACKED_CHUNK_ROWS = 1 << 21
+
+
+def seg_sum_chunks(row_chunks, gid: jnp.ndarray, cap: int, seg=None):
     """Segment-sum per-row chunk lanes and normalize: the wide SUM
     kernel.  Two-chunk inputs (narrow rows) pad with zero chunks —
     `normalize_chunks`' arithmetic carries sign-extend negatives
@@ -350,14 +358,20 @@ def seg_sum_chunks(row_chunks, gid: jnp.ndarray, cap: int):
 
     Small capacities use the masked-matrix reduction per chunk lane
     (XLA:TPU scatter measured ~16M updates/s vs ~100x that for the
-    masked form at cap<=32 — round-3 micro-benchmark, record deleted in PR 22); large capacities fall
-    back to one stacked (n, k) scatter."""
+    masked form at cap<=32 — round-3 micro-benchmark, record deleted in
+    PR 22); large capacities one stacked (n, k) scatter, while its
+    operand fits (`_STACKED_CHUNK_ROWS`).  Past that, sorted group ids
+    (`seg`, an `aggregation.SortedSegments` over `gid`) sum each chunk
+    lane by a prefix sum and range differences, no scatter: a chunk is
+    under 2^32, so its running sum is exact in int64 below 2^31 rows."""
     from .aggregation import _use_masked
 
     if _use_masked(cap):
         from .aggregation import _seg_sum
 
         sums = [_seg_sum(c, gid, cap) for c in row_chunks]
+    elif seg is not None and gid.shape[0] > _STACKED_CHUNK_ROWS:
+        sums = [seg.sum(c) for c in row_chunks]
     else:
         mat = jnp.stack(row_chunks, axis=1)  # (n, k)
         sums2 = jax.ops.segment_sum(mat, gid, num_segments=cap)

@@ -428,7 +428,10 @@ class MeshExecutor(LocalExecutor):
             self.shuffle_hints = self._skew_shuffle_hints(
                 plan, scans, counts, ndev
             )
-        self._ladder_start(plan)
+        # the estimate is bounded by the tables' rows, not a shard's
+        self._ladder_start(
+            plan, {nid: int(c.sum()) for nid, c in counts.items()}
+        )
         for attempt in range(7):
             self._ladder_attempt = attempt
             out, cell, prep = self._run_sharded(plan, scans, counts)
@@ -1052,6 +1055,7 @@ class _MeshTraceCtx(_TraceCtx):
             # partial aggregate locally; gathering exchange of partial
             # group state; re-merge (PARTIAL -> exchange -> FINAL)
             cap = min(self.ex.group_capacity, b.sel.shape[0])
+            self._count_sort_group(b.sel.shape[0], cap)
             perm, gid, ngroups = self._group_sort(key_lanes, b.sel, cap)
             self._note_capacity(ngroups, cap)
             sel_sorted = b.sel[perm]
@@ -1077,7 +1081,9 @@ class _MeshTraceCtx(_TraceCtx):
             }
             key_lanes_g = [(_agather(v), _agather(ok)) for v, ok in keys_local]
             present_g = _agather(present_local)
+            self._count("groupStateExchangeSlots", present_g.shape[0])
             fcap = min(self.ex.group_capacity, present_g.shape[0])
+            self._count_sort_group(present_g.shape[0], fcap)
             perm2, gid2, ngroups2 = self._group_sort(
                 key_lanes_g, present_g, fcap
             )
@@ -1121,6 +1127,23 @@ class _MeshTraceCtx(_TraceCtx):
 
     def _ndev(self) -> int:
         return self.ex.mesh.devices.size
+
+    # -- exchanges (counted at trace time: exec/local.OP_COUNTERS) ---------
+    def _broadcast(self, build: Batch) -> Batch:
+        """Broadcast exchange of a join's or semi join's build side: every
+        device receives every shard's slots, live or not."""
+        self._count("broadcastExchanges")
+        self._count("broadcastExchangeSlots",
+                    self._ndev() * build.sel.shape[0])
+        return _gather_batch(build)
+
+    def _repartition(self, lanes, sel, bucket, keep, chunk):
+        """Partitioned exchange (`shuffle.repartition`'s all-to-all): every
+        device receives one `chunk` of slots from each device."""
+        ndev = self._ndev()
+        self._count("partitionedExchanges")
+        self._count("partitionedExchangeSlots", ndev * chunk)
+        return shuffle.repartition(lanes, sel, bucket, keep, ndev, chunk, AXIS)
 
     def _psum_accs(self, specs, accs):
         """Cross-device accumulator merge by collective; callers must have
@@ -1174,7 +1197,7 @@ class _MeshTraceCtx(_TraceCtx):
             return self._partitioned_join(node, left, right)
         if not right.replicated:
             # broadcast exchange: replicate build side to all workers
-            right = _gather_batch(right)
+            right = self._broadcast(right)
         out = self._join_batches(node, left, right)
         out.replicated = left.replicated
         return out
@@ -1228,11 +1251,11 @@ class _MeshTraceCtx(_TraceCtx):
                                     factor)
         rchunk = self._hinted_chunk(node, "r", right.sel.shape[0], ndev,
                                     factor)
-        llanes, lsel, lmax = shuffle.repartition(
-            left.lanes, left.sel, lbuck, lkeep, ndev, lchunk, AXIS
+        llanes, lsel, lmax = self._repartition(
+            left.lanes, left.sel, lbuck, lkeep, lchunk
         )
-        rlanes, rsel, rmax = shuffle.repartition(
-            right.lanes, right.sel, rbuck, rkeep, ndev, rchunk, AXIS
+        rlanes, rsel, rmax = self._repartition(
+            right.lanes, right.sel, rbuck, rkeep, rchunk
         )
         self._note_capacity(lmax, lchunk, "join")
         self._note_capacity(rmax, rchunk, "join")
@@ -1256,7 +1279,7 @@ class _MeshTraceCtx(_TraceCtx):
             return self._partitioned_semijoin(node, src, filt)
         if not filt.replicated:
             # broadcast the filtering side (dynamic-filter style exchange)
-            filt = _gather_batch(filt)
+            filt = self._broadcast(filt)
         hit = self._semi_hit(node, src, filt)
         lanes = dict(src.lanes)
         lanes[node.output] = (hit, jnp.ones(hit.shape, bool))
@@ -1300,11 +1323,11 @@ class _MeshTraceCtx(_TraceCtx):
                                     factor)
         fchunk = self._hinted_chunk(node, "r", filt.sel.shape[0], ndev,
                                     factor)
-        slanes, ssel, smax = shuffle.repartition(
-            src.lanes, src.sel, sbuck, src.sel, ndev, schunk, AXIS
+        slanes, ssel, smax = self._repartition(
+            src.lanes, src.sel, sbuck, src.sel, schunk
         )
-        flanes, fsel, fmax = shuffle.repartition(
-            filt.lanes, filt.sel, fbuck, filt.sel & fok, ndev, fchunk, AXIS
+        flanes, fsel, fmax = self._repartition(
+            filt.lanes, filt.sel, fbuck, filt.sel & fok, fchunk
         )
         self._note_capacity(smax, schunk, "join")
         self._note_capacity(fmax, fchunk, "join")
@@ -1346,8 +1369,8 @@ class _MeshTraceCtx(_TraceCtx):
             b.sel.shape[0], ndev, getattr(self.ex, "join_factor", 1),
             quantize=self.ex.ladder.quantize,
         )
-        lanes, sel, mx = shuffle.repartition(
-            b.lanes, b.sel, bucket, b.sel, ndev, chunk, AXIS
+        lanes, sel, mx = self._repartition(
+            b.lanes, b.sel, bucket, b.sel, chunk
         )
         self._note_capacity(mx, chunk, "join")
         return Batch(lanes, sel, replicated=False)
@@ -1405,8 +1428,8 @@ class _MeshTraceCtx(_TraceCtx):
             b.sel.shape[0], ndev, getattr(self.ex, "join_factor", 1),
             quantize=self.ex.ladder.quantize,
         )
-        lanes, sel, mx = shuffle.repartition(
-            b.lanes, b.sel, bucket, b.sel, ndev, chunk, AXIS
+        lanes, sel, mx = self._repartition(
+            b.lanes, b.sel, bucket, b.sel, chunk
         )
         self._note_capacity(mx, chunk, "join")
         b2 = Batch(lanes, sel, replicated=False)
@@ -1532,8 +1555,8 @@ class _MeshTraceCtx(_TraceCtx):
             sel.shape[0], ndev, getattr(self.ex, "join_factor", 1),
             quantize=self.ex.ladder.quantize,
         )
-        lanes2, sel2, mx = shuffle.repartition(
-            all_lanes, sel, bucket, keep, ndev, chunk, AXIS
+        lanes2, sel2, mx = self._repartition(
+            all_lanes, sel, bucket, keep, chunk
         )
         self._note_capacity(mx, chunk, "join")
         tag2, _ = lanes2.pop("__tag__")
