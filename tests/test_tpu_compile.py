@@ -8,6 +8,7 @@ one process at a time may load the TPU library, and the topology is
 described inside a fixture so every xdist worker collects the same tests.
 """
 import contextlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -222,6 +223,47 @@ def test_compaction_at_q3_sizes_is_one_sort_for_v5e(
             + mem.output_size_in_bytes) < HBM_BYTES
 
 
+def _scatter_kinds(text):
+    """The jax primitives (`scatter-add`, `scatter-min`, ...) behind the
+    scatter instructions of a compiled program, from their metadata: an
+    int64 combiner is split into 32-bit compares, its name is not."""
+    return set(re.findall(
+        r" scatter\(.*op_name=\"[^\"]*/(scatter[-\w]*)\"", text))
+
+
+def test_two_arbitraries_at_q3_sizes_read_sorted_runs_for_v5e(
+        one_chip, no_persistent_cache):
+    """`accumulate(..., seg=)` at `tpch_sf1.q3`'s own sizes (1,048,576
+    group-by slots, `cap` 262,144): Q3's two `arbitrary`s of its
+    functionally dependent group keys read each group's first live row off
+    the sorted run, one reversed `cummin` and a `cap`-sized gather each.
+    The `_seg_min` of row ids they replaced compiled to one `scatter`
+    fusion each (96 + 73 ms a query on the chip; 1,530 each at the mesh's
+    16,777,216 slots)."""
+    n, cap = 1_048_576, 262_144
+    specs = [agg_ops.AggSpec("arbitrary", "o_orderdate", "d"),
+             agg_ops.AggSpec("arbitrary", "o_shippriority", "p")]
+
+    def picks(gid, sel, date, dok, prio, pok, by_run):
+        seg = agg_ops.SortedSegments(gid, cap) if by_run else None
+        lanes = {"o_orderdate": (date, dok), "o_shippriority": (prio, pok)}
+        return agg_ops.accumulate(specs, lanes, gid, sel, cap, seg=seg)
+
+    def sds(dtype):
+        return jax.ShapeDtypeStruct((n,), dtype, sharding=one_chip)
+
+    args = (sds(jnp.int64), sds(jnp.bool_), sds(jnp.int32), sds(jnp.bool_),
+            sds(jnp.int32), sds(jnp.bool_))
+    texts = {
+        by_run: jax.jit(picks, static_argnums=6).lower(*args, by_run)
+        .compile().as_text() for by_run in (True, False)}
+    # (function names ride in the metadata: none here may say "scatter")
+    assert "scatter" not in texts[True]
+    assert "reduce-window" in texts[True]
+    # without the runs (direct-domain and global group-bys) it stays
+    assert "scatter" in texts[False]
+
+
 def test_mesh_q3_fragment_at_sf10_shards_fits_four_chips(
         topo, no_persistent_cache, monkeypatch):
     """The SPMD fragment of `tpch_sf10_mesh4_q3.q3` at its own shard sizes
@@ -295,6 +337,9 @@ def test_mesh_q3_fragment_at_sf10_shards_fits_four_chips(
     assert counts["groupStateExchangeSlots"] == 4 * 262_144
     assert counts["sortGroupRows"] == 16_777_216 + 4 * 262_144
     assert "partitionedExchanges" not in counts
+    # both steps' `arbitrary`s and the final keys read their sorted runs
+    assert counts["sortedFirstRows"] == 2 + 2 + 1
+    assert "scatterFirstRows" not in counts
     mem = seen["compiled"].memory_analysis()   # bytes on each device
     assert 0 < (mem.temp_size_in_bytes + mem.argument_size_in_bytes
                 + mem.output_size_in_bytes) < HBM_BYTES // 2
@@ -303,3 +348,8 @@ def test_mesh_q3_fragment_at_sf10_shards_fits_four_chips(
     text = seen["compiled"].as_text()
     assert "all-gather" in text and "all-reduce" in text
     assert "all-to-all" not in text and "collective-permute" not in text
+    # no first-row pick is a scatter any more (two of 16,777,216 updates
+    # were 29 % of the query); `build_direct`'s scatters combine by
+    # `maximum` (`scatter-max`), the final step's sums add: they stay
+    kinds = _scatter_kinds(text)
+    assert "scatter-max" in kinds and "scatter-min" not in kinds

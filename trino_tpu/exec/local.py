@@ -320,6 +320,7 @@ OP_COUNTERS = (
     "sortGroupBys", "sortGroupRows", "sortGroupCapacity", "directGroupBys",
     "directJoins", "sortJoins", "semiJoins", "lazyDictionaryColumns",
     "compactions", "compactRows", "compactCapacity",
+    "sortedFirstRows", "scatterFirstRows",
     "broadcastExchanges", "broadcastExchangeSlots",
     "partitionedExchanges", "partitionedExchangeSlots",
     "groupStateExchangeSlots",
@@ -1922,6 +1923,18 @@ class _TraceCtx:
         self._count("sortGroupRows", slots)
         self._count("sortGroupCapacity", cap)
 
+    def _count_first_rows(self, specs, sorted_run: bool,
+                          final_keys: bool = False) -> None:
+        """The first-row picks of one aggregate step: each `arbitrary`,
+        and a FINAL step's group keys (one pick for all key lanes).  Read
+        off the sorted run (`SortedSegments.first`, run heads) or served
+        by a `_seg_min` of row ids."""
+        n = sum(s.kind == "arbitrary" for s in specs) + int(final_keys)
+        if n:
+            self._count(
+                "sortedFirstRows" if sorted_run else "scatterFirstRows", n
+            )
+
     # -- dispatch -------------------------------------------------------
     def visit(self, node: P.PlanNode) -> Batch:
         m = getattr(self, f"_visit_{type(node).__name__.lower()}", None)
@@ -2365,6 +2378,9 @@ class _TraceCtx:
             )
 
         def reduce_rows(lanes, gid, sel, cap, seg=None):
+            self._count_first_rows(
+                specs, seg is not None, final and bool(node.keys)
+            )
             if final:
                 acc_in = {
                     n: lanes[n] for s in specs for n in s.accumulator_names
@@ -2372,6 +2388,7 @@ class _TraceCtx:
                 return agg_ops.merge_accumulators(
                     specs, acc_in, gid, sel, cap,
                     overflow_flags=self.sum_overflow,
+                    seg=seg,
                 )
             return agg_ops.accumulate(
                 specs, lanes, gid, sel, cap,

@@ -671,6 +671,30 @@ def _moment_sums(v, live, gid, cap, in_t):
     )
 
 
+_SCAN_BLOCK = 1024
+
+
+def _suffix_min(v: jnp.ndarray) -> jnp.ndarray:
+    """`lax.cummin(v, reverse=True)` of a 1-D integer lane, by blocks: the
+    suffix minimum inside each block of `_SCAN_BLOCK`, and under it the
+    (recursive) suffix minimum of the blocks that follow.  The same
+    numbers; the one-pass form is XLA:TPU's to compile, and over 2^20 or
+    2^21 int32 elements that takes it 35-42 s (7-10 s at 2^22 and 2^24;
+    compiled for a described v5e), the blocked form under a second."""
+    n = v.shape[0]
+    if n <= _SCAN_BLOCK:
+        return jax.lax.cummin(v, reverse=True)
+    big = jnp.iinfo(v.dtype).max
+    blocks = jnp.pad(
+        v, (0, -n % _SCAN_BLOCK), constant_values=big
+    ).reshape(-1, _SCAN_BLOCK)
+    within = jax.lax.cummin(blocks, axis=1, reverse=True)
+    after = jnp.concatenate(
+        [_suffix_min(within[:, 0])[1:], jnp.full(1, big, v.dtype)]
+    )
+    return jnp.minimum(within, after[:, None]).reshape(-1)[:n]
+
+
 class SortedSegments:
     """Scatter-free grouped reductions over a SORTED gid lane (the
     hash-sort grouping path: rows arrive permuted so equal groups are
@@ -733,11 +757,37 @@ class SortedSegments:
     def max(self, v: jnp.ndarray) -> jnp.ndarray:
         return self._scan_extreme(v, False)
 
+    def first(self, live: jnp.ndarray):
+        """Each group's first row at which `live` holds, as `(row, has)`:
+        the row `_seg_min` of the masked row ids picks, with no scatter.
+        The suffix minimum of the masked ids is monotone across runs, so
+        no segmented scan is needed: read it at each run's start, and the
+        run has a live row iff that row lies before the run's end.  Dead
+        rows share gid cap-1 but sort last (sort_group_ids), so a real
+        last group still reads its own first live row."""
+        n = self.n
+        suf = _suffix_min(jnp.where(live, row_ids(n), n))
+        row = jnp.concatenate([suf, jnp.full(1, n, suf.dtype)])[self.starts]
+        return row, row < self.ends
+
+
+def _first_live_row(live, gid, cap, seg: Optional[SortedSegments]):
+    """Per group the first row at which `live` holds, as `(row, has)`: off
+    the sorted run where `seg` is given, else (direct-domain and global
+    group-bys, unsorted callers) by a scatter-min of the masked row ids."""
+    if seg is not None:
+        return seg.first(live)
+    n = gid.shape[0]
+    ridx = _seg_min(
+        jnp.where(live, jnp.arange(n, dtype=jnp.int64), n), gid, cap
+    )
+    return ridx, ridx < n
+
 
 # aggregate kinds the SortedSegments fast path covers; others fall back
 # to the generic segment ops
 SORTED_FAST_KINDS = ("sum", "avg", "count", "count_star", "count_if",
-                     "min", "max")
+                     "min", "max", "arbitrary")
 
 
 def accumulate(
@@ -944,12 +994,8 @@ def accumulate(
             out[f"{o}$val"] = _seg_sum(jnp.where(sel, addend, 0), gid, cap)
             out[f"{o}$valid"] = _seg_count(sel, gid, cap)
         elif s.kind == "arbitrary":
-            n = gid.shape[0]
-            ridx = _seg_min(
-                jnp.where(live, jnp.arange(n, dtype=jnp.int64), n), gid, cap
-            )
-            has = ridx < n
-            safe = jnp.clip(ridx, 0, n - 1)
+            ridx, has = _first_live_row(live, gid, cap, seg)
+            safe = jnp.clip(ridx, 0, gid.shape[0] - 1)
             out[f"{o}$val"] = jnp.where(has, v[safe], jnp.zeros_like(v[safe]))
             out[f"{o}$valid"] = has.astype(jnp.int64)
         elif s.kind in ("min_by", "max_by"):
@@ -1017,8 +1063,10 @@ def merge_accumulators(
     sel: jnp.ndarray,
     capacity: int,
     overflow_flags: Optional[list] = None,
+    seg: Optional["SortedSegments"] = None,
 ) -> Dict[str, jnp.ndarray]:
-    """FINAL step: merge partial accumulator rows grouped by gid."""
+    """FINAL step: merge partial accumulator rows grouped by gid.  With
+    `seg` (gid sorted), `arbitrary` reads its row off the sorted run."""
     out: Dict[str, jnp.ndarray] = {}
     cap = capacity
     w = sel
@@ -1117,8 +1165,8 @@ def merge_accumulators(
             else:
                 sentinel = I64_MAX if s.kind == "min" else -I64_MAX
             vv = jnp.where(has, sv, sentinel)
-            seg = _seg_min if s.kind == "min" else _seg_max
-            out[f"{o}$val"] = seg(vv, gid, cap)
+            ext = _seg_min if s.kind == "min" else _seg_max
+            out[f"{o}$val"] = ext(vv, gid, cap)
             out[f"{o}$valid"] = _seg_sum(jnp.where(w, cv, 0), gid, cap)
         elif s.kind in ("bool_and", "bool_or"):
             sv, _ = acc_lanes[f"{o}$val"]
@@ -1146,12 +1194,8 @@ def merge_accumulators(
             sv, _ = acc_lanes[f"{o}$val"]
             cv, _ = acc_lanes[f"{o}$valid"]
             has = w & (cv > 0)
-            n = gid.shape[0]
-            ridx = _seg_min(
-                jnp.where(has, jnp.arange(n, dtype=jnp.int64), n), gid, cap
-            )
-            ok2 = ridx < n
-            safe = jnp.clip(ridx, 0, n - 1)
+            ridx, ok2 = _first_live_row(has, gid, cap, seg)
+            safe = jnp.clip(ridx, 0, gid.shape[0] - 1)
             out[f"{o}$val"] = jnp.where(ok2, sv[safe], jnp.zeros_like(sv[safe]))
             out[f"{o}$valid"] = ok2.astype(jnp.int64)
         elif s.kind in ("min_by", "max_by"):
@@ -1366,10 +1410,7 @@ def group_keys_output(
         for v, ok in key_lanes:
             out.append((v[safe], ok[safe] & present & sel[safe]))
         return out
-    first = _seg_min(
-        jnp.where(sel, jnp.arange(n, dtype=jnp.int64), n), gid, capacity
-    )
-    present = first < n
+    first, present = _first_live_row(sel, gid, capacity, None)
     safe = jnp.clip(first, 0, n - 1)
     out = []
     for v, ok in key_lanes:

@@ -1063,6 +1063,7 @@ class _MeshTraceCtx(_TraceCtx):
 
             sorted_lanes = permute_lanes(b.lanes, perm)
             ss = agg_ops.SortedSegments(gid, cap)
+            self._count_first_rows(specs, True)
             accs = agg_ops.accumulate(
                 specs, sorted_lanes, gid, sel_sorted, cap, step="partial",
                 overflow_flags=self.sum_overflow,
@@ -1092,9 +1093,14 @@ class _MeshTraceCtx(_TraceCtx):
             acc_sorted = {
                 s: (v[perm2], ok[perm2]) for s, (v, ok) in acc_lanes.items()
             }
+            # gid2 is sorted too: `arbitrary` and the final keys read
+            # their rows off its runs (sums still merge by scatter)
+            ss2 = agg_ops.SortedSegments(gid2, fcap)
+            self._count_first_rows(specs, True, final_keys=True)
             merged = agg_ops.merge_accumulators(
                 specs, acc_sorted, gid2, sel2, fcap,
                 overflow_flags=self.sum_overflow,
+                seg=ss2,
             )
             out = agg_ops.finalize(specs, merged)
             keys_out = agg_ops.group_keys_output(
@@ -1102,6 +1108,7 @@ class _MeshTraceCtx(_TraceCtx):
                 gid2,
                 sel2,
                 fcap,
+                starts=ss2.starts,
             )
             present = jnp.arange(fcap) < ngroups2
             cap = fcap
