@@ -169,6 +169,8 @@ def check_tree(root: str):
          "trino_tpu.connectors.lakehouse", "SNAPSHOT_FIELDS"),
         ("trino_tpu/distributed/topology.py",
          "trino_tpu.distributed.topology", "TOPOLOGY_FIELDS"),
+        ("trino_tpu/obs/program_census.py",
+         "trino_tpu.obs.program_census", "CENSUS_FIELDS"),
     )
     for rel, mod, attr in field_schemas:
         try:

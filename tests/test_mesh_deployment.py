@@ -329,14 +329,16 @@ def _shard_cap(prof, column):
     ("broadcastExchanges", 2),
     ("partitionedExchanges", 0), ("partitionedExchangeSlots", 0),
     ("sortGroupBys", 2), ("directJoins", 2), ("compactions", 0),
-    # first-row picks off the sorted runs: `arbitrary(o_orderdate)` and
-    # `arbitrary(o_shippriority)` in each step, and the final step's keys
-    ("sortedFirstRows", 2 + 2 + 1), ("scatterFirstRows", 0)])
+    # the compiled program's census: a `build_direct` scatter a join and the
+    # sums' six; `arbitrary(o_orderdate)`, `arbitrary(o_shippriority)` and
+    # the final step's keys read their rows off the sorted runs (a
+    # `scatter-min` each before PR 36: 13)
+    ("scatters", 8), ("sorts", 12)])
 def test_q3_counts_two_broadcast_joins_and_a_two_step_group_by(
         q3_traced, counter, expected):
     rows, correct, prof = q3_traced["automatic"]
     assert correct, rows[:3]
-    assert prof.get(counter, 0) == expected
+    assert dict(prof["programCensus"], **prof).get(counter, 0) == expected
 
 
 def test_one_chip_q3_counts_its_two_arbitraries_and_answers_as_the_mesh(
@@ -346,8 +348,9 @@ def test_one_chip_q3_counts_its_two_arbitraries_and_answers_as_the_mesh(
                      compile_cache=False)
     rows = s.execute(sql).to_pylist()
     prof = s.last_kernel_profile
-    # one step: no final keys to pick
-    assert prof["sortedFirstRows"] == 2 and "scatterFirstRows" not in prof
+    # its two `arbitrary`s read the sorted run: the scatters left are the
+    # joins' `build_direct` and the wide sum's
+    assert prof["programCensus"]["scatters"] == 3
     assert rows == q3_traced["automatic"][0]
 
 
@@ -382,8 +385,8 @@ def test_fused_q1_on_the_mesh_carries_no_exchange_or_sort_counter():
     assert s.execute(sql).to_pylist()
     prof = s.last_kernel_profile
     assert prof["meshProgramCache"] == "miss" and prof.get("fusedAggregates")
-    assert not [c for c in EXCHANGE_COUNTERS + (
-        "sortGroupBys", "sortedFirstRows", "scatterFirstRows") if c in prof]
+    assert not [c for c in EXCHANGE_COUNTERS + ("sortGroupBys",)
+                if c in prof]
 
 
 @pytest.mark.parametrize("profiles, value", [

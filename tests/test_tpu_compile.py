@@ -19,6 +19,7 @@ from tpch_sql import QUERIES
 from trino_tpu.connectors import tpch_device
 from trino_tpu.exec.local import LocalExecutor
 from trino_tpu.exec.shapes import resolve_ladder
+from trino_tpu.obs import program_census
 from trino_tpu.ops import aggregation as agg_ops
 from trino_tpu.ops import pallas_kernels as pk
 from trino_tpu.session import tpch_session
@@ -337,9 +338,14 @@ def test_mesh_q3_fragment_at_sf10_shards_fits_four_chips(
     assert counts["groupStateExchangeSlots"] == 4 * 262_144
     assert counts["sortGroupRows"] == 16_777_216 + 4 * 262_144
     assert "partitionedExchanges" not in counts
-    # both steps' `arbitrary`s and the final keys read their sorted runs
-    assert counts["sortedFirstRows"] == 2 + 2 + 1
-    assert "scatterFirstRows" not in counts
+    # both steps' `arbitrary`s and the final keys read their sorted runs:
+    # the census of the program compiled for the chip has PR 36's hand
+    # counts (no `scatter-min` left: 12 scatters and 9 sorts before)
+    census = program_census.census(seen["compiled"])
+    assert (census["scatters"], census["sorts"]) == (7, 13)
+    assert census["tempBytes"] == (
+        seen["compiled"].memory_analysis().temp_size_in_bytes)
+    assert census["scopedInstructions"] > 0 and census["collectives"] > 0
     mem = seen["compiled"].memory_analysis()   # bytes on each device
     assert 0 < (mem.temp_size_in_bytes + mem.argument_size_in_bytes
                 + mem.output_size_in_bytes) < HBM_BYTES // 2

@@ -316,7 +316,7 @@ def _named_jit(fn, table: str):
     profile's `XLA Modules` line and idle-gap labels show for it."""
     fn.__name__ = fn.__qualname__ = "devgen_" + table
     # no-donate: generator args are two scalars (lo, hi); lanes are outputs
-    return jax.jit(fn)
+    return jax.jit(jax.named_scope(fn.__name__)(fn))
 
 
 def _per_shard(fn, mesh):

@@ -320,7 +320,6 @@ OP_COUNTERS = (
     "sortGroupBys", "sortGroupRows", "sortGroupCapacity", "directGroupBys",
     "directJoins", "sortJoins", "semiJoins", "lazyDictionaryColumns",
     "compactions", "compactRows", "compactCapacity",
-    "sortedFirstRows", "scatterFirstRows",
     "broadcastExchanges", "broadcastExchangeSlots",
     "partitionedExchanges", "partitionedExchangeSlots",
     "groupStateExchangeSlots",
@@ -1783,6 +1782,7 @@ class LocalExecutor:
                 # sort program) and is not a wedged device.  Only the
                 # execution of the finished executable is supervised.
                 fn = self._compile_fragment(fn, resident_prep, tile_prep)
+                census = self._program_census(fn, digest)
                 compile_s = time.time() - compile_start
                 with TRACER.span("launch"):
                     out = self._dispatch(
@@ -1804,7 +1804,7 @@ class LocalExecutor:
             self._note_op_counts(cell["op_counts"])
             cell["dicts"] = dict(self.dicts)
             # the plan reference pins id(plan) (fingerprint memo validity)
-            entry = {"fn": fn, "cell": cell, "plan": plan}
+            entry = {"fn": fn, "cell": cell, "plan": plan, "census": census}
             cache[key] = entry
         else:
             cell = entry["cell"]
@@ -1818,6 +1818,8 @@ class LocalExecutor:
                     lambda: entry["fn"](resident_prep, tile_prep), bc
                 )
             self._record_kernel(digest, compile_s=0.0, cached=True)
+        # one object for the compile and for every warm query of the entry
+        self.kernel_profile["programCensus"] = entry["census"]
         out_lanes, sel, ngroups, dup_vals, colls, wides, sflags = out
         checks = [
             (ng, cap, kind)
@@ -1839,6 +1841,18 @@ class LocalExecutor:
         prof.update(op_counts)
 
     @staticmethod
+    def _program_census(compiled, digest: str) -> dict:
+        """The census of the program just compiled (obs/program_census);
+        the caller keeps it on the cache entry and puts it into the kernel
+        profile of every query that runs the entry."""
+        from ..obs import program_census
+
+        with TRACER.span("program_census", fragment=digest):
+            census = program_census.census(compiled)
+        census["fragment"] = digest
+        return census
+
+    @staticmethod
     def _compile_fragment(fn, *args):
         """Ahead-of-time trace + compile of one jitted program for the
         concrete `args`; returns the executable.  Kept apart from the
@@ -1847,6 +1861,9 @@ class LocalExecutor:
 
     # ------------------------------------------------------------------
     def _run(self, plan: P.Output, ctx: "_TraceCtx"):
+        from ..cache.compile_cache import plan_ordinals
+
+        ctx.ordinals = plan_ordinals(plan)[0]
         batch = ctx.visit(plan.source)
         out = {s: batch.lanes[s] for s in plan.symbols}
         return out, batch.sel, batch.ordered, ctx.capacity_checks
@@ -1911,6 +1928,8 @@ class _TraceCtx:
         # which lowering each operator of THIS trace took (OP_COUNTERS);
         # the executor copies them into its kernel profile after the trace
         self.op_counts: Dict[str, int] = {}
+        # id(plan node) -> pre-order ordinal in the fragment (`_run`)
+        self.ordinals: Dict[int, int] = {}
         self.lowering = LoweringContext(ex.dicts)
         self.lowering.force_wide_mul = getattr(ex, 'force_wide_mul', False)
 
@@ -1923,63 +1942,62 @@ class _TraceCtx:
         self._count("sortGroupRows", slots)
         self._count("sortGroupCapacity", cap)
 
-    def _count_first_rows(self, specs, sorted_run: bool,
-                          final_keys: bool = False) -> None:
-        """The first-row picks of one aggregate step: each `arbitrary`,
-        and a FINAL step's group keys (one pick for all key lanes).  Read
-        off the sorted run (`SortedSegments.first`, run heads) or served
-        by a `_seg_min` of row ids."""
-        n = sum(s.kind == "arbitrary" for s in specs) + int(final_keys)
-        if n:
-            self._count(
-                "sortedFirstRows" if sorted_run else "scatterFirstRows", n
-            )
-
     # -- dispatch -------------------------------------------------------
-    def visit(self, node: P.PlanNode) -> Batch:
-        m = getattr(self, f"_visit_{type(node).__name__.lower()}", None)
-        if m is None:
-            raise ExecutionError(f"no executor for {type(node).__name__}")
-        if not self.ex.config.get("collect_node_stats"):
-            return m(node)
-        # EXPLAIN ANALYZE instrumentation (OperatorContext timing analog);
-        # wall time is inclusive of children — the printer (and
-        # obs/opstats.frames_from_plan) subtracts.  The dispatch-to-sync
-        # split approximates host (trace + dispatch) vs device (waiting
-        # on the computation) wall in eager mode.
-        import time as _time
+    # the eager per-node probes concretize row counts (int(jnp.sum(sel))),
+    # which no trace inside shard_map can: _MeshTraceCtx turns them off
+    node_probes = True
 
-        t0 = _time.perf_counter()
-        b = m(node)
-        t1 = _time.perf_counter()
-        # EXPLAIN ANALYZE timing sync; runs inside the supervised eager
-        # dispatch, so it is already covered by the boundary
-        jax.block_until_ready((b.sel,))  # dispatch-guard: ok
-        t2 = _time.perf_counter()
-        st = self.ex.node_stats.setdefault(
-            id(node),
-            {"rows": 0, "bytes": 0, "wall_s": 0.0,
-             "device_wall_s": 0.0, "calls": 0},
-        )
-        rows = int(jnp.sum(b.sel))
-        cap = int(b.sel.shape[0]) if getattr(b.sel, "shape", None) else 0
-        lane_bytes = 0
-        for v in b.lanes.values():
-            parts = v if isinstance(v, tuple) else (v,)
-            lane_bytes += sum(
-                int(getattr(p, "nbytes", 0))
-                for p in parts if p is not None
+    def visit(self, node: P.PlanNode) -> Batch:
+        name = type(node).__name__
+        m = getattr(self, f"_visit_{name.lower()}", None)
+        if m is None:
+            raise ExecutionError(f"no executor for {name}")
+        # the operator's name in the compiled program's metadata
+        # (obs/program_census): its type and its pre-order position in
+        # the fragment, the same in every process that traces this text
+        o = self.ordinals.get(id(node))
+        with jax.named_scope(name if o is None else "%s#%d" % (name, o)):
+            if not (self.node_probes
+                    and self.ex.config.get("collect_node_stats")):
+                return m(node)
+            # EXPLAIN ANALYZE instrumentation (OperatorContext timing analog);
+            # wall time is inclusive of children — the printer (and
+            # obs/opstats.frames_from_plan) subtracts.  The dispatch-to-sync
+            # split approximates host (trace + dispatch) vs device (waiting
+            # on the computation) wall in eager mode.
+            import time as _time
+
+            t0 = _time.perf_counter()
+            b = m(node)
+            t1 = _time.perf_counter()
+            # EXPLAIN ANALYZE timing sync; runs inside the supervised eager
+            # dispatch, so it is already covered by the boundary
+            jax.block_until_ready((b.sel,))  # dispatch-guard: ok
+            t2 = _time.perf_counter()
+            st = self.ex.node_stats.setdefault(
+                id(node),
+                {"rows": 0, "bytes": 0, "wall_s": 0.0,
+                 "device_wall_s": 0.0, "calls": 0},
             )
-        st["rows"] = rows
-        # logical (unpadded) bytes: padded lane footprint scaled by the
-        # live-row fraction, matching rows x width hand-computation
-        st["bytes"] = (
-            int(lane_bytes * rows / cap) if cap else lane_bytes
-        )
-        st["wall_s"] += t2 - t0
-        st["device_wall_s"] = st.get("device_wall_s", 0.0) + (t2 - t1)
-        st["calls"] += 1
-        return b
+            rows = int(jnp.sum(b.sel))
+            cap = int(b.sel.shape[0]) if getattr(b.sel, "shape", None) else 0
+            lane_bytes = 0
+            for v in b.lanes.values():
+                parts = v if isinstance(v, tuple) else (v,)
+                lane_bytes += sum(
+                    int(getattr(p, "nbytes", 0))
+                    for p in parts if p is not None
+                )
+            st["rows"] = rows
+            # logical (unpadded) bytes: padded lane footprint scaled by the
+            # live-row fraction, matching rows x width hand-computation
+            st["bytes"] = (
+                int(lane_bytes * rows / cap) if cap else lane_bytes
+            )
+            st["wall_s"] += t2 - t0
+            st["device_wall_s"] = st.get("device_wall_s", 0.0) + (t2 - t1)
+            st["calls"] += 1
+            return b
 
     # -- leaves ---------------------------------------------------------
     def _visit_tablescan(self, node: P.TableScan) -> Batch:
@@ -2052,6 +2070,7 @@ class _TraceCtx:
     # mesh shards see 1/ndev of the rows, so _MeshTraceCtx disables this
     allow_compaction = True
 
+    @jax.named_scope("_maybe_compact")
     def _maybe_compact(self, b: Batch, node) -> Batch:
         """Tighten survivors into a smaller static capacity (the
         optimizer's compact_rows estimate, grown by the ladder's
@@ -2378,9 +2397,6 @@ class _TraceCtx:
             )
 
         def reduce_rows(lanes, gid, sel, cap, seg=None):
-            self._count_first_rows(
-                specs, seg is not None, final and bool(node.keys)
-            )
             if final:
                 acc_in = {
                     n: lanes[n] for s in specs for n in s.accumulator_names
@@ -2864,6 +2880,7 @@ class _TraceCtx:
         lanes[node.output] = (hit, jnp.ones(hit.shape, bool))
         return Batch(lanes, src.sel, src.ordered, src.replicated)
 
+    @jax.named_scope("_semi_hit")
     def _semi_hit(self, node: P.SemiJoin, src: Batch, filt: Batch):
         """Membership mark; duplicates in the filtering side are fine
         (sorted search, any match counts).  Single-column keys compare the

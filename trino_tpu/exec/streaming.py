@@ -263,6 +263,13 @@ def _merge_tile_counters(executor, fe) -> None:
         executor.kernel_profile["lastFusionReject"] = (
             prof["lastFusionReject"]
         )
+    census = prof.get("programCensus")
+    if census is not None:
+        from ..obs import program_census
+
+        executor.kernel_profile["programCensus"] = program_census.merge(
+            executor.kernel_profile.get("programCensus"), census
+        )
 
 
 def plan_streaming(executor, plan: P.Output, memory_limit: int,

@@ -355,6 +355,7 @@ def _group_hash(key_lanes: Sequence[Lane], salt: int) -> jnp.ndarray:
     return (h % jnp.uint64(2**61)).astype(jnp.int64)
 
 
+@jax.named_scope("sort_group_ids")
 def sort_group_ids(
     key_lanes: Sequence[Lane],
     sel: jnp.ndarray,
@@ -674,6 +675,7 @@ def _moment_sums(v, live, gid, cap, in_t):
 _SCAN_BLOCK = 1024
 
 
+@jax.named_scope("_suffix_min")
 def _suffix_min(v: jnp.ndarray) -> jnp.ndarray:
     """`lax.cummin(v, reverse=True)` of a 1-D integer lane, by blocks: the
     suffix minimum inside each block of `_SCAN_BLOCK`, and under it the
@@ -729,6 +731,7 @@ class SortedSegments:
         cs0 = jnp.concatenate([zero, cs])  # cs0[i] = sum of rows < i
         return cs0[self.ends] - cs0[self.starts]
 
+    @jax.named_scope("SortedSegments.sum")
     def sum(self, v: jnp.ndarray) -> jnp.ndarray:
         return self._range_diff(jnp.cumsum(v))
 
@@ -757,6 +760,7 @@ class SortedSegments:
     def max(self, v: jnp.ndarray) -> jnp.ndarray:
         return self._scan_extreme(v, False)
 
+    @jax.named_scope("SortedSegments.first")
     def first(self, live: jnp.ndarray):
         """Each group's first row at which `live` holds, as `(row, has)`:
         the row `_seg_min` of the masked row ids picks, with no scatter.
@@ -790,6 +794,7 @@ SORTED_FAST_KINDS = ("sum", "avg", "count", "count_star", "count_if",
                      "min", "max", "arbitrary")
 
 
+@jax.named_scope("accumulate")
 def accumulate(
     specs: Sequence[AggSpec],
     lanes: Dict[str, Lane],
@@ -1056,6 +1061,7 @@ def _merge_wide_chunks(s, acc_lanes, w, gid, cap, out):
         out[f"{o}$c{i}"] = c
 
 
+@jax.named_scope("merge_accumulators")
 def merge_accumulators(
     specs: Sequence[AggSpec],
     acc_lanes: Dict[str, Lane],

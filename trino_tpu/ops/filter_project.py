@@ -44,6 +44,7 @@ def compile_filter_project(
     return apply
 
 
+@jax.named_scope("compact_indices")
 def compact_indices(sel: jnp.ndarray, cap: int) -> jnp.ndarray:
     """The first `cap` selected row numbers in row order, fill value 0:
     what jax's `nonzero(sel, size=cap, fill_value=0)` returns, without
@@ -62,6 +63,7 @@ def compact_indices(sel: jnp.ndarray, cap: int) -> jnp.ndarray:
     return jnp.where(key < n, key, 0)
 
 
+@jax.named_scope("permute_lanes")
 def permute_lanes(
     lanes: Dict[str, Lane], idx: jnp.ndarray, extra_ok=None
 ) -> Dict[str, Lane]:

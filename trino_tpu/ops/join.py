@@ -98,6 +98,7 @@ class LookupSource(NamedTuple):
     dup_count: jnp.ndarray  # scalar: number of duplicate keys (0 required)
 
 
+@jax.named_scope("build_unique")
 def build_unique(key: Lane, sel: jnp.ndarray) -> LookupSource:
     """Sort build rows by key; unselected/null rows sort to the end."""
     v, ok = key
@@ -157,6 +158,7 @@ class DirectLookupSource(NamedTuple):
     violations: jnp.ndarray  # scalar: live build keys outside the domain
 
 
+@jax.named_scope("build_direct")
 def build_direct(key: Lane, sel: jnp.ndarray, lo: int, domain: int
                  ) -> DirectLookupSource:
     v, ok = key
@@ -183,6 +185,7 @@ def build_direct(key: Lane, sel: jnp.ndarray, lo: int, domain: int
     return DirectLookupSource(table, lo, viol + dups)
 
 
+@jax.named_scope("probe_direct")
 def probe_direct(
     source: DirectLookupSource, key: Lane, sel: jnp.ndarray
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -250,6 +253,7 @@ def probe_counts(
     return counts, lo
 
 
+@jax.named_scope("expand_join_slots")
 def expand_join_slots(
     source: MultiLookupSource,
     counts: jnp.ndarray,

@@ -558,9 +558,11 @@ def _run(ctx, node: P.Aggregate):
     assert not (interpret and jax.default_backend() == "tpu"), (
         "fused megakernel would run in pallas interpret mode on a TPU"
     )
-    sums = pk.fused_agg_sums(
-        cols32, live, emit, n_terms, cap, interpret=interpret,
-    )
+    kernel = "megakernel:%s/t%d/g%d" % (scan.table, n_terms, cap)
+    with jax.named_scope(kernel):
+        sums = pk.fused_agg_sums(
+            cols32, live, emit, n_terms, cap, interpret=interpret,
+        )
     # mesh shard bodies: each device fused ITS split shard; the trace
     # context merges the int64 (term, group) partials across the mesh
     # before the shared finalize tail (identity on a single device).
@@ -633,8 +635,5 @@ def _run(ctx, node: P.Aggregate):
         prof["fusedSumsPastInt64"] = (
             prof.get("fusedSumsPastInt64", 0) + past_int64
         )
-    ex._record_kernel(
-        "megakernel:%s/t%d/g%d" % (scan.table, n_terms, cap),
-        0.0, True, mode="megakernel",
-    )
+    ex._record_kernel(kernel, 0.0, True, mode="megakernel")
     return ctx._finish_aggregate(node, keys_out, out, present, cap)

@@ -109,6 +109,7 @@ def _order_encode(v, ok, sel, key: SortKey) -> jnp.ndarray:
     return (enc ^ jnp.uint64(_SIGN_BITS)).astype(jnp.int64)
 
 
+@jax.named_scope("topn")
 def topn(
     keys: Sequence[SortKey],
     lanes: Dict[str, Lane],

@@ -350,6 +350,7 @@ def chunks_to_wide(chunks) -> jnp.ndarray:
 _STACKED_CHUNK_ROWS = 1 << 21
 
 
+@jax.named_scope("seg_sum_chunks")
 def seg_sum_chunks(row_chunks, gid: jnp.ndarray, cap: int, seg=None):
     """Segment-sum per-row chunk lanes and normalize: the wide SUM
     kernel.  Two-chunk inputs (narrow rows) pad with zero chunks —

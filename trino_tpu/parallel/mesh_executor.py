@@ -44,6 +44,7 @@ from ..exec.local import (
 )
 from ..exec.shapes import lane_align
 from ..obs import compile_observatory as _compile_obs
+from ..obs import device_profile
 from ..utils.tracing import TRACER
 from ..expr import ir
 from ..expr.lower import compile_expr
@@ -432,19 +433,29 @@ class MeshExecutor(LocalExecutor):
         self._ladder_start(
             plan, {nid: int(c.sum()) for nid, c in counts.items()}
         )
+        # operator_stats on a backend with device planes: each attempt is
+        # profiled, and the settled one's (the last `cap`) gives each
+        # device's task wall its measured busy time; while another
+        # profile runs the walls stay row shares
+        probe = bool(self.config.get("collect_node_stats")) and (
+            device_profile.has_device_planes()
+        )
         for attempt in range(7):
             self._ladder_attempt = attempt
-            out, cell, prep = self._run_sharded(plan, scans, counts)
-            # ONE supervised transfer for every retry-ladder check and
-            # the output lanes (a retry simply discards the lanes)
-            with TRACER.span("device_get"):
-                (checks, dups, colls, wides, sflags, host_lanes,
-                 sel_np) = self._device_get(
-                    out[2:] + ({s: out[0][s] for s in plan.symbols}, out[1]),
-                    self._dispatch_crumb(
-                        self._last_crumb.kernel, "device_get"
-                    ),
-                )
+            with device_profile.capture_if_free(probe) as cap:
+                out, cell, prep = self._run_sharded(plan, scans, counts)
+                # ONE supervised transfer for every retry-ladder check and
+                # the output lanes (a retry simply discards the lanes)
+                with TRACER.span("device_get"):
+                    (checks, dups, colls, wides, sflags, host_lanes,
+                     sel_np) = self._device_get(
+                        out[2:] + (
+                            {s: out[0][s] for s in plan.symbols}, out[1]
+                        ),
+                        self._dispatch_crumb(
+                            self._last_crumb.kernel, "device_get"
+                        ),
+                    )
             if self._ladder_settled(
                 cell["dup_nodes"], dups, colls, wides,
                 cell["caps"], checks, sflags,
@@ -463,9 +474,14 @@ class MeshExecutor(LocalExecutor):
             self.scan_bytes = tree_nbytes(prep)
             page = self._materialize_host(plan, host_lanes, sel_np)
         if self.config.get("collect_node_stats"):
+            census = self.kernel_profile.get("programCensus") or {}
             self._mesh_node_stats(
                 plan, scans, counts,
                 time.perf_counter() - t_exec0, ndev, page,
+                device_profile.reduce(
+                    device_profile.last_module(cap.get("planes") or {}),
+                    census.get("ops"),
+                ),
             )
         return page
 
@@ -528,6 +544,7 @@ class MeshExecutor(LocalExecutor):
         if entry is not None:
             cell = entry["cell"]
             self.dicts.update(cell["dicts"])
+            census = entry["census"]
             fn = entry["fn"]
             with TRACER.span("launch"):
                 out = self._dispatch(lambda: fn(prep), bc)
@@ -546,6 +563,7 @@ class MeshExecutor(LocalExecutor):
                     self, {ids.get(o, o): v for o, v in prep_arg.items()},
                     None,
                 )
+                ctx.ordinals = order
                 batch = ctx.visit(plan.source)
                 if not batch.replicated:
                     batch = _gather_batch(batch)
@@ -608,6 +626,7 @@ class MeshExecutor(LocalExecutor):
                     ),
                     prep,
                 )
+                census = self._program_census(fn, digest)   # one shard's
                 compile_s = time.time() - compile_start
                 with TRACER.span("launch"):
                     out = self._dispatch(lambda: fn(prep), bc)
@@ -629,26 +648,31 @@ class MeshExecutor(LocalExecutor):
             cell["dicts"] = dict(self.dicts)
             if keyed:
                 # the plan reference pins id(plan) (fingerprint memo)
-                cache[key] = {"fn": fn, "cell": cell, "plan": plan}
+                cache[key] = {
+                    "fn": fn, "cell": cell, "plan": plan, "census": census,
+                }
+        self.kernel_profile["programCensus"] = census
         cell = dict(
             cell, dup_nodes=[by_ord.get(o) for o in cell["dup_ords"]]
         )
         return out, cell, prep
 
     # ------------------------------------------------------------------
-    def _mesh_node_stats(self, plan, scans, counts, wall_s, ndev, page):
+    def _mesh_node_stats(self, plan, scans, counts, wall_s, ndev, page,
+                         chips=None):
         """Post-execute operator/task stats for the SPMD program.
 
         The eager per-node row probes cannot run inside shard_map (the
-        counts are traced there), so the mesh synthesizes its timeline
-        after the program settles: whole-plan node stats feeding
-        frames_from_plan, plus one task rollup PER SHARD so EXPLAIN
-        ANALYZE stage timelines and the straggler detector see shards.
-        Per-shard wall is not separately observable inside one lockstep
-        SPMD program; each shard's wall is scaled by its scan-row share
-        relative to the heaviest shard — the slowest shard sets the
-        program wall and lighter shards idle, which is exactly the data
-        skew the straggler detector should surface."""
+        counts are traced there), so the mesh makes its timeline after
+        the program settles: whole-plan node stats feeding
+        frames_from_plan, plus one task rollup PER SHARD so stage
+        timelines and the straggler detector see shards.  `chips` is the
+        execution's device profile (obs/device_profile.reduce): a
+        device's task wall, and its root frame's `deviceWallS`, is its
+        measured busy time.  Only where the backend
+        has no device plane (`chips` None: virtual CPU devices) is a
+        shard's wall the program's scaled by its scan-row share relative
+        to the heaviest shard."""
         from ..obs import opstats
 
         shard_rows = np.zeros(ndev, dtype=np.int64)
@@ -695,7 +719,13 @@ class MeshExecutor(LocalExecutor):
         total = int(shard_rows.sum())
         tasks = []
         for d in range(ndev):
-            frac = (int(shard_rows[d]) / heaviest) if heaviest else 1.0
+            chip = (chips or {}).get(
+                "%s%d" % (device_profile.DEVICE_PLANE,
+                          self._mesh_device_ids[d])
+            )
+            frac = 1.0 if chip else (
+                (int(shard_rows[d]) / heaviest) if heaviest else 1.0
+            )
             share = (int(shard_rows[d]) / total) if total else 1.0 / ndev
             fl = []
             for f in frames:
@@ -708,11 +738,19 @@ class MeshExecutor(LocalExecutor):
                     if k in g:
                         g[k] = float(f.get(k) or 0.0) * frac
                 fl.append(g)
+            if chip:
+                # the fragment root's frame stands for the whole program
+                # (the only operator frames a mesh makes are its and the
+                # scans'): the chip's busy time
+                device_profile.apply_device_time(fl, {
+                    "%s#1" % type(plan.source).__name__: chip["busyMs"],
+                })
             tasks.append({
                 "taskId": "%s.0.%d" % (qid, d),
                 "nodeId": "device-%d" % self._mesh_device_ids[d],
                 "operatorStats": opstats.task_rollup(
-                    fl, wall_s=float(wall_s) * frac
+                    fl, wall_s=chip["busyMs"] / 1e3 if chip
+                    else float(wall_s) * frac
                 ),
             })
         self.mesh_tasks = tasks
@@ -900,6 +938,9 @@ class _MeshTraceCtx(_TraceCtx):
     # compaction capacities are GLOBAL row estimates; a mesh shard holds
     # 1/ndev of the rows (and skew could overflow a shard-scaled guess)
     allow_compaction = False
+    # the executor makes node stats and per-device task rollups after
+    # the program settles (_mesh_node_stats)
+    node_probes = False
 
     def __init__(self, ex: MeshExecutor, scans, counts):
         super().__init__(ex, scans, counts)
@@ -913,16 +954,6 @@ class _MeshTraceCtx(_TraceCtx):
 
     def _note_collision(self, coll):
         self.collision_checks.append(_pmax(coll))
-
-    def visit(self, node: P.PlanNode) -> Batch:
-        # the eager per-node instrumentation concretizes row counts
-        # (int(jnp.sum(sel))), which is impossible while tracing inside
-        # shard_map — the executor synthesizes node stats and per-shard
-        # task rollups after the program settles (_mesh_node_stats)
-        m = getattr(self, f"_visit_{type(node).__name__.lower()}", None)
-        if m is None:
-            raise ExecutionError(f"no executor for {type(node).__name__}")
-        return m(node)
 
     def _merge_fused_sums(self, sums):
         """Megakernel shard bodies: merge the per-shard fused
@@ -1063,7 +1094,6 @@ class _MeshTraceCtx(_TraceCtx):
 
             sorted_lanes = permute_lanes(b.lanes, perm)
             ss = agg_ops.SortedSegments(gid, cap)
-            self._count_first_rows(specs, True)
             accs = agg_ops.accumulate(
                 specs, sorted_lanes, gid, sel_sorted, cap, step="partial",
                 overflow_flags=self.sum_overflow,
@@ -1076,40 +1106,47 @@ class _MeshTraceCtx(_TraceCtx):
                 [sorted_lanes[k] for k in node.keys], gid, sel_sorted, cap,
                 starts=ss.starts,
             )
-            acc_lanes = {
-                name: (_agather(arr), jnp.ones(arr.shape[0] * self._ndev(), bool))
-                for name, arr in accs.items()
-            }
-            key_lanes_g = [(_agather(v), _agather(ok)) for v, ok in keys_local]
-            present_g = _agather(present_local)
+            with jax.named_scope("gather_group_state"):
+                acc_lanes = {
+                    name: (
+                        _agather(arr),
+                        jnp.ones(arr.shape[0] * self._ndev(), bool),
+                    )
+                    for name, arr in accs.items()
+                }
+                key_lanes_g = [
+                    (_agather(v), _agather(ok)) for v, ok in keys_local
+                ]
+                present_g = _agather(present_local)
             self._count("groupStateExchangeSlots", present_g.shape[0])
-            fcap = min(self.ex.group_capacity, present_g.shape[0])
-            self._count_sort_group(present_g.shape[0], fcap)
-            perm2, gid2, ngroups2 = self._group_sort(
-                key_lanes_g, present_g, fcap
-            )
-            self._note_capacity(ngroups2, fcap)
-            sel2 = present_g[perm2]
-            acc_sorted = {
-                s: (v[perm2], ok[perm2]) for s, (v, ok) in acc_lanes.items()
-            }
-            # gid2 is sorted too: `arbitrary` and the final keys read
-            # their rows off its runs (sums still merge by scatter)
-            ss2 = agg_ops.SortedSegments(gid2, fcap)
-            self._count_first_rows(specs, True, final_keys=True)
-            merged = agg_ops.merge_accumulators(
-                specs, acc_sorted, gid2, sel2, fcap,
-                overflow_flags=self.sum_overflow,
-                seg=ss2,
-            )
-            out = agg_ops.finalize(specs, merged)
-            keys_out = agg_ops.group_keys_output(
-                [(v[perm2], ok[perm2]) for v, ok in key_lanes_g],
-                gid2,
-                sel2,
-                fcap,
-                starts=ss2.starts,
-            )
+            with jax.named_scope("final_step"):
+                fcap = min(self.ex.group_capacity, present_g.shape[0])
+                self._count_sort_group(present_g.shape[0], fcap)
+                perm2, gid2, ngroups2 = self._group_sort(
+                    key_lanes_g, present_g, fcap
+                )
+                self._note_capacity(ngroups2, fcap)
+                sel2 = present_g[perm2]
+                acc_sorted = {
+                    s: (v[perm2], ok[perm2])
+                    for s, (v, ok) in acc_lanes.items()
+                }
+                # gid2 is sorted too: `arbitrary` and the final keys read
+                # their rows off its runs (sums still merge by scatter)
+                ss2 = agg_ops.SortedSegments(gid2, fcap)
+                merged = agg_ops.merge_accumulators(
+                    specs, acc_sorted, gid2, sel2, fcap,
+                    overflow_flags=self.sum_overflow,
+                    seg=ss2,
+                )
+                out = agg_ops.finalize(specs, merged)
+                keys_out = agg_ops.group_keys_output(
+                    [(v[perm2], ok[perm2]) for v, ok in key_lanes_g],
+                    gid2,
+                    sel2,
+                    fcap,
+                    starts=ss2.starts,
+                )
             present = jnp.arange(fcap) < ngroups2
             cap = fcap
 
@@ -1136,6 +1173,7 @@ class _MeshTraceCtx(_TraceCtx):
         return self.ex.mesh.devices.size
 
     # -- exchanges (counted at trace time: exec/local.OP_COUNTERS) ---------
+    @jax.named_scope("_broadcast")
     def _broadcast(self, build: Batch) -> Batch:
         """Broadcast exchange of a join's or semi join's build side: every
         device receives every shard's slots, live or not."""
@@ -1144,6 +1182,7 @@ class _MeshTraceCtx(_TraceCtx):
                     self._ndev() * build.sel.shape[0])
         return _gather_batch(build)
 
+    @jax.named_scope("_repartition")
     def _repartition(self, lanes, sel, bucket, keep, chunk):
         """Partitioned exchange (`shuffle.repartition`'s all-to-all): every
         device receives one `chunk` of slots from each device."""

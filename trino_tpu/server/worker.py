@@ -313,6 +313,12 @@ class TaskManager:
                 blocked_exchange_s=ex.blocked_exchange_s,
             )
             op_rollup["outputRows"] = page.count
+            prof = getattr(ex, "kernel_profile", None)
+            if prof and prof.get("programCensus"):
+                from ..obs import program_census
+
+                prof = dict(prof, programCensus=program_census.without_ops(
+                    prof["programCensus"]))
             t.stats = {
                 "dynamicFilterRowsPruned": ex.df_rows_pruned,
                 "scanBytes": ex.scan_bytes,
@@ -320,7 +326,7 @@ class TaskManager:
                 "wallMillis": int(wall_s * 1000),
                 # per-kernel compile wall / recompiles / padding — rides
                 # the existing stats rollup back to the coordinator
-                "kernelProfile": getattr(ex, "kernel_profile", None),
+                "kernelProfile": prof,
                 # pipeline -> task OperatorStats rollup (frames only when
                 # operator_stats forced the instrumented eager path)
                 "operatorStats": op_rollup,

@@ -969,6 +969,58 @@ class Session:
 
         walk(plan)
 
+    def device_profile(self, sql: str) -> dict:
+        """Device time by operator of `sql`, measured on the program this
+        session runs for it (obs/device_profile): `census` (the compiled
+        program's, obs/program_census), `hostSpans` (name -> [count, ms]
+        of the profiled execution), `wallMs`, and `device`: per chip busy
+        ms and self ms by operator, by operator/step and by kind, None
+        where the backend has no device planes.  Raises `ProfileBusy`
+        while another profile runs in this process."""
+        return self._profile_plan(
+            self._plan_cache.get(sql) or self._plan_stmt(parse(sql))
+        )
+
+    def _profile_plan(self, plan) -> dict:
+        import contextlib
+
+        from .obs import device_profile as dp
+
+        if isinstance(plan.source, P.TableWriter):
+            raise ValueError(
+                "a profile executes its plan more than once: not for a "
+                "statement that writes"
+            )
+
+        def executor():
+            ex = self._executor()
+            ex.config["collect_node_stats"] = False   # the compiled path
+            return ex
+
+        on_chip = dp.has_device_planes()
+        if on_chip:
+            # untimed: compiles what is not cached, settles the ladder
+            executor().execute(plan)
+        ex = executor()
+        with (dp.capture() if on_chip else contextlib.nullcontext({})) as cap:
+            with self.tracer.span("device_profile") as root:
+                ex.execute(plan)
+        spans: dict = {}
+        for s in list(self.tracer.spans):
+            if s.trace_id == root.trace_id and s is not root:
+                rec = spans.setdefault(s.name, [0, 0.0])
+                rec[0] += 1
+                rec[1] += s.duration_ms
+        census = (ex.kernel_profile or {}).get("programCensus")
+        return {
+            "wallMs": root.duration_ms,
+            "hostSpans": spans,
+            "census": census,
+            "device": dp.reduce(
+                cap.get("planes") or {}, (census or {}).get("ops")
+            ),
+        }
+
     def _explain_analyze(self, query, query_id: str) -> Page:
         """EXPLAIN ANALYZE: execute with per-node instrumentation and print
         the plan annotated with rows + wall time (ExplainAnalyzeOperator +
@@ -987,6 +1039,7 @@ class Session:
         )
         t0 = time.perf_counter()
         t_created = time.time()  # wall-clock window for the doctor
+        fallbacks = executor.supervisor.fallback_attempted
         page = executor.execute(plan)
         wall = time.perf_counter() - t0
         self.last_kernel_profile = getattr(executor, "kernel_profile", None)
@@ -995,6 +1048,14 @@ class Session:
             f"\n\nQuery: {page.count} output rows in {wall * 1000:.2f}ms "
             f"(single node)"
         )
+        if executor.supervisor.fallback_attempted > fallbacks:
+            # this pass has its own executor and the process's default
+            # supervisor: the session's fallback setting does not reach it
+            text += (
+                "\n  DEGRADED: this eager pass met a device fault (device "
+                f"{executor.supervisor.device_state()}) and ran again on "
+                "the CPU backend: its rows are exact, its walls the CPU's"
+            )
         # per-operator timeline (OperatorStats frames): estimated rows
         # come from the cost model so estimate-vs-observed divergence is
         # visible per operator
@@ -1014,6 +1075,19 @@ class Session:
                 executor, "blocked_exchange_s", 0.0
             ),
         )
+        # the compiled program of the same plan, through the session's own
+        # executor: its census and, where the backend has device planes,
+        # its measured device time (a mesh: the slowest chip's, by operator)
+        compiled, compiled_error = None, ""
+        try:
+            compiled = self._profile_plan(plan)
+        except Exception as e:  # noqa: BLE001 — the eager pass stands alone
+            compiled_error = "%s: %s" % (type(e).__name__, e)
+        chips = (compiled or {}).get("device")
+        if chips:
+            from .obs import device_profile as _dp
+
+            _dp.apply_device_time(frames, _dp.slowest_by_operator(chips))
         self.last_timeline = {
             "queryId": query_id, "wallS": wall, "operators": frames,
         }
@@ -1054,6 +1128,20 @@ class Session:
                         text += f"\n  {cause}: {n}"
             else:
                 text += "\n  (no compiles this query)"
+        if compiled and compiled.get("census"):
+            from .obs import device_profile as _dp
+
+            text += "\n\n" + _dp.format_profile(compiled)
+            if chips:
+                text += (
+                    "\n  (the timeline's device= is this table's, by "
+                    "operator, the slowest chip's)"
+                )
+        elif compiled_error:
+            text += (
+                "\n\nCompiled program: not profiled ("
+                + compiled_error.splitlines()[0][:300] + ")"
+            )
         # the doctor's causal verdict over the same evidence (EXPLAIN
         # ANALYZE is the interactive "why was this slow" surface)
         if self.properties.get("query_doctor"):
