@@ -333,7 +333,10 @@ def _shard_cap(prof, column):
     # sums' six; `arbitrary(o_orderdate)`, `arbitrary(o_shippriority)` and
     # the final step's keys read their rows off the sorted runs (a
     # `scatter-min` each before PR 36: 13)
-    ("scatters", 8), ("sorts", 12)])
+    ("scatters", 8), ("sorts", 12),
+    # each group sort permutes its rows once, the final step's keys and
+    # accumulators in one stacked gather (PR 38: 62 before)
+    ("gathers", 45)])
 def test_q3_counts_two_broadcast_joins_and_a_two_step_group_by(
         q3_traced, counter, expected):
     rows, correct, prof = q3_traced["automatic"]
@@ -351,6 +354,9 @@ def test_one_chip_q3_counts_its_two_arbitraries_and_answers_as_the_mesh(
     # its two `arbitrary`s read the sorted run: the scatters left are the
     # joins' `build_direct` and the wide sum's
     assert prof["programCensus"]["scatters"] == 3
+    # ... and the gathers the one permutation of the group sort's rows
+    # leaves (PR 38: 40 before)
+    assert prof["programCensus"]["gathers"] == 35
     assert rows == q3_traced["automatic"][0]
 
 

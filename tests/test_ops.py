@@ -93,17 +93,18 @@ def test_sort_based_grouping_multi_key():
     k1 = lane([3, 1, 3, 1, 3], dtype=jnp.int64)
     k2 = lane([0, 1, 0, 1, 1], dtype=jnp.int64)
     sel = allsel(5)
-    perm, gid, ngroups, coll = agg.sort_group_ids([k1, k2], sel, 8)
+    perm, gid, ngroups, sel_sorted, same_run = agg.sort_group_ids(
+        [k1, k2], sel, 8)
     assert int(ngroups) == 3
+    sorted_keys = [(k1[0][perm], k1[1][perm]), (k2[0][perm], k2[1][perm])]
+    assert int(agg.run_collisions(sorted_keys, same_run)) == 0
     # aggregate x by groups through the permutation
     x = jnp.asarray([10.0, 20.0, 30.0, 40.0, 50.0])
     xs = x[perm]
     specs = [AggSpec("sum", "x", "s")]
-    accs = agg.accumulate(specs, {"x": (xs, jnp.ones(5, bool))}, gid, sel[perm], 8)
+    accs = agg.accumulate(specs, {"x": (xs, jnp.ones(5, bool))}, gid, sel_sorted, 8)
     out = agg.finalize(specs, accs)
-    keys_out = agg.group_keys_output(
-        [(k1[0][perm], k1[1][perm]), (k2[0][perm], k2[1][perm])], gid, sel[perm], 8
-    )
+    keys_out = agg.group_keys_output(sorted_keys, gid, sel_sorted, 8)
     got = {}
     s = np.asarray(out["s"][0])
     kv1, kv2 = np.asarray(keys_out[0][0]), np.asarray(keys_out[1][0])

@@ -257,6 +257,38 @@ def test_merge_sums_the_fragments_of_a_streamed_query_once_each():
     assert outer["fragments"]["a"] is one
 
 
+# -- the sort group-by permutes its rows once (PR 38) -------------------------
+
+# `Aggregate#n` -> gathers in the parent's program (PR 37's tree, this
+# lowering: CPU, SF 0.01), where `sort_group_ids` gathered both neighbours of
+# every key plane and its callers gathered the keys, and `sel`, again
+PARENT_GATHERS = {
+    "q3": {"Aggregate#3": 17},
+    "q18": {"Aggregate#3": 39, "Aggregate#14": 14},
+    "mesh_q3": {"Aggregate#3": 39},
+}
+
+
+@pytest.mark.parametrize("cell", sorted(PARENT_GATHERS))
+def test_the_group_sort_gathers_nothing_and_its_aggregate_gathers_less(cell):
+    """`sort_group_ids` sorts, `_group_sort` permutes every lane once and
+    `run_collisions` compares the sorted key lanes with their one-row
+    shift: no gather is left under the sort's scope (on the mesh neither
+    under the final step's), and none came back under another name."""
+    mesh = {"distributed": True, "num_devices": 4} if cell == "mesh_q3" else {}
+    s = _session(compile_cache=False, **mesh)
+    s.execute(_texts(cell.replace("mesh_", ""))[0])
+    census = s.last_kernel_profile["programCensus"]
+    assert census["scopedInstructions"]
+    gathers = [scope for scope, kind, _shape, _rule in census["ops"].values()
+               if kind == "gather"]
+    assert gathers
+    assert not [g for g in gathers if g.endswith("sort_group_ids")
+                or g.endswith("run_collisions")]
+    for operator, before in PARENT_GATHERS[cell].items():
+        assert 0 < census["byOperator"][operator]["gathers"] < before
+
+
 # -- where the census goes --------------------------------------------------
 
 

@@ -69,12 +69,15 @@ def test_sort_group_ids_keeps_the_rows_order_inside_a_group():
     n = 600
     k = rng.integers(0, 25, n).astype(np.int64)
     sel = rng.random(n) < 0.9
-    perm, gid, ngroups, coll = agg.sort_group_ids(
+    perm, gid, ngroups, sel_sorted, same_run = agg.sort_group_ids(
         [(jnp.asarray(k), jnp.ones(n, dtype=bool))], jnp.asarray(sel), 64)
+    coll = agg.run_collisions(
+        [(jnp.asarray(k)[perm], jnp.ones(n, dtype=bool))], same_run)
     perm, gid = np.asarray(perm), np.asarray(gid)
     assert perm.dtype == np.int64 and sorted(perm) == list(range(n))
     assert int(ngroups) == len(set(k[sel])) and int(coll) == 0
     live = sel[perm]
+    assert np.array_equal(np.asarray(sel_sorted), live)
     assert not live[live.sum():].any()               # unselected rows last
     for g in range(int(ngroups)):
         rows = perm[live & (gid == g)]

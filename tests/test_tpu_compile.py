@@ -343,6 +343,12 @@ def test_mesh_q3_fragment_at_sf10_shards_fits_four_chips(
     # counts (no `scatter-min` left: 12 scatters and 9 sorts before)
     census = program_census.census(seen["compiled"])
     assert (census["scatters"], census["sorts"]) == (7, 13)
+    # the two group sorts permute their rows once and verify their hash
+    # runs on the sorted lanes (PR 38: 89 gathers before, 6 + 3 of them
+    # under `sort_group_ids`, over 16,777,216 and 1,048,576 slots)
+    assert census["gathers"] == 75
+    assert not [scope for scope, kind, _shape, _rule in census["ops"].values()
+                if kind == "gather" and scope.endswith("sort_group_ids")]
     assert census["tempBytes"] == (
         seen["compiled"].memory_analysis().temp_size_in_bytes)
     assert census["scopedInstructions"] > 0 and census["collectives"] > 0

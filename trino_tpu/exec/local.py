@@ -2140,18 +2140,20 @@ class _TraceCtx:
 
     def _visit_distinct(self, node: P.Distinct) -> Batch:
         b = self.visit(node.source)
-        syms = node.output_symbols()
-        key_lanes = [b.lanes[s] for s in syms]
-        cap = b.sel.shape[0]
-        perm, gid, ngroups = self._group_sort(key_lanes, b.sel, cap)
-        sel_sorted = b.sel[perm]
+        return Batch(
+            *self._distinct_rows(b.lanes, node.output_symbols(), b.sel)
+        )
+
+    def _distinct_rows(self, lanes, syms, sel):
+        """(lanes, sel) with one live row for each distinct tuple of
+        `syms`: the first row of every group of the group sort."""
+        lanes, sel_sorted, gid, _ = self._group_sort(
+            lanes, syms, sel, sel.shape[0]
+        )
         boundary = jnp.concatenate(
             [jnp.ones(1, dtype=bool), gid[1:] != gid[:-1]]
         )
-        lanes = {
-            s: (v[perm], ok[perm]) for s, (v, ok) in b.lanes.items()
-        }
-        return Batch(lanes, sel_sorted & boundary)
+        return lanes, sel_sorted & boundary
 
     def _visit_unnest(self, node: P.Unnest) -> Batch:
         """UNNEST via host-side expansion: lengths come from the array
@@ -2453,12 +2455,10 @@ class _TraceCtx:
         else:
             cap = min(self.ex.group_capacity, b.sel.shape[0])
             self._count_sort_group(b.sel.shape[0], cap)
-            perm, gid, ngroups = self._group_sort(key_lanes, b.sel, cap)
+            sorted_lanes, sel_sorted, gid, ngroups = self._group_sort(
+                b.lanes, node.keys, b.sel, cap
+            )
             self._note_capacity(ngroups, cap)
-            sel_sorted = b.sel[perm]
-            from ..ops.filter_project import permute_lanes
-
-            sorted_lanes = permute_lanes(b.lanes, perm)
             # gid is SORTED here: one shared run-range computation
             # replaces per-aggregate scatters (SortedSegments)
             ss = agg_ops.SortedSegments(gid, cap)
@@ -2594,15 +2594,27 @@ class _TraceCtx:
     def _note_collision(self, coll):
         self.collision_checks.append(coll)
 
-    def _group_sort(self, key_lanes, sel, cap):
+    def _group_sort(self, lanes, keys, sel, cap):
         """Salted hash-sort grouping with exact verification; a
         detected locator collision re-runs the fragment under a fresh
-        salt (executor retry ladder), so grouping is always exact."""
-        perm, gid, ngroups, coll = agg_ops.sort_group_ids(
-            key_lanes, sel, cap, getattr(self.ex, 'group_salt', 0)
+        salt (executor retry ladder), so grouping is always exact.
+
+        The one place that sorts, permutes and verifies: every lane of
+        `lanes` is gathered by the sort's permutation ONCE (one stacked
+        gather a dtype), and the hash runs are verified on those sorted
+        key lanes.  Returns (sorted_lanes, sel_sorted, gid, ngroups),
+        rows grouped by `keys`, dead rows last."""
+        from ..ops.filter_project import permute_lanes
+
+        perm, gid, ngroups, sel_sorted, same_run = agg_ops.sort_group_ids(
+            [lanes[k] for k in keys], sel, cap,
+            getattr(self.ex, 'group_salt', 0),
         )
-        self._note_collision(coll)
-        return perm, gid, ngroups
+        sorted_lanes = permute_lanes(lanes, perm)
+        self._note_collision(agg_ops.run_collisions(
+            [sorted_lanes[k] for k in keys], same_run
+        ))
+        return sorted_lanes, sel_sorted, gid, ngroups
 
     def _visit_join(self, node: P.Join) -> Batch:
         left = self.visit(node.left)
@@ -3135,14 +3147,7 @@ class _TraceCtx:
         batch = Batch(lanes, sel)
         if not node.all:
             # UNION DISTINCT via the Distinct path
-            key_lanes = [lanes[s] for s in node.symbols]
-            cap = sel.shape[0]
-            perm, gid, _ = self._group_sort(key_lanes, sel, cap)
-            boundary = jnp.concatenate(
-                [jnp.ones(1, dtype=bool), gid[1:] != gid[:-1]]
-            )
-            lanes = {s: (v[perm], ok[perm]) for s, (v, ok) in lanes.items()}
-            batch = Batch(lanes, sel[perm] & boundary)
+            batch = Batch(*self._distinct_rows(lanes, node.symbols, sel))
         return batch
 
     def _union_lanes(self, node: P.SetOperation):
@@ -3199,11 +3204,12 @@ class _TraceCtx:
         rows: group-sort by the full row, per-side presence marks,
         keep-group predicate, first-of-group dedup.  Used by the local
         path and (post-repartition) by the mesh path."""
-        key_lanes = [lanes0[s] for s in node.symbols]
-        perm, gid, ngroups = self._group_sort(key_lanes, sel, cap)
+        tagged = {**lanes0, "__tag__": (tag, jnp.ones(tag.shape[0], bool))}
+        lanes, sel_sorted, gid, ngroups = self._group_sort(
+            tagged, node.symbols, sel, cap
+        )
         self._note_capacity(ngroups, cap)
-        sel_sorted = sel[perm]
-        tag_sorted = tag[perm]
+        tag_sorted, _ = lanes.pop("__tag__")
         side0 = agg_ops._seg_count(
             sel_sorted & (tag_sorted == 0), gid, cap
         ) > 0
@@ -3216,9 +3222,6 @@ class _TraceCtx:
         boundary = jnp.concatenate(
             [jnp.ones(1, dtype=bool), gid[1:] != gid[:-1]]
         )
-        from ..ops.filter_project import permute_lanes
-
-        lanes = permute_lanes(lanes0, perm)
         return Batch(lanes, sel_sorted & boundary & keep_group[gid])
 
     def _intersect_except(self, node: P.SetOperation) -> Batch:

@@ -361,21 +361,31 @@ def sort_group_ids(
     sel: jnp.ndarray,
     capacity: int,
     salt: int = 0,
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Hash-sort grouping: returns (perm, gid_sorted, ngroups, collisions).
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Hash-sort grouping: returns (perm, gid_sorted, ngroups, sel_sorted,
+    same_run).
 
     perm reorders rows so equal keys are adjacent (unselected rows last);
     gid_sorted[i] is the group id of sorted row i (unselected rows get
-    capacity-1 but are excluded by weight later).
+    capacity-1 but are excluded by weight later); sel_sorted is `sel[perm]`,
+    read off the sorted locator (dead rows are keyed 2**61); same_run[i]
+    says that sorted row i is live and has the locator of row i-1.
 
     TPU-first design note: a lexicographic multi-key `lax.sort` compiles a
     (1+2k)-operand comparator whose XLA:TPU compile time explodes with k
     (~190s for k=3 at 8M rows vs ~50s for one key).  Instead rows sort by
     ONE salted 64-bit locator hash of the key tuple, and adjacent rows in
-    the same hash run are verified equal on the real key columns — the
-    `collisions` output counts mismatches (probability ~n²/2⁻⁶⁴) and the
+    the same hash run are verified equal on the real key columns
+    (`run_collisions`; probability of a mismatch ~n²/2⁻⁶⁴), and the
     executor re-runs with a fresh salt when it is ever nonzero, so results
-    are exact, never probabilistic (same protocol as the join locators)."""
+    are exact, never probabilistic (same protocol as the join locators).
+
+    Nothing here gathers: the CALLER permutes its rows by `perm`, keys
+    included, once (`exec/local._TraceCtx._group_sort`: one stacked
+    `permute_lanes` over the whole batch), and `run_collisions` verifies
+    on those sorted key lanes.  A random gather over all slots is what a
+    sort group-by costs on the chip (45-50 M elements/s), so no key lane
+    is gathered twice."""
     n = key_lanes[0][0].shape[0]
     hk = _group_hash(key_lanes, salt)
     key = jnp.where(sel, hk, jnp.int64(2**61))  # dead rows sort last
@@ -390,22 +400,38 @@ def sort_group_ids(
         [jnp.ones(1, bool), sorted_key[1:] != sorted_key[:-1]]
     )
     boundary = diff & sel_sorted
-    # exact adjacent verification (PagesHashStrategy positionEquals analog)
-    prev = jnp.concatenate([perm[:1], perm[:-1]])
     same_run = (~diff) & sel_sorted
-    all_eq = jnp.ones(n, dtype=bool)
-    for v, ok in key_lanes:
-        okp, okq = ok[perm], ok[prev]
-        vals_eq = jnp.ones(n, dtype=bool)
-        for bits in _key_bit_lanes(v):
-            vals_eq = vals_eq & (bits[perm] == bits[prev])
-        lane_eq = (okp == okq) & (~okp | vals_eq)
-        all_eq = all_eq & lane_eq
-    collisions = jnp.sum(same_run & ~all_eq)
     gid = jnp.cumsum(boundary.astype(jnp.int64)) - 1
     ngroups = boundary.sum()
     gid = jnp.where(sel_sorted, jnp.clip(gid, 0, capacity - 1), capacity - 1)
-    return perm, gid, ngroups, collisions
+    return perm, gid, ngroups, sel_sorted, same_run
+
+
+def _shift_down(x: jnp.ndarray) -> jnp.ndarray:
+    """Row i reads row i-1 (row 0 itself): the neighbour above."""
+    return jnp.concatenate([x[:1], x[:-1]])
+
+
+@jax.named_scope("run_collisions")
+def run_collisions(
+    sorted_key_lanes: Sequence[Lane], same_run: jnp.ndarray
+) -> jnp.ndarray:
+    """Exact adjacent verification of `sort_group_ids`' hash runs
+    (PagesHashStrategy positionEquals analog), on key lanes ALREADY
+    permuted by its `perm`: the count of live rows that share the locator
+    of the row above but not its key tuple.  NULL equals NULL and no
+    value; floats compare by `f64_order_bits` (NaN equals NaN, -0 equals
+    +0), wide keys limb by limb.  Elementwise and a one-row shift: no
+    gather."""
+    n = same_run.shape[0]
+    all_eq = jnp.ones(n, dtype=bool)
+    for v, ok in sorted_key_lanes:
+        vals_eq = jnp.ones(n, dtype=bool)
+        for bits in _key_bit_lanes(v):
+            vals_eq = vals_eq & (bits == _shift_down(bits))
+        lane_eq = (ok == _shift_down(ok)) & (~ok | vals_eq)
+        all_eq = all_eq & lane_eq
+    return jnp.sum(same_run & ~all_eq)
 
 
 def distinct_first_mask(

@@ -1087,12 +1087,10 @@ class _MeshTraceCtx(_TraceCtx):
             # group state; re-merge (PARTIAL -> exchange -> FINAL)
             cap = min(self.ex.group_capacity, b.sel.shape[0])
             self._count_sort_group(b.sel.shape[0], cap)
-            perm, gid, ngroups = self._group_sort(key_lanes, b.sel, cap)
+            sorted_lanes, sel_sorted, gid, ngroups = self._group_sort(
+                b.lanes, node.keys, b.sel, cap
+            )
             self._note_capacity(ngroups, cap)
-            sel_sorted = b.sel[perm]
-            from ..ops.filter_project import permute_lanes
-
-            sorted_lanes = permute_lanes(b.lanes, perm)
             ss = agg_ops.SortedSegments(gid, cap)
             accs = agg_ops.accumulate(
                 specs, sorted_lanes, gid, sel_sorted, cap, step="partial",
@@ -1114,23 +1112,17 @@ class _MeshTraceCtx(_TraceCtx):
                     )
                     for name, arr in accs.items()
                 }
-                key_lanes_g = [
-                    (_agather(v), _agather(ok)) for v, ok in keys_local
-                ]
+                for k, (v, ok) in zip(node.keys, keys_local):
+                    acc_lanes[k] = (_agather(v), _agather(ok))
                 present_g = _agather(present_local)
             self._count("groupStateExchangeSlots", present_g.shape[0])
             with jax.named_scope("final_step"):
                 fcap = min(self.ex.group_capacity, present_g.shape[0])
                 self._count_sort_group(present_g.shape[0], fcap)
-                perm2, gid2, ngroups2 = self._group_sort(
-                    key_lanes_g, present_g, fcap
+                acc_sorted, sel2, gid2, ngroups2 = self._group_sort(
+                    acc_lanes, node.keys, present_g, fcap
                 )
                 self._note_capacity(ngroups2, fcap)
-                sel2 = present_g[perm2]
-                acc_sorted = {
-                    s: (v[perm2], ok[perm2])
-                    for s, (v, ok) in acc_lanes.items()
-                }
                 # gid2 is sorted too: `arbitrary` and the final keys read
                 # their rows off its runs (sums still merge by scatter)
                 ss2 = agg_ops.SortedSegments(gid2, fcap)
@@ -1141,7 +1133,7 @@ class _MeshTraceCtx(_TraceCtx):
                 )
                 out = agg_ops.finalize(specs, merged)
                 keys_out = agg_ops.group_keys_output(
-                    [(v[perm2], ok[perm2]) for v, ok in key_lanes_g],
+                    [acc_sorted[k] for k in node.keys],
                     gid2,
                     sel2,
                     fcap,
@@ -1537,20 +1529,10 @@ class _MeshTraceCtx(_TraceCtx):
             # FIXED_HASH exchange on the distinct keys: equal rows
             # co-locate, each device dedupes its hash range, and the
             # output STAYS distributed (MarkDistinct partitioned plan)
-            b = self._hash_repartition(b, tuple(node.output_symbols()))
-            b = self._local_distinct(node.output_symbols(), b)
-            b.replicated = False
+            syms = node.output_symbols()
+            b = self._hash_repartition(b, tuple(syms))
+            b = Batch(*self._distinct_rows(b.lanes, syms, b.sel))
         return b
-
-    def _local_distinct(self, syms, b: Batch) -> Batch:
-        key_lanes = [b.lanes[s] for s in syms]
-        cap = b.sel.shape[0]
-        perm, gid, _ = self._group_sort(key_lanes, b.sel, cap)
-        boundary = jnp.concatenate(
-            [jnp.ones(1, dtype=bool), gid[1:] != gid[:-1]]
-        )
-        lanes = {s: (v[perm], ok[perm]) for s, (v, ok) in b.lanes.items()}
-        return Batch(lanes, b.sel[perm] & boundary, replicated=b.replicated)
 
     def _partitioned_setop(self, node: P.SetOperation) -> Batch:
         """INTERSECT/EXCEPT on the mesh: union the inputs positionally
