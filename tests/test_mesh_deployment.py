@@ -329,6 +329,8 @@ def _shard_cap(prof, column):
     ("broadcastExchanges", 2),
     ("partitionedExchanges", 0), ("partitionedExchangeSlots", 0),
     ("sortGroupBys", 2), ("directJoins", 2), ("compactions", 0),
+    # the mesh never compacts, so no join compacts before its build gather
+    ("survivorGatherJoins", 0),
     # the compiled program's census: a `build_direct` scatter a join and the
     # sums' six; `arbitrary(o_orderdate)`, `arbitrary(o_shippriority)` and
     # the final step's keys read their rows off the sorted runs (a

@@ -289,6 +289,51 @@ def test_the_group_sort_gathers_nothing_and_its_aggregate_gathers_less(cell):
         assert 0 < census["byOperator"][operator]["gathers"] < before
 
 
+# -- a join that compacts gathers its build side for the survivors ----------
+
+JOIN_THAT_COMPACTS = """select o_orderpriority, count(*) as n
+  from lineitem join orders on l_orderkey = o_orderkey
+  where o_orderdate >= date '1995-01-01' and o_orderdate < date '1995-04-01'
+  group by o_orderpriority order by o_orderpriority"""
+
+
+def _join_gather_widths(census):
+    """step -> the slot counts of the gathers under a join's
+    `permute_lanes` and `_maybe_compact/permute_lanes`."""
+    out = {}
+    for scope, kind, shape, _rule in census["ops"].values():
+        m = re.fullmatch(r"Join#\d+/((?:_maybe_compact/)?permute_lanes)",
+                         scope)
+        if kind == "gather" and m:
+            out.setdefault(m.group(1), set()).update(
+                int(d) for d in re.findall(r"\[(\d+)", shape))
+    return out
+
+
+@pytest.mark.parametrize("compacts", [True, False])
+def test_a_join_that_compacts_gathers_at_the_compacted_width(
+        compacts, monkeypatch):
+    """The build columns and the compaction's probe lanes are gathered over
+    the join's compacted capacity, none over its probe slots; without the
+    compaction the build columns are gathered over every probe slot."""
+    from trino_tpu.plan import optimizer
+
+    monkeypatch.setattr(optimizer, "_COMPACT_MIN_ROWS",
+                        1 << 10 if compacts else 1 << 40)
+    s = _session(compile_cache=False)
+    s.execute(JOIN_THAT_COMPACTS)
+    prof = s.last_kernel_profile
+    widths = _join_gather_widths(prof["programCensus"])
+    if not compacts:
+        assert "survivorGatherJoins" not in prof
+        assert widths == {"permute_lanes": {prof["joinSlots"]}}
+        return
+    assert prof["survivorGatherJoins"] == 1
+    assert set(widths) == {"permute_lanes", "_maybe_compact/permute_lanes"}
+    (cap,) = set.union(*widths.values())
+    assert cap < prof["joinSlots"]
+
+
 # -- where the census goes --------------------------------------------------
 
 

@@ -433,6 +433,20 @@ def test_q5_fragment_at_sf10_fits_one_chip(
                                    + 2 * 524_288)
     census = program_census.census(seen["compiled"])
     assert census["tempBytes"] < 15.75 * 1024**3
+    # lineitem's join compacts before it gathers orders x customer's
+    # columns: its gathers run over the 16,777,216 survivors' slots, none
+    # over the probe's 67,108,864 (the supplier join, residual key, keeps
+    # the gather-then-compact order over 16,777,216)
+    assert counts["survivorGatherJoins"] == 1
+    widths = {}
+    for scope, kind, shape, _rule in census["ops"].values():
+        m = re.fullmatch(r"(Join#\d+)/(?:_maybe_compact/)?permute_lanes",
+                         scope)
+        if kind == "gather" and m:
+            widths.setdefault(m.group(1), set()).update(
+                int(d) for d in re.findall(r"\[(\d+)", shape))
+    assert widths and 67_108_864 not in set.union(*widths.values())
+    assert {16_777_216} in widths.values()
     mem = seen["compiled"].memory_analysis()
     assert census["tempBytes"] == mem.temp_size_in_bytes
     assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes

@@ -318,6 +318,8 @@ def dict_fingerprint(dicts: Dict[str, np.ndarray], symbols) -> int:
 # mesh's (parallel/mesh_executor): its exchanges, in slots one device
 # receives.  `joinSlots` is the join order as the program holds it: the
 # output slots of every inner join, before any compaction.
+# `survivorGatherJoins`: inner joins that compacted before gathering their
+# build side (`_TraceCtx._compact_then_gather`).
 OP_COUNTERS = (
     "sortGroupBys", "sortGroupRows", "sortGroupCapacity", "directGroupBys",
     "directJoins", "sortJoins", "semiJoins", "lazyDictionaryColumns",
@@ -326,7 +328,12 @@ OP_COUNTERS = (
     "partitionedExchanges", "partitionedExchangeSlots",
     "groupStateExchangeSlots",
     "residualKeyJoins", "hashedKeyJoins", "joinSlots",
+    "survivorGatherJoins",
 )
+
+# the key under which a join's build row rides the probe lanes' compaction
+# (a sentinel: no plan symbol equals it)
+_BUILD_ROW = object()
 
 
 def _is_null_expr(e: ir.Expr) -> bool:
@@ -2073,41 +2080,53 @@ class _TraceCtx:
     # mesh shards see 1/ndev of the rows, so _MeshTraceCtx disables this
     allow_compaction = True
 
+    def _compaction_cap(self, node, n: int) -> Optional[int]:
+        """The one decision whether `n` slots of `node`'s output compact,
+        and into what capacity: the optimizer's compact_rows estimate,
+        grown by the ladder's compact_factor; None where it does not
+        engage.  `_maybe_compact` and the inner join that compacts before
+        it gathers its build side (`_compact_then_gather`) both ask here,
+        so they cannot disagree."""
+        est = getattr(node, "compact_rows", None)
+        if est is None or not self.allow_compaction:
+            return None
+        factor = getattr(self.ex, "compact_factor", 1)
+        cap = self.ex.ladder.quantize(int(est * 1.3) * factor)
+        return cap if cap < n else None
+
+    def _compact_ids(self, sel, cap: int):
+        """(row ids of the first `cap` survivors, their true count), the
+        count noted for the capacity checks."""
+        from ..ops.filter_project import compact_indices
+
+        self._count("compactions")
+        self._count("compactRows", sel.shape[0])
+        self._count("compactCapacity", cap)
+        total = sel.sum()
+        idx = compact_indices(sel, cap)
+        self._note_capacity(total, cap, "compact")
+        return idx, total
+
     @jax.named_scope("_maybe_compact")
     def _maybe_compact(self, b: Batch, node) -> Batch:
-        """Tighten survivors into a smaller static capacity (the
-        optimizer's compact_rows estimate, grown by the ladder's
-        compact_factor).  One single-key int32 sort for the survivors'
+        """Tighten survivors into a smaller static capacity
+        (`_compaction_cap`).  One single-key int32 sort for the survivors'
         row ids (ops/filter_project.compact_indices: no scatter, no
         int64 scan) + one stacked row-gather; every downstream
         sort/gather then runs at the tightened width and the fragment's
         HBM peak shrinks with it.  Exactness: the
         true survivor count rides the capacity checks — overflow re-runs
         with a wider (eventually input-width, i.e. no-op) capacity."""
-        est = getattr(node, "compact_rows", None)
-        if (
-            est is None
-            or not self.allow_compaction
-            or b.ordered
-            or b.replicated
-        ):
+        if b.ordered or b.replicated:
             return b
-        factor = getattr(self.ex, "compact_factor", 1)
-        cap = self.ex.ladder.quantize(int(est * 1.3) * factor)
-        n = b.sel.shape[0]
-        if cap >= n:
+        cap = self._compaction_cap(node, b.sel.shape[0])
+        if cap is None:
             return b
-        from ..ops.filter_project import compact_indices, permute_lanes
+        from ..ops.filter_project import permute_lanes
 
-        self._count("compactions")
-        self._count("compactRows", n)
-        self._count("compactCapacity", cap)
-        total = b.sel.sum()
-        idx = compact_indices(b.sel, cap)
-        self._note_capacity(total, cap, "compact")
+        idx, total = self._compact_ids(b.sel, cap)
         lanes = permute_lanes(b.lanes, idx)
-        sel = jnp.arange(cap) < total
-        return Batch(lanes, sel, b.ordered, b.replicated)
+        return Batch(lanes, jnp.arange(cap) < total, b.ordered, b.replicated)
 
     def _visit_filter(self, node: P.Filter) -> Batch:
         b = self.visit(node.source)
@@ -2624,11 +2643,13 @@ class _TraceCtx:
         right = self.visit(node.right)
         out = self._join_batches(node, left, right)
         if node.kind == "inner":
-            self._count("joinSlots", out.sel.shape[0])
+            # a no-op where the join compacted before its build gather
             out = self._maybe_compact(out, node)
         return out
 
     def _join_batches(self, node: P.Join, left: Batch, right: Batch) -> Batch:
+        """Join two batches; every inner join counts its output slots
+        before any compaction (`joinSlots`)."""
         if node.kind == "cross":
             return self._cross_join(node, left, right)
         hashed = len(node.criteria) > 1
@@ -2638,7 +2659,10 @@ class _TraceCtx:
             self._count("sortJoins")
             if hashed:
                 self._count("hashedKeyJoins")
-            return self._expansion_join(node, left, right)
+            out = self._expansion_join(node, left, right)
+            if node.kind == "inner":
+                self._count("joinSlots", out.sel.shape[0])
+            return out
         # unique-keyed build on right, probe on left
         lkeys = [left.lanes[l] for l, _ in node.criteria]
         rkeys = [right.lanes[r] for _, r in node.criteria]
@@ -2684,6 +2708,14 @@ class _TraceCtx:
                 # locator collision must reject the candidate, not
                 # return a wrong row
                 matched = matched & join_ops.verify_rows(rkeys, lkeys, row)
+        if node.kind == "inner":
+            n = left.sel.shape[0]
+            self._count("joinSlots", n)
+            if not residual_keys and node.filter is None:
+                cap = self._compaction_cap(node, n)
+                if cap is not None:
+                    return self._compact_then_gather(
+                        left, right, row, matched, cap)
         build_cols = join_ops.gather_build(right.lanes, row, matched)
         if residual_keys:
             # exact, as a residual, on the build row just gathered: the
@@ -2713,6 +2745,36 @@ class _TraceCtx:
                 for name in build_cols:
                     bv, bok = lanes[name]
                     lanes[name] = (bv, bok & keep)
+        return Batch(lanes, sel)
+
+    def _compact_then_gather(self, left: Batch, right: Batch, row, matched,
+                             cap: int) -> Batch:
+        """An inner join whose output selection is exactly
+        `left.sel & matched` (no residual key, no filter) and whose
+        compaction engages: compact that selection first, then gather the
+        build side for the survivors only.  The probe's lanes and the
+        build row are permuted together, then `gather_build` runs over
+        `cap` slots where it ran over every probe slot and
+        `_maybe_compact` gathered its lanes a second time.  The live
+        slots hold what `_maybe_compact` of the join's output holds, in
+        the same order."""
+        from ..ops.filter_project import permute_lanes
+
+        # the build row joins a stack the probe's lanes already make (int32,
+        # else int64): on the chip a stacked gather costs by the rows it
+        # moves, hardly by its columns, and a stack of its own is one more
+        # gather (measured on a v5e: PERF.md section 6)
+        have = {v.dtype for v, _ in left.lanes.values()}
+        rdt = (jnp.int64 if jnp.dtype(jnp.int64) in have
+               and jnp.dtype(jnp.int32) not in have else jnp.int32)
+        self._count("survivorGatherJoins")
+        with jax.named_scope("_maybe_compact"):
+            idx, total = self._compact_ids(left.sel & matched, cap)
+            lanes = permute_lanes(
+                {**left.lanes, _BUILD_ROW: (row.astype(rdt), matched)}, idx)
+        row, _ = lanes.pop(_BUILD_ROW)
+        sel = jnp.arange(cap) < total
+        lanes.update(join_ops.gather_build(right.lanes, row, sel))
         return Batch(lanes, sel)
 
     def _expansion_join(self, node: P.Join, left: Batch, right: Batch) -> Batch:

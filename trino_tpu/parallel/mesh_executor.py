@@ -1239,8 +1239,6 @@ class _MeshTraceCtx(_TraceCtx):
                 right = self._broadcast(right)
             out = self._join_batches(node, left, right)
             out.replicated = left.replicated
-        if node.kind == "inner":
-            self._count("joinSlots", out.sel.shape[0])
         return out
 
     def _hinted_chunk(self, node, side, cap, ndev, factor):
