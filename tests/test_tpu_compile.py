@@ -367,17 +367,11 @@ def test_mesh_q3_fragment_at_sf10_shards_fits_four_chips(
     assert "scatter-max" in kinds and "scatter-min" not in kinds
 
 
-def test_q5_fragment_at_sf10_fits_one_chip(
-        one_chip, no_persistent_cache, monkeypatch):
-    """The one fragment of `tpch_sf10_q5.q5` at its own sizes (lineitem
-    67,108,864 slots, orders 16,777,216, customer 2,097,152, supplier
-    131,072), compiled for the described chip: the session plans Q5 at SF
-    10, the generators hand out shapes where they would hand out lanes, and
-    the query stops where it would launch.  The parent's order joined
-    customer x supplier on the nation key first (6e9 rows estimated); this
-    one joins five times through direct tables, supplier on two columns."""
-    from oracle import bench_module
-
+def _fragment_for_chip(one_chip, monkeypatch, sf, sql, **props):
+    """Plan `sql` at `sf`, hand out shapes where the generators would hand
+    out lanes, and compile the one fragment for the described chip where
+    the query would launch it: what the trace counted and the compiled
+    program."""
     def generator(table, cols, lo, hi, cap, sf, cap_orders, mesh=None):
         raw = (tpch_device._gen_lineitem(cols, cap_orders, cap, sf)
                if table == "lineitem"
@@ -417,11 +411,27 @@ def test_q5_fragment_at_sf10_fits_one_chip(
     monkeypatch.setattr(pk, "enabled", lambda: True)
     monkeypatch.setattr(agg_ops, "_use_masked",
                         lambda cap: cap <= agg_ops._SMALL_SEG_CAP)
-    q5 = bench_module("queries", "q5")
-    s = tpch_session(10.0, device_cpu_fallback=False, result_cache=False,
-                     compile_cache=False)
+    s = tpch_session(sf, device_cpu_fallback=False, result_cache=False,
+                     compile_cache=False, **props)
     with pytest.raises(Launch):
-        s.execute(q5.sql({"region": "ASIA", "date": "1994-01-01"}))
+        s.execute(sql)
+    return seen
+
+
+def test_q5_fragment_at_sf10_fits_one_chip(
+        one_chip, no_persistent_cache, monkeypatch):
+    """The one fragment of `tpch_sf10_q5.q5` at its own sizes (lineitem
+    67,108,864 slots, orders 16,777,216, customer 2,097,152, supplier
+    131,072), compiled for the described chip: the session plans Q5 at SF
+    10, the generators hand out shapes where they would hand out lanes, and
+    the query stops where it would launch.  The parent's order joined
+    customer x supplier on the nation key first (6e9 rows estimated); this
+    one joins five times through direct tables, supplier on two columns."""
+    from oracle import bench_module
+
+    q5 = bench_module("queries", "q5")
+    seen = _fragment_for_chip(one_chip, monkeypatch, 10.0,
+                              q5.sql({"region": "ASIA", "date": "1994-01-01"}))
     assert seen["shapes"] == [(128,), (131_072,), (2_097_152,),
                               (16_777_216,), (67_108_864,)]
     counts = seen["ctx"].op_counts
@@ -449,5 +459,38 @@ def test_q5_fragment_at_sf10_fits_one_chip(
     assert {16_777_216} in widths.values()
     mem = seen["compiled"].memory_analysis()
     assert census["tempBytes"] == mem.temp_size_in_bytes
+    assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes) < HBM_BYTES
+
+
+def test_q21_fragment_at_sf10_fits_one_chip(
+        one_chip, no_persistent_cache, monkeypatch):
+    """The one fragment of `tpch_sf10_q21.q21` at its own sizes (lineitem
+    67,108,864 slots, read three times, orders 16,777,216, supplier
+    131,072), compiled for the described chip.  The late lines of the
+    nation's suppliers compact to 2,097,152 slots (the late-line share
+    estimated from both dates' ranges, 0.51 of 0.63 true); both mark joins
+    read each key's least and greatest l_suppkey over lineitem's slots,
+    no pair buffer, no slot sized from the source's."""
+    from oracle import bench_module
+
+    q21 = bench_module("queries", "q21")
+    seen = _fragment_for_chip(one_chip, monkeypatch, 10.0,
+                              q21.sql({"nation": "SAUDI ARABIA"}),
+                              query_max_memory_bytes=16 * 1024**3)
+    assert seen["shapes"] == [(128,), (131_072,), (16_777_216,),
+                              (67_108_864,)]
+    counts = seen["ctx"].op_counts
+    assert counts["semiJoins"] == counts["residualSemiJoins"] == 2
+    assert counts["semiWorkSlots"] == 2 * (67_108_864 + 2_097_152)
+    census = program_census.census(seen["compiled"])
+    scopes = {scope for scope, *_ in census["ops"].values()}
+    assert any(re.fullmatch(r"SemiJoin#\d+/_semi_residual(/.*)?", sc)
+               for sc in scopes)
+    assert not any("_semi_hit" in sc for sc in scopes)
+    mem = seen["compiled"].memory_analysis()
+    print("q21 sf10 temp %.2f GB, args %.2f GB, counts %s" % (
+        mem.temp_size_in_bytes / 1e9, mem.argument_size_in_bytes / 1e9,
+        counts))
     assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
             + mem.output_size_in_bytes) < HBM_BYTES

@@ -214,6 +214,8 @@ class _Emitter:
                 "semijoin", self.keys(n.source_keys),
                 self.keys(n.filtering_keys), self.sym(n.output),
             )
+            if n.pair_rows is not None:
+                self.tok("pairs", n.pair_rows)
             self.expr(n.filter)
         elif isinstance(n, P.ScalarJoin):
             self.tok("scalarjoin")

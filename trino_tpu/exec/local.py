@@ -319,7 +319,9 @@ def dict_fingerprint(dicts: Dict[str, np.ndarray], symbols) -> int:
 # receives.  `joinSlots` is the join order as the program holds it: the
 # output slots of every inner join, before any compaction.
 # `survivorGatherJoins`: inner joins that compacted before gathering their
-# build side (`_TraceCtx._compact_then_gather`).
+# build side (`_TraceCtx._compact_then_gather`).  `residualSemiJoins`: semi
+# and anti joins with a residual predicate; `semiWorkSlots`: the slots those
+# mark joins work over (`_TraceCtx._semi_residual`).
 OP_COUNTERS = (
     "sortGroupBys", "sortGroupRows", "sortGroupCapacity", "directGroupBys",
     "directJoins", "sortJoins", "semiJoins", "lazyDictionaryColumns",
@@ -328,8 +330,13 @@ OP_COUNTERS = (
     "partitionedExchanges", "partitionedExchangeSlots",
     "groupStateExchangeSlots",
     "residualKeyJoins", "hashedKeyJoins", "joinSlots",
-    "survivorGatherJoins",
+    "survivorGatherJoins", "residualSemiJoins", "semiWorkSlots",
 )
+
+# a buffer sized from a plan-time row estimate holds this many times it
+# (`_compaction_cap`, the residual mark join's pair buffer); the ladder's
+# capacity check catches a data set past it
+ESTIMATE_MARGIN = 1.3
 
 # the key under which a join's build row rides the probe lanes' compaction
 # (a sentinel: no plan symbol equals it)
@@ -738,7 +745,9 @@ class LocalExecutor:
         # direct-address joins whose domain proof failed at runtime
         # (stale stats): first rung retries the sorted UNIQUE kernel
         # (still exact for a unique key outside its claimed domain);
-        # only a genuine duplicate then escalates to expansion
+        # only a genuine duplicate then escalates to expansion.  Mark
+        # joins whose keys or values did not fit int32 sort words
+        # (`_TraceCtx._semi_residual`) retry at int64
         self.force_no_direct = set()
         self.group_salt = 0
         self.topn_factor = int(
@@ -779,11 +788,13 @@ class LocalExecutor:
                         "duplicate build keys in unresolvable join"
                     )
                 if (
-                    getattr(join_node, "direct_domain", None) is not None
+                    (getattr(join_node, "direct_domain", None) is not None
+                     or isinstance(join_node, P.SemiJoin))
                     and id(join_node) not in self.force_no_direct
                 ):
                     # direct-table domain/dup proof failed: retry
-                    # on the sorted unique kernel first
+                    # on the sorted unique kernel first (a mark join's
+                    # int32 sort words did not hold its keys: at int64)
                     self.force_no_direct.add(id(join_node))
                 else:
                     # duplicate (or colliding) build keys: re-trace
@@ -2091,7 +2102,7 @@ class _TraceCtx:
         if est is None or not self.allow_compaction:
             return None
         factor = getattr(self.ex, "compact_factor", 1)
-        cap = self.ex.ladder.quantize(int(est * 1.3) * factor)
+        cap = self.ex.ladder.quantize(int(est * ESTIMATE_MARGIN) * factor)
         return cap if cap < n else None
 
     def _compact_ids(self, sel, cap: int):
@@ -2975,26 +2986,36 @@ class _TraceCtx:
     def _visit_semijoin(self, node: P.SemiJoin) -> Batch:
         src = self.visit(node.source)
         filt = self.visit(node.filtering)
-        hit = self._semi_hit(node, src, filt)
+        hit = self._semi_mark(node, src, filt)
         lanes = dict(src.lanes)
         lanes[node.output] = (hit, jnp.ones(hit.shape, bool))
         return Batch(lanes, src.sel, src.ordered, src.replicated)
+
+    def _semi_mark(self, node: P.SemiJoin, src: Batch, filt: Batch):
+        """The semi join's mark per source row: `_semi_residual` where a
+        residual predicate rides the equi-key (a correlated EXISTS whose
+        correlation is more than a key match), else `_semi_hit`."""
+        self._count("semiJoins")
+        if node.filter is not None:
+            return self._semi_residual(node, src, filt)
+        return self._semi_hit(node, src, filt)
 
     @jax.named_scope("_semi_hit")
     def _semi_hit(self, node: P.SemiJoin, src: Batch, filt: Batch):
         """Membership mark; duplicates in the filtering side are fine
         (sorted search, any match counts).  Single-column keys compare the
-        real value directly (collision-free); multi-column keys and residual
-        predicates go through the expansion path with exact verification."""
-        self._count("semiJoins")
+        real value directly (collision-free); multi-column keys go through
+        the pair expansion with exact verification."""
         skeys = [src.lanes[k] for k in node.source_keys]
         fkeys0 = [filt.lanes[k] for k in node.filtering_keys]
         if (
-            node.filter is not None
-            or join_ops.needs_verification(skeys)
+            join_ops.needs_verification(skeys)
             or join_ops.needs_verification(fkeys0)
         ):
-            return self._semi_hit_expanded(node, src, filt)
+            capacity = self.ex.ladder.quantize(
+                int(src.sel.shape[0] * getattr(self.ex, "join_factor", 1))
+            )
+            return self._semi_pairs(node, src, filt, capacity)
         build = join_ops.build_multi(
             filt.lanes[node.filtering_keys[0]], filt.sel
         )
@@ -3003,11 +3024,112 @@ class _TraceCtx:
         )
         return counts > 0
 
-    def _semi_hit_expanded(self, node: P.SemiJoin, src: Batch, filt: Batch):
+    @jax.named_scope("_semi_residual")
+    def _semi_residual(self, node: P.SemiJoin, src: Batch, filt: Batch):
+        """Mark join with a residual (TPC-H Q21's `l2.l_suppkey <>
+        l1.l_suppkey`), sized at plan time.  Where the residual is one
+        comparison `f.c <op> s.c` of a filtering column with a source
+        column (and the filtering side's own conjuncts), on a single
+        narrow key, no pair is made: a key's filtering rows hold an `f.c
+        <op> s.c` iff the least or greatest `f.c` of the key does
+        (`<>`: unless both equal `s.c`), so the filtering side is sorted
+        by (key, c) once and probed like a unique build
+        (`join_ops.build_extremes`).  Any other residual expands the
+        pairs into a buffer sized from the plan's pair estimate
+        (`SemiJoin.pair_rows`), `_semi_pairs`.  NULL keys never match and
+        a NULL `c` on either side makes the comparison unknown: that pair
+        does not count."""
+        self._count("residualSemiJoins")
+        form = self._extreme_residual(node, src, filt)
+        if form is None:
+            est = node.pair_rows if self.allow_compaction else None
+            n = (src.sel.shape[0] if est is None
+                 else int(est * ESTIMATE_MARGIN))
+            capacity = self.ex.ladder.quantize(
+                n * getattr(self.ex, "join_factor", 1)
+            )
+            self._count("semiWorkSlots", capacity)
+            return self._semi_pairs(node, src, filt, capacity)
+        fcol, op, scol, local = form
+        fsel = filt.sel
+        for c in local:
+            fv, fok = compile_expr(c, self.lowering)(filt.lanes)
+            fsel = fsel & fv & fok
+        self._count("semiWorkSlots", fsel.shape[0] + src.sel.shape[0])
+        # int32 sort words where the build's keys and values fit them
+        # (checked: a key or value outside retraces this join at int64)
+        fkey, fval = filt.lanes[node.filtering_keys[0]], filt.lanes[fcol]
+        narrow = id(node) not in getattr(self.ex, "force_no_direct", ())
+        build, sorted_vals, outside = join_ops.build_extremes(
+            fkey, fval, fsel, jnp.int32 if narrow else jnp.int64
+        )
+        if narrow and max(fkey[0].dtype.itemsize, fval[0].dtype.itemsize) > 4:
+            self.dup_checks.append((node, outside))
+        found, least, greatest = join_ops.probe_extremes(
+            build, sorted_vals, src.lanes[node.source_keys[0]], src.sel
+        )
+        sv, sok = src.lanes[scol]
+        if op in ("<>", "!="):
+            holds = (least != sv) | (greatest != sv)
+        elif op in ("<", "<="):   # f.c < s.c: the least f.c decides
+            holds = least < sv if op == "<" else least <= sv
+        else:                     # f.c > s.c: the greatest f.c decides
+            holds = greatest > sv if op == ">" else greatest >= sv
+        return found & sok & holds
+
+    _EXTREME_OPS = {"<>": "<>", "!=": "!=", "<": ">", "<=": ">=",
+                    ">": "<", ">=": "<="}   # op -> op with sides swapped
+    _EXTREME_TYPES = ("tinyint", "smallint", "integer", "bigint", "date")
+
+    def _extreme_residual(self, node: P.SemiJoin, src: Batch, filt: Batch):
+        """(filtering column, op, source column, the filtering side's own
+        conjuncts) where the residual is one comparison of a filtering
+        column with a source column of one integral or date type (lane
+        order is value order), the rest of it reads the filtering side
+        alone, and the key is one narrow integral column; else None."""
+        if len(node.source_keys) != 1:
+            return None
+        skey = src.lanes[node.source_keys[0]]
+        fkey = filt.lanes[node.filtering_keys[0]]
+        if (join_ops.needs_verification([skey])
+                or join_ops.needs_verification([fkey])
+                or not jnp.issubdtype(skey[0].dtype, jnp.integer)
+                or not jnp.issubdtype(fkey[0].dtype, jnp.integer)):
+            return None
+        from ..plan.optimizer import _conjuncts
+
+        fsyms = set(node.filtering.output_symbols())
+        ssyms = set(node.source.output_symbols())
+        cross, local = None, []
+        for c in _conjuncts(node.filter):
+            if set(ir.referenced_columns(c)) <= fsyms:
+                local.append(c)
+                continue
+            if (cross is not None or not isinstance(c, ir.Comparison)
+                    or c.op not in self._EXTREME_OPS
+                    or not isinstance(c.left, ir.ColumnRef)
+                    or not isinstance(c.right, ir.ColumnRef)
+                    or c.left.type != c.right.type
+                    or c.left.type.name not in self._EXTREME_TYPES):
+                return None
+            a, b = c.left.name, c.right.name
+            if a in fsyms and b in ssyms:
+                cross = (a, c.op, b)
+            elif b in fsyms and a in ssyms:
+                cross = (b, self._EXTREME_OPS[c.op], a)
+            else:
+                return None
+        if cross is None or filt.lanes[cross[0]][0].ndim != 1:
+            return None
+        return cross + (local,)
+
+    def _semi_pairs(self, node: P.SemiJoin, src: Batch, filt: Batch,
+                    capacity: int):
         """Mark join via candidate expansion: expand (source, filtering)
-        pairs on the equi-key locator ranges, verify exact key equality,
-        evaluate the residual if any, reduce any-match per source row
-        (EXISTS with non-equality correlation, e.g. TPC-H Q21)."""
+        pairs on the equi-key locator ranges into `capacity` slots (the
+        ladder checks the true count), verify exact key equality,
+        evaluate the residual if any on the lanes it reads, reduce
+        any-match per source row."""
         fkeys = [filt.lanes[k] for k in node.filtering_keys]
         skeys = [src.lanes[k] for k in node.source_keys]
         need_verify = join_ops.needs_verification(
@@ -3018,9 +3140,6 @@ class _TraceCtx:
         build = join_ops.build_multi(bkey, filt.sel)
         counts, lo = join_ops.probe_counts(build, pkey, src.sel)
         n_src = src.sel.shape[0]
-        capacity = self.ex.ladder.quantize(
-            int(n_src * getattr(self.ex, "join_factor", 1))
-        )
         probe_row, build_row, matched, total, _ = join_ops.expand_join_slots(
             build, counts, lo, capacity
         )
@@ -3032,10 +3151,13 @@ class _TraceCtx:
         pair_ok = matched & src.sel[probe_row]
         if node.filter is not None:
             lanes = {}
-            for s, (v, ok) in src.lanes.items():
-                lanes[s] = (v[probe_row], ok[probe_row])
-            for s, (v, ok) in filt.lanes.items():
-                lanes[s] = (v[build_row], ok[build_row] & matched)
+            for s in ir.referenced_columns(node.filter):
+                if s in filt.lanes:
+                    v, ok = filt.lanes[s]
+                    lanes[s] = (v[build_row], ok[build_row] & matched)
+                else:
+                    v, ok = src.lanes[s]
+                    lanes[s] = (v[probe_row], ok[probe_row])
             f = compile_expr(node.filter, self.lowering)
             fv, fok = f(lanes)
             pair_ok = pair_ok & fv & fok

@@ -124,18 +124,20 @@ WIDE_AGG_FACTOR = 0.28
 DEVGEN_TEMP_LANES = 4
 
 
-def _devgen_temp_bytes(executor, plan: P.PlanNode) -> float:
+def _devgen_temp_bytes(executor, scans) -> float:
     """HBM temporaries of on-device scan generation.  These were the
     Round-5 bench blind spot: estimate_program_bytes covered scan lanes and
     wide-agg chunk temporaries, but a device-generated scan ALSO runs a
     splitmix64 hash chain over the full padded row range, and its u64
     intermediates sat outside the reserve-before-dispatch accounting —
     so the first q6_sf100 generator compile exceeded the reservation and
-    killed the worker process."""
+    killed the worker process.  Each scan's generator is a program of its
+    own, run before the fragment and one at a time: the largest one's
+    temporaries are what stands beside the lanes."""
     if not executor.config.get("device_generation", True):
         return 0.0
-    total = 0.0
-    for sc in _find_scan_nodes(plan):
+    most = 0.0
+    for sc in scans:
         conn = executor.catalogs.get(sc.catalog)
         if getattr(conn, "device_generation", None) is None:
             continue
@@ -143,8 +145,24 @@ def _devgen_temp_bytes(executor, plan: P.PlanNode) -> float:
             stats = conn.metadata().get_table_statistics(sc.table)
         except Exception:  # noqa: BLE001 — unknown stats: assume small
             continue
-        total += float(stats.row_count) * 8.0 * DEVGEN_TEMP_LANES
-    return total
+        most = max(most, float(stats.row_count) * 8.0 * DEVGEN_TEMP_LANES)
+    return most
+
+
+def _resident_scans(executor, plan: P.PlanNode) -> List[P.TableScan]:
+    """The plan's scans, one for each set of lanes it holds: with the
+    scan cache on, a table read twice with the same columns and
+    constraint (a self join: TPC-H Q21's `l1` and `l3`) is one entry, so
+    one set of lanes."""
+    scans = _find_scan_nodes(plan)
+    if executor.config.get("scan_cache") is None:
+        return scans
+    seen = {}
+    for sc in scans:
+        key = (sc.catalog, sc.table, tuple(c for _, c in sc.assignments),
+               sc.constraint)
+        seen.setdefault(key, sc)
+    return list(seen.values())
 
 
 def estimate_program_bytes(executor, plan: P.PlanNode) -> float:
@@ -155,10 +173,12 @@ def estimate_program_bytes(executor, plan: P.PlanNode) -> float:
     compiled to a 20.6 GB buffer assignment (r04's q1_sf20 hard error:
     XLA's own message, reproduced 2026-07-31) — so the gate streams
     BEFORE submitting a compile that XLA would refuse for HBM."""
-    scan = estimate_plan_scan_bytes(executor, plan)
+    scans = _resident_scans(executor, plan)
+    scan = sum(_est_scan_bytes(executor, sc.catalog, sc.table, sc)
+               for sc in scans)
     return (
         scan * (1.0 + WIDE_AGG_FACTOR * _wide_agg_count(plan))
-        + _devgen_temp_bytes(executor, plan)
+        + _devgen_temp_bytes(executor, scans)
     )
 
 

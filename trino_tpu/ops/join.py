@@ -224,7 +224,7 @@ def probe_counts(
     """Per-probe-row match count and first-match slot ([lo,hi) range);
     dead build slots (beyond nvalid) and dead probe rows count zero."""
     v, ok = key
-    pk = v.astype(jnp.int64)
+    pk = v.astype(source.sorted_keys.dtype)
     lo = merge_rank(source.sorted_keys, pk, side="left")
     # hi = lo + the run length of the matching key.  Run lengths come
     # from two prefix scans over the SORTED build keys — a segment_sum
@@ -251,6 +251,51 @@ def probe_counts(
     hi = jnp.minimum(hi, source.nvalid)
     counts = jnp.where(sel & ok, hi - lo, 0).astype(jnp.int64)
     return counts, lo
+
+
+def build_extremes(key: Lane, val: Lane, sel: jnp.ndarray, dtype):
+    """(build, sorted values, outside): the build rows whose key and `val`
+    are both non-NULL, sorted by (key, val) as `dtype` words, so each
+    key's run holds its values in ascending order — its least value at the
+    run's first slot and its greatest at the last.  `build` is what
+    `probe_counts` reads (no row permutation: no build row is gathered).
+    Dead rows sort last as (max, max): a live row equal to that pair is
+    the same pair of values, so the first `nvalid` slots hold exactly the
+    live rows' pairs.  `outside` counts live rows whose key or value does
+    not fit `dtype` (int32 sort words compile and run far cheaper than
+    int64 ones; the caller retraces at int64 where any does not fit)."""
+    v, ok = key
+    c, cok = val
+    v, c = v.astype(jnp.int64), c.astype(jnp.int64)
+    live = sel & ok & cok
+    info = jnp.iinfo(dtype)
+    outside = jnp.sum(live & (
+        (v < info.min) | (v > info.max) | (c < info.min) | (c > info.max)))
+    kv = jnp.where(live, v, info.max).astype(dtype)
+    cv = jnp.where(live, c, info.max).astype(dtype)
+    sorted_keys, sorted_vals = jax.lax.sort(
+        (kv, cv), num_keys=2, is_stable=False
+    )
+    return (MultiLookupSource(sorted_keys, None, live.sum()), sorted_vals,
+            outside)
+
+
+def probe_extremes(source: MultiLookupSource, sorted_vals: jnp.ndarray,
+                   key: Lane, sel: jnp.ndarray):
+    """Per probe row: (any build row, least value, greatest value) over the
+    build rows of its key (`build_extremes`), compared in the build's
+    words: a probe key outside them matches nothing.  The values are
+    garbage where there is no row."""
+    v, ok = key
+    v = v.astype(jnp.int64)
+    info = jnp.iinfo(source.sorted_keys.dtype)
+    fits = (v >= info.min) & (v <= info.max)
+    counts, lo = probe_counts(
+        source, (v.astype(source.sorted_keys.dtype), ok), sel & fits)
+    last = sorted_vals.shape[0] - 1
+    least = sorted_vals[jnp.clip(lo, 0, last)]
+    greatest = sorted_vals[jnp.clip(lo + counts - 1, 0, last)]
+    return counts > 0, least, greatest
 
 
 @jax.named_scope("expand_join_slots")

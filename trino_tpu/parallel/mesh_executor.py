@@ -1319,7 +1319,7 @@ class _MeshTraceCtx(_TraceCtx):
         if not filt.replicated:
             # broadcast the filtering side (dynamic-filter style exchange)
             filt = self._broadcast(filt)
-        hit = self._semi_hit(node, src, filt)
+        hit = self._semi_mark(node, src, filt)
         lanes = dict(src.lanes)
         lanes[node.output] = (hit, jnp.ones(hit.shape, bool))
         return Batch(lanes, src.sel, src.ordered, src.replicated)
@@ -1372,7 +1372,7 @@ class _MeshTraceCtx(_TraceCtx):
         self._note_capacity(fmax, fchunk, "join")
         src2 = Batch(slanes, ssel, replicated=False)
         filt2 = Batch(flanes, fsel, replicated=False)
-        hit = self._semi_hit(node, src2, filt2)
+        hit = self._semi_mark(node, src2, filt2)
         lanes = dict(src2.lanes)
         lanes[node.output] = (hit, jnp.ones(hit.shape, bool))
         return Batch(lanes, src2.sel, replicated=False)

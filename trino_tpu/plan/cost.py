@@ -138,17 +138,22 @@ class StatsProvider:
         r = self.estimate(node.right)
         if node.kind == "cross" or not node.criteria:
             return Estimate(l.rows * r.rows, width)
-        # |L JOIN R| = |L|*|R| / max(ndv(keys)) per equi conjunct
-        # (JoinStatsRule.java simplified to independent keys)
-        rows = l.rows * r.rows
-        for a, b in node.criteria:
-            ndv = max(
-                self.ndv(node.left, a), self.ndv(node.right, b), 1.0
-            )
-            rows /= ndv
+        rows = self.equi_rows(node.left, node.right, node.criteria)
         if node.kind == "left":
             rows = max(rows, l.rows)
         return Estimate(max(rows, 1.0), width)
+
+    def equi_rows(self, left: P.PlanNode, right: P.PlanNode,
+                  criteria) -> float:
+        """|L JOIN R| = |L|*|R| / max(ndv(keys)) per equi conjunct
+        (JoinStatsRule.java simplified to independent keys).  A semi
+        join's (source, filtering) pairs on its keys are the same count
+        (`SemiJoin.pair_rows`)."""
+        rows = self.estimate(left).rows * self.estimate(right).rows
+        for a, b in criteria:
+            ndv = max(self.ndv(left, a), self.ndv(right, b), 1.0)
+            rows /= ndv
+        return rows
 
     # -- NDV ------------------------------------------------------------
     def ndv(self, node: P.PlanNode, symbol: str) -> float:
@@ -405,7 +410,10 @@ def conjunct_selectivity(
         return UNKNOWN_FILTER
     sym, const, op = _simple_comparison(c)
     if sym is None:
-        return UNKNOWN_FILTER
+        pair = _column_pair_share(
+            col_stats(c.left), col_stats(c.right), c.op
+        )
+        return UNKNOWN_FILTER if pair is None else _clamp_sel(pair)
     col = assigns.get(sym)
     if col is None:
         return UNKNOWN_FILTER
@@ -440,6 +448,42 @@ def conjunct_selectivity(
     if op in (">", ">="):
         return _clamp_sel((1.0 - frac) * notnull)
     return UNKNOWN_FILTER
+
+
+def _column_pair_share(a, b, op: str) -> Optional[float]:
+    """P(a <op> b) for two columns of one row (`l_receiptdate >
+    l_commitdate`), each taken uniform over its [min, max] and the two
+    independent, as a literal's range fraction takes one column: the
+    share of the pair rectangle on the comparison's side of the diagonal,
+    times both non-null fractions.  None where either range is unknown or
+    the comparison is not an inequality."""
+    if op in (">", ">="):
+        a, b = b, a
+    elif op not in ("<", "<="):
+        return None
+    if a is None or b is None or None in (
+        a.min_value, a.max_value, b.min_value, b.max_value
+    ):
+        return None
+    a0, a1 = float(a.min_value), float(a.max_value)
+    b0, b1 = float(b.min_value), float(b.max_value)
+    if a1 - a0 <= 0.0 or b1 - b0 <= 0.0:
+        return None
+    w = b1 - b0
+
+    def area(x):
+        """Integral over a in [a0, x] of the length of b in (a, b1]."""
+        def below(t):   # integral of min(max(t - b0, 0), w) dt from -inf
+            if t <= b0:
+                return 0.0
+            if t <= b1:
+                return (t - b0) ** 2 / 2.0
+            return w * w / 2.0 + w * (t - b1)
+        return (x - a0) * w - (below(x) - below(a0))
+
+    share = area(a1) / ((a1 - a0) * w)
+    notnull = (1.0 - a.null_fraction) * (1.0 - b.null_fraction)
+    return min(max(share, 0.0), 1.0) * notnull
 
 
 def _column_stats(scan: P.TableScan, metadata: Metadata, sym: str):

@@ -312,8 +312,7 @@ class Fragmenter:
         else:
             fs = self._broadcast(filt, fpart, fkeys, probe_single)
         return (
-            P.SemiJoin(src, fs, node.source_keys, node.filtering_keys,
-                       node.output, node.filter),
+            dataclasses.replace(node, source=src, filtering=fs),
             part,
             keys,
         )

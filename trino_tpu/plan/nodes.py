@@ -446,6 +446,10 @@ class SemiJoin(PlanNode):
     # residual predicate over (source row, filtering row) pairs — the
     # "mark join" form needed by EXISTS with non-equality correlation
     filter: Optional[ir.Expr] = None
+    # estimated (source, filtering) pairs on the equi-key where a residual
+    # rides it (optimizer._annotate_compaction): the pair buffer's size
+    # where the executor expands pairs (exec/local._semi_residual)
+    pair_rows: Optional[int] = None
 
     @property
     def sources(self):
