@@ -220,8 +220,14 @@ def test_only_the_attributes_with_a_use_are_set(resident):
     warm = {s.name: s for s in resident["warm"]}
     assert warm["query_finish"].attributes == {"state": "FINISHED"}
     assert set(warm["query"].attributes) == {"query_id"}
+    # the streaming gate's decision, the one phase that carries some
+    gate = warm["stream_plan"].attributes
+    assert set(gate) == {"estimate_bytes", "limit_bytes",
+                         "fused_aggregates", "streamed"}
+    assert gate["streamed"] is False
     for name in RESIDENT_PHASES:
-        assert warm[name].attributes == {}
+        if name != "stream_plan":
+            assert warm[name].attributes == {}
 
 
 def test_phases_lie_inside_execute(resident):

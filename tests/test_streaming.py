@@ -8,6 +8,7 @@ fragment DAG (see exec/streaming.py docstring).
 import pytest
 
 from oracle import assert_q1_fuses_past_the_int64_gate
+from tpch_sql import QUERIES
 from trino_tpu.exec import streaming
 from trino_tpu.session import tpch_session
 
@@ -85,6 +86,88 @@ def test_streamed_q1_fuses_past_the_int64_gate(monkeypatch):
 def test_q3_streams_exact(free):
     # joins (broadcast builds) + group-by + topN across tiles
     assert _streamed(Q3) == free.execute(Q3).to_pylist()
+
+
+GIB = 1 << 30
+
+
+def _gate(sql, sf=10, limit=8 * GIB, **props):
+    """Plan `sql` at `sf` and put it to the streaming gate, executing
+    nothing: (program estimate, fused aggregates, fragments or None,
+    the executor's `programEstimate`)."""
+    s = tpch_session(sf, **props)
+    plan = s.plan(sql)
+    ex = s._executor()
+    program, fused = streaming.program_estimate(ex, plan)
+    frags = streaming.plan_streaming(ex, plan, limit)
+    return program, fused, frags, ex.kernel_profile.get("programEstimate")
+
+
+def test_fused_q1_at_sf10_is_reckoned_as_one_resident_program():
+    """TPC-H Q1 at SF 10 where the fused kernel takes its aggregate: the
+    scan's lanes (3.06 GB), the kernel's int32 copies of its 4 decimal
+    columns (the 3 int32 ones are read in place), `live` and one low word
+    in flight at the table's rung (24 B x 67,108,864 slots) and the
+    generator's temporaries (1.92 GB) stay under the session's 8 GiB,
+    so the gate does not tile; under 4 GiB it still does."""
+    program, fused, frags, gate = _gate(QUERIES[1][0], megakernels="on")
+    assert fused == 1
+    assert program == 3.06e9 + 24 * 67_108_864 + 1.92e9
+    assert frags is None
+    assert gate == {"estimate_bytes": int(program), "limit_bytes": 8 * GIB,
+                    "fused_aggregates": 1, "streamed": False}
+    _, _, frags, gate = _gate(QUERIES[1][0], limit=4 * GIB,
+                              megakernels="on")
+    assert frags is not None and len(frags) >= 2
+    assert gate["estimate_bytes"] > gate["limit_bytes"] == 4 * GIB
+
+
+def test_unfused_q1_at_sf10_keeps_the_wide_factor():
+    """Where the kernel does not fuse, Q1's seven wide accumulators are
+    charged WIDE_AGG_FACTOR of the lanes each, as before: 10.978 GB, so
+    the query tiles."""
+    program, fused, frags, gate = _gate(QUERIES[1][0], megakernels="off")
+    assert fused == 0 and gate["fused_aggregates"] == 0
+    assert program == pytest.approx(10.978e9, rel=0.01)
+    assert frags is not None
+
+
+def test_an_aggregate_over_a_join_keeps_the_wide_factor():
+    """Q3's wide sum is over a join: no fused kernel takes it, whatever
+    the session's `megakernels`, so its estimate is the unfused one."""
+    on = _gate(Q3, megakernels="on")
+    off = _gate(Q3, megakernels="off")
+    assert on[1] == off[1] == 0
+    assert on[0] == off[0]
+    s = tpch_session(10)
+    ex = s._executor()
+    scans = streaming._resident_scans(ex, s.plan(Q3))
+    lanes = sum(streaming._est_scan_bytes(ex, sc.catalog, sc.table, sc)
+                for sc in scans)
+    assert on[0] == pytest.approx(
+        lanes * (1 + streaming.WIDE_AGG_FACTOR)
+        + streaming._devgen_temp_bytes(ex, scans))
+
+
+@pytest.mark.parametrize("limit, streamed", [(600_000, True), (None, False)])
+def test_stream_plan_says_what_the_gate_decided(limit, streamed):
+    """The `stream_plan` span carries the gate's estimate, the limit, the
+    fused aggregates and whether the query left the resident path, and
+    the query's kernel profile holds the same four."""
+    props = {"result_cache": False}
+    if limit is not None:
+        props["query_max_memory_bytes"] = limit
+    s = tpch_session(0.01, **props)
+    s.tracer.spans.clear()
+    s.execute(Q6).to_pylist()
+    gate = s.last_kernel_profile["programEstimate"]
+    (span,) = [sp for sp in s.tracer.spans if sp.name == "stream_plan"]
+    assert span.attributes == gate
+    assert gate["streamed"] is streamed
+    assert gate["limit_bytes"] == s.properties.get("query_max_memory_bytes")
+    assert (gate["estimate_bytes"] > gate["limit_bytes"]) is streamed
+    assert gate["fused_aggregates"] == 0  # `auto` fuses nothing off the chip
+    assert bool(s.last_kernel_profile.get("streamedFragments")) is streamed
 
 
 def test_count_distinct_streams_under_memory_limit(free):

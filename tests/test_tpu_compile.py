@@ -494,3 +494,35 @@ def test_q21_fragment_at_sf10_fits_one_chip(
         counts))
     assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
             + mem.output_size_in_bytes) < HBM_BYTES
+
+
+def test_q1_fragment_at_sf10_is_one_resident_program_for_v5e(
+        one_chip, no_persistent_cache, monkeypatch):
+    """`tpch_sf10.q1` at its own sizes under the session's 8 GiB: the
+    streaming gate reckons the fused aggregate by what the fused kernel
+    allocates (an int32 copy of each of its 4 decimal columns, `live` and
+    one low word in flight: 24 B a slot), so lineitem's 67,108,864 slots
+    compile as one program for the described chip, with no tile.  That
+    program's temporaries lie within a third of the fused term the gate
+    charged."""
+    from oracle import bench_module
+    from trino_tpu.exec import streaming
+
+    q1 = bench_module("queries", "q1")
+    seen = _fragment_for_chip(one_chip, monkeypatch, 10.0,
+                              q1.sql({"delta": 90}))
+    assert seen["shapes"] == [(67_108_864,)]
+    ex = seen["ctx"].ex
+    gate = ex.kernel_profile["programEstimate"]
+    assert gate["fused_aggregates"] == 1 and gate["streamed"] is False
+    assert ex.kernel_profile["fusedAggregates"] == 1
+    fused = 6 * streaming.FUSED_LANE_BYTES * 67_108_864
+    mem = seen["compiled"].memory_analysis()
+    print("q1 sf10 temp %.3f GB, args %.3f GB, out %.6f GB, fused term "
+          "%.3f GB, estimate %.3f GB" % (
+              mem.temp_size_in_bytes / 1e9, mem.argument_size_in_bytes / 1e9,
+              mem.output_size_in_bytes / 1e9, fused / 1e9,
+              gate["estimate_bytes"] / 1e9))
+    assert abs(mem.temp_size_in_bytes - fused) <= 0.35 * fused
+    assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes) < gate["estimate_bytes"]

@@ -829,9 +829,12 @@ class TpchConnector(Connector):
     def __init__(self, name: str, sf: float):
         self.name = name
         self.sf = sf
+        # one instance a connector, so its statistics cache outlives a
+        # call: the streaming gate reads them on every query
+        self._metadata = TpchMetadata(sf)
 
     def metadata(self):
-        return TpchMetadata(self.sf)
+        return self._metadata
 
     def split_manager(self):
         return TpchSplitManager(self.sf, self)

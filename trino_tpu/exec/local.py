@@ -535,8 +535,12 @@ class LocalExecutor:
         # (MemoryRevokingScheduler -> spill, host RAM as the spill tier)
         limit = self.config.get("memory_limit_bytes")
         if limit and self.config.get("spill_enabled", True):
-            with TRACER.span("stream_plan"):
+            with TRACER.span("stream_plan") as span:
                 out_of_core = self._plan_out_of_core(plan, int(limit))
+                gate = self.kernel_profile.get("programEstimate")
+                if gate is not None:
+                    gate["streamed"] = out_of_core is not None
+                    span.attributes.update(gate)
             if out_of_core is not None:
                 return out_of_core()
         # 1. host side: load scans, collect dictionaries — or adopt the
@@ -919,6 +923,9 @@ class LocalExecutor:
         if out is not None:
             from ..obs import journal
 
+            gate = self.kernel_profile.get("programEstimate")
+            if gate is not None:
+                gate["streamed"] = True
             journal.emit(
                 journal.FORCED_STREAMING, query_id=self.query_id,
                 severity=journal.WARN,

@@ -36,6 +36,8 @@ from __future__ import annotations
 import math
 from typing import Dict, List, Optional
 
+import numpy as np
+
 from ..page import Page
 from ..plan import nodes as P
 from ..plan.fragment import fragment_plan
@@ -62,13 +64,19 @@ def _scan_row_bytes(node: P.TableScan) -> int:
     return max(total, 1)
 
 
-def _est_scan_bytes(executor, catalog: str, table: str, node) -> float:
+def _table_rows(executor, catalog: str, table: str) -> Optional[int]:
+    """The connector's row count of a table, None where it has none."""
     conn = executor.catalogs.get(catalog)
     try:
         stats = conn.metadata().get_table_statistics(table)
     except Exception:  # noqa: BLE001 — unknown stats: assume small
-        return 0.0
-    return float(stats.row_count) * _scan_row_bytes(node)
+        return None
+    return int(stats.row_count)
+
+
+def _est_scan_bytes(executor, catalog: str, table: str, node) -> float:
+    rows = _table_rows(executor, catalog, table)
+    return 0.0 if rows is None else float(rows) * _scan_row_bytes(node)
 
 
 def _find_scan_nodes(root: P.PlanNode) -> List[P.TableScan]:
@@ -91,31 +99,82 @@ def estimate_plan_scan_bytes(executor, plan: P.PlanNode) -> float:
     )
 
 
-def _wide_agg_count(plan: P.PlanNode) -> int:
-    """Aggregates whose accumulation runs 128-bit chunked math at input
-    width (decimal sums/avgs): each adds full-width u32 chunk-lane
-    temporaries to the compiled program's HBM peak."""
-    n = 0
+def _aggregates(plan: P.PlanNode) -> List[P.Aggregate]:
+    out: List[P.Aggregate] = []
 
     def walk(node: P.PlanNode):
-        nonlocal n
         if isinstance(node, P.Aggregate):
-            for a in node.aggs:
-                try:
-                    if a.to_spec()._wide_sum:
-                        n += 1
-                except Exception:  # noqa: BLE001
-                    pass
+            out.append(node)
         for s in node.sources:
             walk(s)
 
     walk(plan)
+    return out
+
+
+def _wide_sums(node: P.Aggregate) -> int:
+    """Accumulators of `node` that run 128-bit chunked math (decimal
+    sums/avgs)."""
+    n = 0
+    for a in node.aggs:
+        try:
+            n += bool(a.to_spec()._wide_sum)
+        except Exception:  # noqa: BLE001
+            pass
     return n
 
 
-# HBM a wide-decimal aggregate adds to the compiled program, as a share
-# of its scan lanes (estimate_program_bytes' one calibration point)
+# HBM a wide-decimal aggregate that does NOT fuse adds to the compiled
+# program, as a share of its scan lanes: its 128-bit chunk lanes run at
+# input width (estimate_program_bytes' first calibration point)
 WIDE_AGG_FACTOR = 0.28
+
+# HBM a fused aggregate's program holds beside the scan's lanes, per
+# slot and per 32-bit lane of it (ops/megakernel._run ->
+# pallas_kernels.fused_agg_sums, as XLA lays the program out for a v5e):
+# an int32 copy of each column the kernel reads whose lane is not int32
+# already (an int32 lane is read in place), the `live` mask as int32, and
+# one 64-bit lane's low word (`X64SplitLow`) on its way to that copy.
+# Its per-term sums are per group, nothing at this scale.  Q1 at SF 10,
+# 4 decimal columns of 7: (4 + 1 + 1) x 4 B x 67,108,864 slots =
+# 1,610,612,736 B, against the compiled program's `tempBytes`
+# 1,614,483,456; Q6, 3 of 4: 1,342,177,280 against 1,342,370,816
+# (estimate_program_bytes' second calibration point)
+FUSED_LANE_BYTES = 4
+
+
+def _fused_slot_bytes(scan: P.TableScan, cols: List[str]) -> int:
+    types = dict(scan.types)
+    copies = sum(types[c].np_dtype != np.int32 for c in cols)
+    return FUSED_LANE_BYTES * (copies + 1 + (copies > 0))
+
+
+def _aggregate_bytes(executor, plan: P.PlanNode, lane_bytes: float,
+                     slots: Optional[int] = None):
+    """(HBM the plan's aggregates hold beside `lane_bytes` of scan lanes,
+    how many of them the fused kernel takes).  One rule for the
+    monolithic program and for a tile's: an aggregate that fuses on this
+    executor holds `_fused_slot_bytes` a slot of its scan, at `slots` or
+    else the ladder's rung for the scan's table; a wide one that does not
+    fuse holds WIDE_AGG_FACTOR of the lanes for each wide accumulator."""
+    from ..ops import megakernel
+
+    fusing = executor._megakernel_mode() == "on"
+    total, fused = 0.0, 0
+    for node in _aggregates(plan):
+        reads = megakernel.plan_reads(node) if fusing else None
+        if reads is None:
+            total += lane_bytes * WIDE_AGG_FACTOR * _wide_sums(node)
+            continue
+        scan, cols = reads
+        n = slots
+        if n is None:
+            rows = _table_rows(executor, scan.catalog, scan.table)
+            n = executor.ladder.quantize(max(rows or 0, 1))
+        total += _fused_slot_bytes(scan, cols) * n
+        fused += 1
+    return total, fused
+
 
 # u64 lanes the generator program keeps live per row on top of its
 # output lanes: the row-index lane, the splitmix64 hash state, one value
@@ -141,11 +200,9 @@ def _devgen_temp_bytes(executor, scans) -> float:
         conn = executor.catalogs.get(sc.catalog)
         if getattr(conn, "device_generation", None) is None:
             continue
-        try:
-            stats = conn.metadata().get_table_statistics(sc.table)
-        except Exception:  # noqa: BLE001 — unknown stats: assume small
-            continue
-        most = max(most, float(stats.row_count) * 8.0 * DEVGEN_TEMP_LANES)
+        rows = _table_rows(executor, sc.catalog, sc.table)
+        if rows is not None:
+            most = max(most, float(rows) * 8.0 * DEVGEN_TEMP_LANES)
     return most
 
 
@@ -165,21 +222,33 @@ def _resident_scans(executor, plan: P.PlanNode) -> List[P.TableScan]:
     return list(seen.values())
 
 
-def estimate_program_bytes(executor, plan: P.PlanNode) -> float:
-    """Estimated HBM peak of the MONOLITHIC compiled program: scan lanes
-    plus wide-decimal accumulation temporaries plus on-device generator
-    temporaries.  Calibrated against the
-    one measured data point — Q1 SF20 (scan est 7.1 GB, 7 wide aggs)
-    compiled to a 20.6 GB buffer assignment (r04's q1_sf20 hard error:
-    XLA's own message, reproduced 2026-07-31) — so the gate streams
-    BEFORE submitting a compile that XLA would refuse for HBM."""
+def program_estimate(executor, plan: P.PlanNode):
+    """(`estimate_program_bytes`, how many of the plan's aggregates the
+    fused kernel takes)."""
     scans = _resident_scans(executor, plan)
     scan = sum(_est_scan_bytes(executor, sc.catalog, sc.table, sc)
                for sc in scans)
-    return (
-        scan * (1.0 + WIDE_AGG_FACTOR * _wide_agg_count(plan))
-        + _devgen_temp_bytes(executor, scans)
-    )
+    aggs, fused = _aggregate_bytes(executor, plan, scan)
+    return scan + aggs + _devgen_temp_bytes(executor, scans), fused
+
+
+def estimate_program_bytes(executor, plan: P.PlanNode) -> float:
+    """Estimated HBM peak of the MONOLITHIC compiled program: scan lanes,
+    the aggregates' temporaries (`_aggregate_bytes`) and on-device
+    generator temporaries, so the gate streams BEFORE submitting a
+    compile that XLA would refuse for HBM.  Two calibration points:
+
+    - an aggregate that does not fuse: Q1 at SF 20 (scan est 7.1 GB, 7
+      wide aggs) compiled, before the fused kernel took its wide sums,
+      to a 20.6 GB buffer assignment (XLA's own hard error) — hence
+      WIDE_AGG_FACTOR;
+    - a fused one: Q1 at SF 10 as one resident program on one TPU v5e
+      (67,108,864 slots, 7 columns read, 4 of them decimal): the fused
+      term 1,610,612,736 B against the program's census `tempBytes`
+      1,614,483,456 and the run's `memory_peak_bytes` 3,038,161,920 (the
+      lanes: temporaries do not show there), under the 6,590,612,736 B
+      reckoned — hence FUSED_LANE_BYTES."""
+    return program_estimate(executor, plan)[0]
 
 
 def _resident_tile_cache(executor, frag, node, tile_splits, tile_rows: int):
@@ -221,10 +290,10 @@ def _resident_tile_cache(executor, frag, node, tile_splits, tile_rows: int):
     if spec is not None:
         tile_bytes = devgen_lane_bytes(spec, cols, tile_rows)
         after = cache.bytes + missing * tile_bytes
-        # beside the resident tiles: the tile program's wide-aggregate
+        # beside the resident tiles: the tile program's aggregate
         # temporaries and, on a miss, the generator's
         room = (
-            tile_bytes * WIDE_AGG_FACTOR * _wide_agg_count(frag.root)
+            _aggregate_bytes(executor, frag.root, tile_bytes, tile_rows)[0]
             + 8.0 * DEVGEN_TEMP_LANES * tile_rows
         )
         device = detect_device_bytes()
@@ -304,11 +373,19 @@ def plan_streaming(executor, plan: P.Output, memory_limit: int,
     # wide-decimal accumulators inflate XLA's buffer assignment well past
     # the scan bytes (the Q1 SF20 calibration point), and the whole point
     # of the gate is streaming before a compile-OOM can kill the worker
-    if not force and max(
-        estimate_plan_scan_bytes(executor, plan),
-        estimate_program_bytes(executor, plan),
-    ) <= memory_limit:
-        return None
+    if not force:
+        program, fused = program_estimate(executor, plan)
+        estimate = max(estimate_plan_scan_bytes(executor, plan), program)
+        # what the gate reckoned, for the `stream_plan` span and the
+        # query's kernel profile (`streamed` is the caller's to settle)
+        executor.kernel_profile["programEstimate"] = {
+            "estimate_bytes": int(estimate),
+            "limit_bytes": int(memory_limit),
+            "fused_aggregates": fused,
+            "streamed": False,
+        }
+        if estimate <= memory_limit:
+            return None
     # cache the fragment DAG per plan object: fragment roots key the jit
     # cache by identity, so re-fragmenting would recompile every tile
     # program on every run (and leak the old executables).  Entries are

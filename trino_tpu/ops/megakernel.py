@@ -333,7 +333,11 @@ def _conjuncts(e: ir.Expr) -> List[ir.Expr]:
 # matcher
 
 
-def _match(ctx, node: P.Aggregate):
+def match_plan(node: P.Aggregate):
+    """The part of the fusion rule the plan alone decides, with no trace
+    context: (scan, symbol -> expression over scan symbols, predicate
+    conjuncts), or Reject.  The rest (the retry rung, dictionaries, the
+    group cap, int32 bounds) is decided while tracing, in `_run`."""
     if node.step not in ("single", "partial"):
         raise Reject(f"step {node.step}")
     if not node.aggs:
@@ -343,8 +347,6 @@ def _match(ctx, node: P.Aggregate):
             raise Reject("DISTINCT aggregate")
         if a.kind not in FUSABLE_KINDS:
             raise Reject(f"aggregate kind {a.kind}")
-    if getattr(ctx.lowering, "force_wide_mul", False):
-        raise Reject("wide-multiply retry rung")
     chain: List[P.PlanNode] = []
     cur = node.source
     while isinstance(cur, (P.Project, P.Filter)):
@@ -365,7 +367,34 @@ def _match(ctx, node: P.Aggregate):
             mapping = {
                 s: ir.replace_refs(e, mapping) for s, e in nd.assignments
             }
+    # group ids are mixed-radix over dictionary/boolean domains alone
+    for k in node.keys:
+        e = mapping.get(k)
+        if not isinstance(e, ir.ColumnRef):
+            raise Reject(f"group key {k} is not a scan column")
+        if not (e.type.is_dictionary or e.type.name == "boolean"):
+            raise Reject(f"group key {e.name} is not low-cardinality")
     return scan, mapping, preds
+
+
+def plan_reads(node: P.Aggregate):
+    """(scan, the scan symbols the fused kernel reads for `node`:
+    predicates, summed inputs, group keys), or None where `match_plan`
+    rejects it."""
+    try:
+        scan, mapping, preds = match_plan(node)
+    except Reject:
+        return None
+    exprs = list(preds) + [mapping.get(k) for k in node.keys] + [
+        mapping.get(a.arg) for a in node.aggs
+        if a.kind not in ("count", "count_star")
+    ]
+    names: List[str] = []
+    for e in exprs:
+        if e is None:
+            continue
+        names += [c for c in ir.referenced_columns(e) if c not in names]
+    return scan, names
 
 
 def _column_env(ex, scan: P.TableScan, types) -> Tuple[Dict[str, dict], object]:
@@ -403,10 +432,7 @@ def _key_domains(ex, node: P.Aggregate, mapping, types, env):
     doms: List[Tuple[str, str, int]] = []
     cap = 1
     for k in node.keys:
-        e = mapping.get(k)
-        if not isinstance(e, ir.ColumnRef):
-            raise Reject(f"group key {k} is not a scan column")
-        sk = e.name
+        sk = mapping[k].name  # a dictionary/boolean column (match_plan)
         info = env.get(sk)
         if info is None:
             raise Reject(f"group key {sk} lacks null-free stats")
@@ -415,10 +441,8 @@ def _key_domains(ex, node: P.Aggregate, mapping, types, env):
             if d is None or len(d) == 0:
                 raise Reject(f"no dictionary for key {sk}")
             dom = len(d)
-        elif info.get("bool"):
+        else:  # boolean
             dom = 2
-        else:
-            raise Reject(f"group key {sk} is not low-cardinality")
         doms.append((k, sk, dom))
         cap *= dom + 1
     if node.keys and cap > pk.MAX_GROUPS:
@@ -474,7 +498,9 @@ def try_fused(ctx, node: P.Aggregate):
 
 def _run(ctx, node: P.Aggregate):
     ex = ctx.ex
-    scan, mapping, preds = _match(ctx, node)
+    scan, mapping, preds = match_plan(node)
+    if getattr(ctx.lowering, "force_wide_mul", False):
+        raise Reject("wide-multiply retry rung")
     types = dict(scan.types)
     env, stats = _column_env(ex, scan, types)
     doms, cap = _key_domains(ex, node, mapping, types, env)
